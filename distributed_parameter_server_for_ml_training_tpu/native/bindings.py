@@ -5,6 +5,7 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
+import sys
 import threading
 
 import numpy as np
@@ -13,8 +14,9 @@ _LIB = None
 _LIB_LOCK = threading.Lock()
 
 # Search order for libps_core.so: explicit override, the source checkout's
-# native/ dir, or alongside this module (where installed images copy it —
-# a pip-installed package has no ../../native).
+# native/ dir (built there on first use — the binary is not committed), or
+# alongside this module (where installed images copy it — a pip-installed
+# package has no ../../native).
 _NATIVE_DIR = os.path.abspath(
     os.path.join(os.path.dirname(__file__), "..", "..", "native"))
 _SO_CANDIDATES = [
@@ -38,45 +40,13 @@ def _build() -> bool:
         subprocess.run(["make", "-C", _NATIVE_DIR], check=True,
                        capture_output=True, timeout=120)
         return _find_so() is not None
-    except (subprocess.SubprocessError, OSError):
+    except (subprocess.SubprocessError, OSError) as e:
+        # The library is built from source (no binary is committed), so a
+        # failed build is why the native backend is unavailable: say so.
+        detail = getattr(e, "stderr", b"") or b""
+        print(f"native: `make -C {_NATIVE_DIR}` failed: {e}\n"
+              f"{detail.decode(errors='replace')}", file=sys.stderr)
         return False
-
-
-# Every symbol the bindings below resolve; _stale() probes these directly.
-_REQUIRED_SYMBOLS = (
-    "dps_fp32_to_fp16", "dps_fp16_to_fp32",
-    "dps_fp32_to_bf16", "dps_bf16_to_fp32",
-    "dps_store_create", "dps_store_destroy", "dps_store_step",
-    "dps_store_rejected", "dps_store_fetch", "dps_store_load",
-    "dps_store_push_fp16", "dps_store_push_fp32", "dps_store_push_int8",
-    "dps_store_stash_fp16", "dps_store_stash_fp32", "dps_store_stash_int8",
-    "dps_store_apply_mean", "dps_store_free_slot",
-)
-
-
-def _stale(so: str) -> bool:
-    """True when the found .so doesn't export every symbol these bindings
-    need (i.e. it predates the current source). Probed directly rather than
-    via mtimes — git checkout order makes source-vs-.so timestamps
-    meaningless, and a false 'stale' would disable the prebuilt library on
-    exactly the toolchain-less machines it was committed for."""
-    try:
-        lib = ctypes.CDLL(so)
-    except OSError:
-        return True
-    try:
-        return any(not hasattr(lib, sym) for sym in _REQUIRED_SYMBOLS)
-    finally:
-        # Release the probe handle: dlopen dedups by pathname, so if make
-        # rebuilds the SAME path, a still-open stale mapping would be what
-        # the post-build CDLL returns (ADVICE r3). dlclose only drops a
-        # refcount; the loader unmaps once no handle remains.
-        try:
-            import _ctypes
-
-            _ctypes.dlclose(lib._handle)
-        except (AttributeError, OSError):
-            pass
 
 
 def load_library() -> ctypes.CDLL | None:
@@ -85,11 +55,7 @@ def load_library() -> ctypes.CDLL | None:
     with _LIB_LOCK:
         if _LIB is not None:
             return _LIB
-        so = _find_so()
-        if (so is None or _stale(so)) and not _build():
-            # Missing OR stale-and-unbuildable: a stale .so lacks newer
-            # symbols, and binding it would raise AttributeError below —
-            # report the native backend unavailable instead.
+        if _find_so() is None and not _build():
             return None
         lib = ctypes.CDLL(_find_so())
 

@@ -18,32 +18,6 @@ DATA_AXIS = "data"
 MODEL_AXIS = "model"
 
 
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True,
-              axis_names=None):
-    """Version-portable ``shard_map``.
-
-    jax >= 0.5 exposes ``jax.shard_map`` with the replication check named
-    ``check_vma`` and manual axes selected by ``axis_names``; 0.4.x only has
-    ``jax.experimental.shard_map.shard_map`` with the check named
-    ``check_rep`` and the COMPLEMENT of the manual set passed as ``auto``.
-    Every SPMD builder in this package routes through here so the rest of
-    the code targets one spelling.
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = {} if axis_names is None else {"axis_names": axis_names}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma,
-                             **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kwargs = {}
-    if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if auto:
-            kwargs["auto"] = auto
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma, **kwargs)
-
-
 def make_mesh(num_workers: int | None = None,
               axis_names: tuple[str, ...] = (DATA_AXIS,),
               devices=None) -> Mesh:

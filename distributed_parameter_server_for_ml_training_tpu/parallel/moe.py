@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .mesh import shard_map
 
 EXPERT_AXIS = "expert"
 
@@ -143,7 +142,7 @@ def make_moe_ffn(mesh: Mesh, capacity: int,
     stats_specs = {"aux_loss": P(), "load": P(), "importance": P(),
                    "drop_frac": P()}
     tok_spec = P(axis) if data_axis is None else P((data_axis, axis))
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body, mesh=mesh,
         in_specs=(param_specs, tok_spec),
         out_specs=(tok_spec, stats_specs),

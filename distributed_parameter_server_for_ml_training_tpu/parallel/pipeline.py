@@ -43,7 +43,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .mesh import shard_map
 
 STAGE_AXIS = "stage"
 
@@ -165,7 +164,7 @@ def make_pipeline_apply(mesh: Mesh, stage_fn: Callable,
     manual = {axis} | ({data_axis} if data_axis else set())
     mb_axis = axis if shard_io else None
     x_spec = P(mb_axis, data_axis)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body, mesh=mesh,
         # params stacked on the stage axis; further (auto-axis) sharding of
         # the leaves rides on the arrays themselves.
@@ -501,7 +500,7 @@ def make_pipeline_train_step(mesh: Mesh, stage_fn: Callable,
     tables = build_1f1b_schedule(axis_size, num_microbatches)
     body = partial(_1f1b_body, stage_fn=stage_fn, loss_fn=loss_fn,
                    tables=tables, axis_name=axis, axis_size=axis_size)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(axis), P(), P()),
         out_specs=(P(), P(axis)),

@@ -22,7 +22,6 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from .mesh import shard_map
 
 _NEG_INF = -1e30
 
@@ -83,7 +82,7 @@ def make_ring_attention(mesh: Mesh, axis: str = "data",
     body = partial(ring_attention_local, axis_name=axis,
                    axis_size=axis_size, causal=causal)
     spec = P(None, axis)  # shard the T dimension
-    fn = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
                        out_specs=spec, check_vma=False)
     return jax.jit(fn)
 
@@ -266,8 +265,9 @@ def make_ring_flash_attention(mesh: Mesh, axis: str = "seq",
     local_ring.defvjp(fwd_rule, bwd_rule)
 
     spec = P(None, axis)
-    fn = shard_map(local_ring, mesh=mesh, in_specs=(spec, spec, spec),
-                       out_specs=spec, check_vma=False)
+    fn = jax.shard_map(local_ring, mesh=mesh,
+                       in_specs=(spec, spec, spec), out_specs=spec,
+                       check_vma=False)
     return jax.jit(fn)
 
 

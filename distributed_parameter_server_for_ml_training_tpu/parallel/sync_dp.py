@@ -38,7 +38,7 @@ from ..data.cifar import augment_batch, standardize, to_float
 from ..ops.compression import compress_for_allreduce, decompress_from_allreduce
 from ..train.steps import cross_entropy_loss
 from ..train.train_state import TrainState
-from .mesh import DATA_AXIS, shard_map
+from .mesh import DATA_AXIS
 
 
 def _int8_ring_allreduce_mean(grads, axis: str, axis_size: int, seed):
@@ -63,8 +63,8 @@ def _int8_ring_allreduce_mean(grads, axis: str, axis_size: int, seed):
     Per-device ICI bytes: 2 (N-1)/N x S x 1B (+ scales, 4B / 32768 elems)
     vs bf16-pmean's 4 (N-1)/N x S — int8 is ~half bf16 at every N, and
     strictly below it from N=2 up (the round-3 scheme crossed above bf16
-    at N>=4). Byte model recorded in experiments/results/PERF.md and
-    asserted against compiled HLO by tests/test_quantize.py.
+    at N>=4). Byte model asserted against compiled HLO by
+    tests/test_quantize.py.
     """
     from jax.flatten_util import ravel_pytree
 
@@ -193,7 +193,7 @@ def make_sync_dp_step(mesh: Mesh, *, axis: str = DATA_AXIS,
 
     metric_specs = {"loss": P(), "accuracy": P(),
                     "worker_loss": P(axis), "worker_accuracy": P(axis)}
-    sharded = shard_map(
+    sharded = jax.shard_map(
         worker_step,
         mesh=mesh,
         in_specs=(P(), P(axis), P(axis), P()),

@@ -16,10 +16,12 @@ gradients pushed at the window end).
 
 from __future__ import annotations
 
+import math
 import threading
 import time
 from contextlib import nullcontext
 from dataclasses import dataclass, field
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -44,6 +46,7 @@ from ..telemetry import (
 from ..train.device_loop import prefetch_to_device
 from ..train.steps import make_eval_step, make_fused_local_step, \
     make_grad_step
+from ..utils.metrics import device_fields
 from ..utils.pytree import flatten_params, unflatten_params
 from .store import ParameterStore
 
@@ -164,6 +167,11 @@ class WorkerResult:
     # Client-side wire accounting (RemoteStore.wire_stats); empty for
     # in-process stores, which cross no wire.
     wire: dict = field(default_factory=dict)
+    # Last batch's train loss (None until a batch ran, or when non-finite:
+    # NaN never rides a JSON hop) and where the step computed, read off
+    # the step's own output array — not the device it was meant for.
+    final_train_loss: float | None = None
+    device_id: int | None = None
     error: Exception | None = None
 
     def metrics(self, total_workers: int, learning_rate: float,
@@ -198,6 +206,9 @@ class WorkerResult:
             "learning_rate": learning_rate,
             "num_epochs": config.num_epochs,
             "reconnects": self.reconnects,
+            "final_train_loss": self.final_train_loss,
+            **device_fields(),
+            "device_id": self.device_id,
         }
 
 
@@ -470,9 +481,13 @@ class PSWorker(threading.Thread):
     def __init__(self, store: ParameterStore, model, dataset: Dataset,
                  config: WorkerConfig | None = None,
                  grad_step=None, eval_step=None, fused_step=None,
-                 worker_name: str = ""):
+                 worker_name: str = "", device=None):
         super().__init__(daemon=True)
         self.store = store
+        # The local device this worker computes on: fetched params and
+        # input batches are placed there, and the compiled step follows
+        # its inputs. None = JAX's default device.
+        self._device = device
         self.model = model
         self.dataset = dataset
         self.config = config or WorkerConfig()
@@ -752,7 +767,6 @@ class PSWorker(threading.Thread):
         payload that poisons the server, not just the boundary batch)."""
         if not self._health_enabled:
             return
-        import math
         try:
             lval = float(loss)
         except (TypeError, ValueError):
@@ -955,6 +969,7 @@ class PSWorker(threading.Thread):
             # the wall so the ledger reconciles end to end.
             gp.add("startup", _tnow() - t_run0)
             gp.start_wall(t_run0)
+        loss = None  # last batch's loss array; None until a batch ran
         try:
             for epoch in range(cfg.num_epochs):
                 t_epoch = time.time()
@@ -999,7 +1014,9 @@ class PSWorker(threading.Thread):
                     # async dispatch; train/device_loop.py). Bitwise the
                     # same batches, off the critical path.
                     batches = prefetch_to_device(
-                        batches, depth=cfg.prefetch_batches)
+                        batches, depth=cfg.prefetch_batches,
+                        device_put=partial(jax.device_put,
+                                           device=self._device))
                 for batch_idx, (xb, yb) in enumerate(batches):
                     boundary = batch_idx % k == 0
                     # One ROOT trace per loop iteration: fetch wait,
@@ -1166,6 +1183,12 @@ class PSWorker(threading.Thread):
 
                 self.result.epoch_times.append(time.time() - t_epoch)
                 self._tm_epochs.inc()
+                if loss is not None:
+                    lval = float(loss)
+                    self.result.final_train_loss = \
+                        round(lval, 6) if math.isfinite(lval) else None
+                    self.result.device_id = min(
+                        d.id for d in loss.devices())
                 if cfg.eval_each_epoch:
                     with trace_span("worker.eval", root=True,
                                     worker=worker_id, epoch=epoch), \
@@ -1497,7 +1520,13 @@ class PSWorker(threading.Thread):
                 self._tm_fetch_post.inc(
                     sum(int(v.nbytes) for v in flat.values()))
             self._last_fetched_step = fetched_step
-            return unflatten_params(flat), fetched_step
+            params = unflatten_params(flat)
+            if self._device is not None:
+                # Host arrays upload here; a device store's references
+                # (its own chip) copy chip-to-chip. No-op when already
+                # there.
+                params = jax.device_put(params, self._device)
+            return params, fetched_step
 
     def _push_mean(self, worker_id, accum_tree, n: int,
                    fetched_step) -> None:
@@ -1619,8 +1648,8 @@ class PSWorker(threading.Thread):
             apply_fn=self.model.apply, params=params,
             batch_stats=batch_stats, tx=optax.identity())
         # Device-resident test set, shared by every worker in the process:
-        # uploaded once instead of ~30 MB per eval (the remote-attach link
-        # is slow; see ps/device_store.py). Benign create race: last wins.
+        # uploaded once instead of ~30 MB per eval. Benign create race:
+        # last wins.
         cache = getattr(self.dataset, "_device_test_cache", None)
         if cache is None:
             import jax.numpy as jnp
@@ -1640,13 +1669,22 @@ class PSWorker(threading.Thread):
 
 def run_workers(store: ParameterStore, model, dataset: Dataset,
                 n_workers: int, config: WorkerConfig | None = None,
-                timeout: float | None = None) -> list[WorkerResult]:
+                timeout: float | None = None,
+                devices=None) -> list[WorkerResult]:
     """Spawn N worker threads sharing one compiled step; join them all.
 
     The in-process equivalent of launching N Fargate worker tasks
-    (terraform/main.tf:387-435).
+    (terraform/main.tf:387-435). Worker ``i`` computes on
+    ``devices[i % len(devices)]`` — by default every chip of this host, so
+    four workers keep four chips busy instead of queueing on chip 0.
+    Virtual CPU devices share the same cores, so on the CPU backend the
+    default is one device: spreading there only multiplies compiles.
     """
     config = config or WorkerConfig()
+    if devices is None:
+        devices = jax.local_devices()
+        if jax.default_backend() == "cpu":
+            devices = devices[:1]
     grad_step = make_grad_step(model, augment=config.augment)
     eval_step = jax.jit(make_eval_step())
     # local_sgd workers share ONE donated fused compile too (same shapes
@@ -1656,7 +1694,8 @@ def run_workers(store: ParameterStore, model, dataset: Dataset,
     workers = [
         PSWorker(store, model, dataset, config, grad_step=grad_step,
                  eval_step=eval_step, fused_step=fused_step,
-                 worker_name=f"worker-{i}")
+                 worker_name=f"worker-{i}",
+                 device=devices[i % len(devices)])
         for i in range(n_workers)
     ]
     for w in workers:

@@ -32,6 +32,7 @@ __all__ = [
     "find_profile_dumps",
     "mfu",
     "peak_flops",
+    "require_peak_flops",
     "prune_capture",
 ]
 
@@ -142,6 +143,19 @@ def peak_flops(device_kind: str) -> float | None:
     """Peak FLOP/s for a device kind, or None when unknown (CPU, new
     hardware this table hasn't met) — callers degrade to mfu=None."""
     return PEAK_FLOPS_BY_KIND.get(str(device_kind))
+
+
+def require_peak_flops(device_kind: str) -> float:
+    """:func:`peak_flops` for callers whose whole output is a utilisation
+    (the ``experiments/`` MFU scripts): a device that is not in the table
+    is an error, never a default — an MFU against a guessed peak is worse
+    than none."""
+    peak = peak_flops(device_kind)
+    if peak is None:
+        raise LookupError(
+            f"no peak FLOP/s recorded for device kind {device_kind!r} "
+            f"(PEAK_FLOPS_BY_KIND); refusing to report a utilisation")
+    return peak
 
 
 def mfu(flops_per_step: float | None, steps_per_s: float | None,

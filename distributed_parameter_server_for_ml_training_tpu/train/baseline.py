@@ -18,7 +18,7 @@ import numpy as np
 
 from ..data.cifar import Dataset, make_batches
 
-from ..utils.metrics import emit_metrics_json
+from ..utils.metrics import device_fields, emit_metrics_json
 from .optimizers import baseline_optimizer, server_sgd
 from .steps import make_eval_step, make_train_step
 from .train_state import create_train_state
@@ -40,9 +40,9 @@ class BaselineConfig:
     model: str = "resnet18"        # models/registry.py name
     seed: int = 0
     # True = run each epoch as ONE compiled program over a device-resident
-    # dataset (train/device_loop.py) — epochs at compute speed even on a
-    # remotely-attached chip. False = per-batch host dispatch (the
-    # reference's DataLoader shape, baseline_training.py:149-179).
+    # dataset (train/device_loop.py) — no per-batch host dispatch or
+    # upload. False = per-batch host dispatch (the reference's DataLoader
+    # shape, baseline_training.py:149-179).
     device_loop: bool = False
 
 
@@ -206,5 +206,6 @@ class BaselineTrainer:
                 "final_test_accuracy": self.metrics.test_accuracies[-1],
                 "all_test_accuracies": self.metrics.test_accuracies,
                 "final_train_loss": self.metrics.train_losses[-1],
+                **device_fields(),
             })
         return self.metrics

@@ -1,11 +1,10 @@
 """On-device epoch loop: a whole training epoch as ONE compiled program.
 
 The reference's trainer re-fed every batch from a host DataLoader each epoch
-(baseline_training.py:149-179), which is fine on a local CPU but pathological
-for a remotely-attached accelerator: each dispatch pays link latency, and the
-batch bytes pay link bandwidth. Here the dataset is uploaded ONCE
-(CIFAR-100's 50k uint8 images are ~150 MB — trivial for HBM), and each epoch
-runs as one XLA program:
+(baseline_training.py:149-179): one host dispatch and one host-to-device
+copy per batch, each of which the device can end up waiting for. Here the
+dataset is uploaded ONCE (CIFAR-100's 50k uint8 images are ~150 MB — trivial
+for HBM), and each epoch runs as one XLA program:
 
     device-side shuffle (jax.random.permutation)
     -> lax.scan over jitted train steps (gathered uint8 batches)
@@ -13,8 +12,7 @@ runs as one XLA program:
     -> scalar metrics out.
 
 Only a handful of scalars cross the host<->device link per epoch, so epoch
-time approaches pure compute (~1.7 s for ResNet-18/CIFAR-100 at the measured
-~30k images/s/chip) regardless of link quality.
+time approaches pure device time (not measured on the current installation).
 
 Epoch semantics match data/cifar.py's host iterator: full shuffle, then
 ``n // batch_size`` full batches with the remainder dropped

@@ -25,7 +25,7 @@ from ..parallel.mesh import make_mesh
 from ..parallel.sync_dp import make_sync_dp_step, shard_batch
 from ..ps.store import ParameterStore, StoreConfig
 from ..ps.worker import WorkerConfig, run_workers
-from ..utils.metrics import emit_metrics_json
+from ..utils.metrics import device_fields, emit_metrics_json
 from ..utils.pytree import flatten_params
 from .optimizers import server_sgd
 from .steps import make_eval_step
@@ -52,9 +52,7 @@ class DistributedConfig:
     overlap: bool = False
     delta_fetch: bool = True
     # Async store backend: 'python' (host numpy), 'native' (C++ arena), or
-    # 'device' (HBM-resident — zero host-link bytes per worker step; the
-    # only backend that runs reference-scale async on a remote-attached
-    # chip).
+    # 'device' (HBM-resident — zero host-link bytes per worker step).
     store_backend: str = "python"
     augment: bool = True
     num_classes: int = 100
@@ -165,6 +163,7 @@ class SyncTrainer:
 
         t_start = time.time()
         per_worker_epochs = []   # per epoch: {"loss": [N], "accuracy": [N]}
+        epoch_loss = None        # last epoch's mean train loss
         for epoch in range(start_epoch, cfg.num_epochs):
             t0 = time.time()
             losses = []
@@ -215,9 +214,10 @@ class SyncTrainer:
             tm_epoch.set(epoch + 1)
             if acc == acc:  # skip non-evaluating multihost ranks' NaN
                 tm_acc.set(acc)
+            epoch_loss = float(np.mean([float(l) for l in losses]))
             if jax.process_index() == 0:
                 print(f"[sync x{cfg.num_workers}] epoch {epoch + 1}: "
-                      f"loss {float(np.mean([float(l) for l in losses])):.4f} "
+                      f"loss {epoch_loss:.4f} "
                       f"test {acc:.2%} ({self.epoch_times[-1]:.1f}s)")
             if mgr is not None and jax.process_index() == 0:
                 # State is replicated; process 0's copy is the full model.
@@ -228,6 +228,7 @@ class SyncTrainer:
         if mgr is not None:
             mgr.close()
 
+        device = device_fields()
         server_metrics = {
             "mode": "sync",
             "total_workers": cfg.num_workers,
@@ -239,6 +240,8 @@ class SyncTrainer:
                 total / max(self.global_steps, 1), 6),
             "updates_per_second": round(self.global_steps / total, 3),
             "learning_rate": cfg.learning_rate,
+            "final_train_loss": epoch_loss,
+            **device,
         }
         if emit_metrics and jax.process_index() == 0:
             emit_metrics_json(server_metrics)
@@ -265,6 +268,7 @@ class SyncTrainer:
                     "batch_size": cfg.batch_size,
                     "learning_rate": cfg.learning_rate,
                     "num_epochs": cfg.num_epochs,
+                    **device,
                 }
                 if per_worker_epochs:
                     row.update({
@@ -361,7 +365,7 @@ class AsyncTrainer:
         finally:
             if ckpt is not None:
                 ckpt.stop(final_snapshot=True)
-        server_metrics = self.store.metrics()
+        server_metrics = {**self.store.metrics(), **device_fields()}
         if emit_metrics:
             emit_metrics_json(server_metrics)
             wc = WorkerConfig(batch_size=cfg.batch_size,

@@ -40,7 +40,7 @@ from ..parallel.tensor import shard_train_state
 from ..train.optimizers import server_sgd
 from ..train.steps import cross_entropy_loss, make_eval_step, make_train_step
 from ..train.train_state import create_train_state
-from ..utils.metrics import emit_metrics_json
+from ..utils.metrics import device_fields, emit_metrics_json
 from .train_state import TrainState
 
 # ViT shapes by registry name, CIFAR-resolution patch sizes.
@@ -188,6 +188,7 @@ class _EpochTrainer:
                                     if self.test_accuracies else 0.0),
             "all_test_accuracies": self.test_accuracies,
             **self._extra_metrics(),
+            **device_fields(),
         }
         if emit_metrics:
             emit_metrics_json(metrics)

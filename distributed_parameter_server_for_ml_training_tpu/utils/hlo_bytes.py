@@ -138,9 +138,8 @@ def sync_grad_mean_bytes(n_devices: int, size: int,
     fns = {"none": mean_none, "bf16": mean_bf16, "int8": mean_int8}
     out: dict = {}
     for name in modes:
-        from ..parallel.mesh import shard_map
-        sm = shard_map(fns[name], mesh=mesh, in_specs=(P(), P()),
-                       out_specs=P(), check_vma=False)
+        sm = jax.shard_map(fns[name], mesh=mesh, in_specs=(P(), P()),
+                           out_specs=P(), check_vma=False)
         hlo = jax.jit(sm).lower(g, key).compile().as_text()
         out[name] = collective_wire_bytes(hlo, n_devices)
     if ("bf16" in out and "none" in out

@@ -26,6 +26,17 @@ def emit_metrics_json(payload: dict, stream: IO | None = None) -> str:
     return line
 
 
+def device_fields() -> dict:
+    """The device this process computes on, as JAX reports it — merged
+    into every trainer/worker METRICS_JSON row so a number can never be
+    read without knowing which backend produced it."""
+    import jax
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices)}
+
+
 def parse_metrics_lines(text: str | Iterable[str]) -> list[dict]:
     """Extract all METRICS_JSON payloads from log text
     (parse_cloudwatch_logs.py:100-121 equivalent)."""

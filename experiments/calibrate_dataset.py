@@ -28,9 +28,10 @@ sys.path.insert(0, REPO)
 
 import jax  # noqa: E402
 
-jax.config.update("jax_compilation_cache_dir",
-                  os.environ.get("JAX_COMPILATION_CACHE_DIR",
-                                 os.path.join(REPO, ".jax_cache")))
+from distributed_parameter_server_for_ml_training_tpu.utils.compile_cache \
+    import enable_compile_cache  # noqa: E402
+
+enable_compile_cache()
 
 GRID = [
     # name, generator kwargs. Round-1 finding: the original defaults

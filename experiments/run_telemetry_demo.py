@@ -40,8 +40,7 @@ CLI = [sys.executable, "-m",
 def _env(n_devices: int = 1) -> dict:
     env = dict(os.environ,
                JAX_PLATFORMS="cpu",
-               PYTHONUNBUFFERED="1",
-               JAX_COMPILATION_CACHE_DIR=os.path.join(REPO, ".jax_cache"))
+               PYTHONUNBUFFERED="1")
     if n_devices > 1:
         env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
                             f" --xla_force_host_platform_device_count="

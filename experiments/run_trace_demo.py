@@ -49,8 +49,7 @@ CLI = [sys.executable, "-m",
 
 
 def _env() -> dict:
-    return dict(os.environ, JAX_PLATFORMS="cpu", PYTHONUNBUFFERED="1",
-                JAX_COMPILATION_CACHE_DIR=os.path.join(REPO, ".jax_cache"))
+    return dict(os.environ, JAX_PLATFORMS="cpu", PYTHONUNBUFFERED="1")
 
 
 def _free_port() -> int:

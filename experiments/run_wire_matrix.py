@@ -28,9 +28,10 @@ run warms it); the summary reports the MEDIAN with min-max spread, so the
 python-vs-native and codec columns carry error bars instead of riding on
 single-run noise.
 
-Workers run --platform cpu (the chip can't host N independent processes);
-the numbers measure the WIRE + store path, complementing the on-chip
-in-process records in experiments/results/calibrated/.
+This is a CPU demo, not a chip recipe: every child (server and workers)
+runs on the CPU backend — a chip belongs to one process at a time, so N
+independent worker processes cannot share it. The numbers measure the WIRE
++ store path on the host; they say nothing about the device.
 
 Run:  python experiments/run_wire_matrix.py [--quick] [--only async_4w...]
 """
@@ -62,8 +63,7 @@ def _free_port() -> int:
 
 
 def _env() -> dict:
-    return dict(os.environ, JAX_PLATFORMS="cpu", PYTHONUNBUFFERED="1",
-                JAX_COMPILATION_CACHE_DIR=os.path.join(REPO, ".jax_cache"))
+    return dict(os.environ, JAX_PLATFORMS="cpu", PYTHONUNBUFFERED="1")
 
 
 def _popen(cmd: list[str], log_path: str) -> subprocess.Popen:

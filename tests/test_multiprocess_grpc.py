@@ -31,7 +31,6 @@ def _cli_env():
         os.environ,
         JAX_PLATFORMS="cpu",
         PYTHONUNBUFFERED="1",
-        JAX_COMPILATION_CACHE_DIR=os.path.join(REPO, ".jax_cache"),
     )
 
 

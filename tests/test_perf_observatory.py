@@ -469,10 +469,22 @@ class TestBenchwatchRegression:
             "insufficient_history"
 
     def test_committed_ledger_is_schema_clean(self):
+        """The root holds no BENCH_r*.json any more (the earlier
+        installation's records went in PR 21; the driver's ledger takes
+        their place): whatever is there must be schema-clean, and the
+        watch must cope with there being no bench history at all."""
         repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         ledger = load_ledger(repo)
-        assert len(ledger["entries"]) >= 10
         assert ledger["malformed"] == []
+        assert not [e for e in ledger["entries"] if e["kind"] == "bench"]
+        assert check_regressions(ledger)["status"] == "pass"
+
+    def test_empty_ledger_passes(self, tmp_path):
+        ledger = load_ledger(str(tmp_path))
+        assert ledger["entries"] == [] and ledger["malformed"] == []
+        v = check_regressions(ledger)
+        assert v["status"] == "pass" and v["regressions"] == []
+        assert "pass" in render_markdown(v).lower()
 
 
 # -- cli status degradation ---------------------------------------------------

@@ -88,11 +88,9 @@ class TestInt8Ring:
             out = _int8_ring_allreduce_mean(vals[0], "data", n, key[0])
             return out[None]
 
-        from distributed_parameter_server_for_ml_training_tpu.parallel.mesh import (
-            shard_map)
-        fn = shard_map(body, mesh=mesh,
-                       in_specs=(P("data"), P("data")),
-                       out_specs=P("data"), check_vma=False)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(P("data"), P("data")),
+                           out_specs=P("data"), check_vma=False)
         keys = jax.random.split(jax.random.PRNGKey(7), n)
         return np.asarray(fn(values, keys))
 

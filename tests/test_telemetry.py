@@ -391,8 +391,11 @@ class TestBenchHardening:
         been — never a bare rc=1."""
         import bench
         monkeypatch.setattr(bench, "_fail_inject_remaining", 99)
-        monkeypatch.setattr(bench.time, "sleep", lambda s: None)
-        monkeypatch.setattr("sys.argv", ["bench.py", "--trials", "1"])
+        # --init-backoff 0: acquire_backend's sleep default is bound at
+        # definition time, so patching time.sleep never reached it and
+        # this test really slept 93 s.
+        monkeypatch.setattr("sys.argv", ["bench.py", "--trials", "1",
+                                         "--init-backoff", "0"])
         rc = bench.main()
         assert rc == 1
         out = capsys.readouterr().out
@@ -403,61 +406,40 @@ class TestBenchHardening:
         assert "injected backend init failure" in diag["error"]
 
 
-class TestBenchCpuFallback:
-    """bench.py must emit a parsed record even when the configured backend
-    stays unavailable through every retry: it falls back to
-    JAX_PLATFORMS=cpu and marks the record (ISSUE 2 satellite)."""
+class TestBenchNoCpuFallback:
+    """bench.py measures the chip: a run that finds no accelerator fails
+    with the diagnostic line and exit code 1 — it never re-runs on CPU and
+    never writes a CPU number under the device metric's name."""
 
-    def test_fallback_engages_after_exhausted_retries(self, monkeypatch):
+    def test_cpu_devices_are_refused(self):
         import bench
-        # 2 injected failures exhaust retries=1 (2 attempts); the fallback
-        # acquisition then succeeds against the real (cpu) backend.
-        monkeypatch.setattr(bench, "_fail_inject_remaining", 2)
-        devices, fallback = bench.acquire_backend_with_fallback(
-            retries=1, backoff=1.0, sleep=lambda s: None)
-        assert devices
-        assert fallback == "cpu"
+        import jax
+        with pytest.raises(RuntimeError, match="no accelerator") as ei:
+            bench.require_accelerator(jax.devices(), attempts=4)
+        assert ei.value.bench_attempts == 4
 
-    def test_no_fallback_when_primary_succeeds(self, monkeypatch):
-        import bench
-        monkeypatch.setattr(bench, "_fail_inject_remaining", 0)
-        devices, fallback = bench.acquire_backend_with_fallback(
-            retries=0, backoff=1.0, sleep=lambda s: None)
-        assert devices and fallback is None
-
-    def test_fallback_disabled_raises_primary_error(self, monkeypatch):
-        import bench
-        monkeypatch.setattr(bench, "_fail_inject_remaining", 99)
-        with pytest.raises(RuntimeError) as ei:
-            bench.acquire_backend_with_fallback(
-                retries=1, backoff=1.0, sleep=lambda s: None,
-                cpu_fallback=False)
-        assert ei.value.bench_attempts == 2
-
-    def test_silent_jax_level_cpu_fallback_is_marked(self, monkeypatch):
-        """ISSUE 14 hardening: xla_bridge can fail TPU init WITHOUT
-        raising — jax.devices() answers CpuDevice after a warning. With
-        nothing pinning JAX_PLATFORMS=cpu that is a fallback and must be
-        marked (or refused under --no-cpu-fallback), never recorded as a
-        chip number."""
+    def test_main_without_chip_emits_diagnostic_and_exits_1(
+            self, monkeypatch, capsys):
+        """xla_bridge can fail TPU init WITHOUT raising — jax.devices()
+        then answers CpuDevice. That must end the run at backend_init,
+        before any workload is built."""
         import bench
         monkeypatch.setattr(bench, "_fail_inject_remaining", 0)
-        monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-        devices, fallback = bench.acquire_backend_with_fallback(
-            retries=0, backoff=1.0, sleep=lambda s: None)
-        assert devices and devices[0].platform == "cpu"
-        assert fallback == "cpu"
-        with pytest.raises(RuntimeError, match="silently fell back"):
-            bench.acquire_backend_with_fallback(
-                retries=0, backoff=1.0, sleep=lambda s: None,
-                cpu_fallback=False)
+        monkeypatch.setattr("sys.argv", ["bench.py", "--trials", "1"])
+        assert bench.main() == 1
+        lines = capsys.readouterr().out.strip().splitlines()
+        diag = json.loads(lines[-1])
+        assert diag["ok"] is False
+        assert diag["stage"] == "backend_init"
+        assert "no accelerator" in diag["error"]
+        assert not any('"metric"' in ln for ln in lines)
 
-    def test_fallback_also_failing_raises_original_error(self, monkeypatch):
-        """When even the CPU fallback fails, the diagnostic must describe
-        the ORIGINAL failure (with its attempt count), not the fallback's."""
+    def test_fallback_surface_is_gone(self, monkeypatch):
         import bench
-        monkeypatch.setattr(bench, "_fail_inject_remaining", 99)
-        with pytest.raises(RuntimeError) as ei:
-            bench.acquire_backend_with_fallback(
-                retries=2, backoff=1.0, sleep=lambda s: None)
-        assert ei.value.bench_attempts == 3
+        assert not hasattr(bench, "acquire_backend_with_fallback")
+        monkeypatch.setattr("sys.argv", ["bench.py", "--no-cpu-fallback"])
+        with pytest.raises(SystemExit) as ei:
+            bench.main()
+        assert ei.value.code == 2  # argparse: unrecognized argument
+
+

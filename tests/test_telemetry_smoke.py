@@ -131,4 +131,9 @@ def test_cli_serve_worker_telemetry_smoke(capsys):
     assert rec["server_metrics"]["mode"] == "async"
     assert rec["server_metrics"]["global_steps_completed"] == 3
     assert len(rec["raw_worker_metrics"]) == 1
-    assert rec["raw_worker_metrics"][0]["local_steps_completed"] == 3
+    row = rec["raw_worker_metrics"][0]
+    assert row["local_steps_completed"] == 3
+    # ...and say which device the worker computed on (PR 21).
+    assert (row["platform"], row["device_id"]) == ("cpu", 0)
+    assert row["device_count"] == 8 and row["device_kind"]
+    assert row["final_train_loss"] > 0

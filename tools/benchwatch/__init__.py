@@ -1,17 +1,16 @@
 """benchwatch: schema-validated bench ledger + regression watch.
 
-The committed ``BENCH_r*.json`` / ``MULTICHIP_r*.json`` records are the
-repo's only longitudinal performance record, and until now nothing read
-them: a PR could halve throughput and tier-1 would stay green. This
-tool ingests the ledger, validates every record against the schema the
-bench harness actually emits, and runs a noise-tolerant regression
-check:
+Root-level ``BENCH_r*.json`` / ``MULTICHIP_r*.json`` records are a
+longitudinal performance record. This tool ingests whatever ledger is
+there (none at all is a pass, not an error), validates every record
+against the schema the bench harness actually emits, and runs a
+noise-tolerant regression check:
 
 - **Usable** records: ``rc == 0``, non-null ``parsed``, and no
   ``platform_fallback`` marker (a CPU-fallback number is not comparable
   to TPU history). Unusable records are SKIPPED AND REPORTED with a
-  reason — an rc!=0 TPU-init flake (BENCH_r05) is not a regression, but
-  it is not silently dropped either.
+  reason — an rc!=0 backend-init flake is not a regression, but it is
+  not silently dropped either.
 - **Regression** per metric: the median of the newest
   ``recent_window`` usable values vs the median of the
   ``baseline_window`` values before them; flagged when recent <
