@@ -1179,18 +1179,16 @@ def _telemetry_session(args, role: str):
 @contextmanager
 def _profiler_session(profile_dir: str | None):
     """``--profile-dir``: bracket the hot loop with
-    ``jax.profiler.start_trace``/``stop_trace`` (via utils/tracing.py) so
-    an XLA-level timeline (MXU utilization, HBM traffic, collectives)
+    ``jax.profiler.start_trace``/``stop_trace`` (telemetry/profiler.py)
+    so an XLA-level timeline (MXU utilization, HBM traffic, collectives)
     lands beside the framework-level span traces. No-op when unset."""
     if not profile_dir:
         yield
         return
-    import os as _os
-    _os.makedirs(profile_dir, exist_ok=True)
-    from .utils.tracing import trace
+    from .telemetry.profiler import capture
     print(f"profiler: tracing into {profile_dir}", file=sys.stderr,
           flush=True)
-    with trace(profile_dir):
+    with capture(profile_dir):
         yield
 
 

@@ -138,10 +138,14 @@ def make_sync_dp_step(mesh: Mesh, *, axis: str = DATA_AXIS,
         # (zero pad = black), then per-channel standardize. Gathers run
         # on uint8 — bit-identical floats at 1/4 the bandwidth
         # (train/steps.py).
-        images = images_u8
-        if augment:
-            images = augment_batch(rng, images)
-        images = standardize(to_float(images))
+        # The four named scopes (also in train/steps.py:make_train_step)
+        # tag each instruction's metadata with the phase it belongs to,
+        # for a profile's readers; they cost nothing at run time.
+        with jax.named_scope("augment"):
+            images = images_u8
+            if augment:
+                images = augment_batch(rng, images)
+            images = standardize(to_float(images))
 
         def loss_fn(params):
             from ..train.steps import _variables
@@ -152,8 +156,9 @@ def make_sync_dp_step(mesh: Mesh, *, axis: str = DATA_AXIS,
             loss = cross_entropy_loss(outputs, labels)
             return loss, (outputs, mutated.get("batch_stats", {}))
 
-        (loss, (logits, new_stats)), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(state.params)
+        with jax.named_scope("forward_backward"):
+            (loss, (logits, new_stats)), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(state.params)
 
         # == server.py:145-169 aggregate_gradients_sync, as one all-reduce,
         # with compression on the wire (the reference cast fp16,
@@ -161,21 +166,23 @@ def make_sync_dp_step(mesh: Mesh, *, axis: str = DATA_AXIS,
         #   bf16/fp16 -> reduced-precision pmean (half the ICI bytes)
         #   int8      -> quantized reduce-scatter + all-gather ring
         #                (~1/2 bf16's bytes, N-independent; EQuARX-style)
-        if compression == "int8":
-            # Dedicated PRNG stream: augment_batch consumes split(rng)
-            # (= fold_in(rng, 0/1)), so the ring's hop seeds must branch
-            # off a tag those small indices can never produce.
-            grads = _int8_ring_allreduce_mean(
-                grads, axis, mesh.shape[axis],
-                jax.random.fold_in(rng, 0x7FFFFFFF))
-        else:
-            grads = compress_for_allreduce(grads, compression)
-            grads = jax.lax.pmean(grads, axis)
-            grads = decompress_from_allreduce(grads, compression)
+        with jax.named_scope("exchange"):
+            if compression == "int8":
+                # Dedicated PRNG stream: augment_batch consumes split(rng)
+                # (= fold_in(rng, 0/1)), so the ring's hop seeds must
+                # branch off a tag those small indices can never produce.
+                grads = _int8_ring_allreduce_mean(
+                    grads, axis, mesh.shape[axis],
+                    jax.random.fold_in(rng, 0x7FFFFFFF))
+            else:
+                grads = compress_for_allreduce(grads, compression)
+                grads = jax.lax.pmean(grads, axis)
+                grads = decompress_from_allreduce(grads, compression)
 
         # == server.py:126-143 apply_gradients, replicated on every worker.
-        state = state.apply_gradients(grads=grads)
-        state = state.replace(batch_stats=new_stats)
+        with jax.named_scope("update"):
+            state = state.apply_gradients(grads=grads)
+            state = state.replace(batch_stats=new_stats)
 
         acc = jnp.mean(jnp.argmax(logits, -1) == labels)
         metrics = {

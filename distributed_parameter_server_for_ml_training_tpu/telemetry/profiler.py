@@ -4,10 +4,9 @@
 nobody parsed; this module is the write half of the perf observatory
 (the read half is :mod:`..analysis.device_profile`):
 
-- :func:`capture` — the capture bracket (same
-  ``jax.profiler.start_trace`` / ``stop_trace`` pair as
-  ``utils.tracing.trace``, re-exported here so profiler consumers have
-  one import surface) plus dump discovery.
+- :func:`capture` — the capture bracket, the package's one wrapper of
+  ``jax.profiler.start_trace`` / ``stop_trace`` (``--profile-dir``, the
+  profile trigger engine, the experiments), plus dump discovery.
 - :func:`compiled_cost` — ``lowered.compile().cost_analysis()`` flops +
   bytes for ONE compiled step. Always compile the SINGLE step for this
   (not a scanned window): XLA reports the whole program, and a
@@ -20,10 +19,9 @@ nobody parsed; this module is the write half of the perf observatory
 
 from __future__ import annotations
 
+import contextlib
 import glob
 import os
-
-from ..utils.tracing import trace as _trace
 
 __all__ = [
     "PEAK_FLOPS_BY_KIND",
@@ -48,15 +46,22 @@ PEAK_FLOPS_BY_KIND = {
 }
 
 
+@contextlib.contextmanager
 def capture(logdir: str):
     """Profiler capture bracket: ``with capture(dir): hot_loop()``.
 
     Creates ``logdir`` and brackets the body with
     ``jax.profiler.start_trace``/``stop_trace``; the dump lands under
     ``logdir/plugins/profile/<timestamp>/`` (one xplane.pb + one
-    Chrome-format ``*.trace.json.gz`` per host)."""
+    Chrome-format ``*.trace.json.gz`` per host). End the body with
+    ``jax.block_until_ready`` so the traced work is inside it."""
+    import jax
     os.makedirs(logdir, exist_ok=True)
-    return _trace(logdir)
+    jax.profiler.start_trace(logdir)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
 
 
 def find_profile_dumps(logdir: str) -> list[str]:

@@ -16,7 +16,7 @@ Two usage shapes:
   nanosecond is on-budget (store push/fetch). ``now`` is re-exported
   ``time.perf_counter`` so call sites don't import ``time`` twice.
 
-For deep profiler traces use utils/tracing.py (jax.profiler) — spans and
+For deep profiler traces use telemetry/profiler.py (jax.profiler) — spans and
 traces answer different questions (always-on time-series vs one-off
 timeline).
 """

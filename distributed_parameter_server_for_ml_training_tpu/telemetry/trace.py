@@ -25,13 +25,19 @@ was doing. This module is that missing causal layer:
 Tracing is OFF by default: every span site costs one module-global check
 plus a shared no-op context manager (~100 ns), so the always-on metrics
 overhead budget (docs/OBSERVABILITY.md, the <2% tier-1 guard) is
-untouched. Enable with ``--trace`` (CLI) or :func:`enable_tracing`.
+untouched. Enable with ``--trace`` (CLI) or :func:`enable_tracing`. A
+call site whose spans are few enough to record in every run says so with
+``trace_span(..., always=True)``: the sync trainer's phase spans (three a
+step, five an epoch) go into the same ring whether tracing is on or not,
+so a benchmark that builds the trainer itself finds them there.
 
-Span timestamps are ``time.time()`` (wall clock — comparable across the
-processes of one host, which is what the multi-process demo assembles);
-durations are ``perf_counter`` deltas (monotonic). Span names come from
-:data:`SPAN_CATALOG`; ``tests/test_docs_drift.py`` pins catalog, call
-sites, and docs/OBSERVABILITY.md to each other.
+Every span carries two starts: ``ts`` is ``time.time()`` (wall clock —
+comparable across the processes of one host, which is what the
+multi-process demo assembles) and ``mono`` is ``time.monotonic()``, the
+clock ``dur`` is taken on, so ``mono + dur`` is the span's end on the
+clock a benchmark's window edges and a device trace can be joined to.
+Span names come from :data:`SPAN_CATALOG`; ``tests/test_docs_drift.py``
+pins catalog, call sites, and docs/OBSERVABILITY.md to each other.
 """
 
 from __future__ import annotations
@@ -39,12 +45,13 @@ from __future__ import annotations
 import atexit
 import json
 import os
+import random
 import signal
 import sys
 import threading
 import time
 from collections import deque
-from time import perf_counter as _pc
+from time import monotonic as _mono
 from typing import NamedTuple
 
 __all__ = [
@@ -103,7 +110,25 @@ SPAN_CATALOG = {
     "store.apply": "parameter update apply (sync round aggregate+apply "
                    "or async staleness-weighted apply; attrs backend, "
                    "staleness/weight in async mode)",
-    "trainer.step": "SPMD sync-trainer step (root; attr mode=sync)",
+    "trainer.epoch": "one pass of the sync trainer's epoch loop (root; "
+                     "attrs epoch, first_step); its self time is what "
+                     "the phase spans below leave unnamed",
+    "trainer.input": "host batch gather + device_put for one step "
+                     "(attrs epoch, step, bytes)",
+    "trainer.step": "SPMD sync-trainer step call, dispatch-to-return "
+                    "(attrs epoch, step, mode=sync)",
+    "trainer.epoch_sync": "the epoch's first wait for the device: the "
+                          "per-worker fetches, each step's as it ends, "
+                          "and before the last step's a "
+                          "block_until_ready on its metrics (attr "
+                          "ready_mono: the monotonic time of its "
+                          "return; attr epoch)",
+    "trainer.eval": "sync-trainer test pass: batches, dispatch, the "
+                    "blocking count fetch (attrs epoch, batches)",
+    "trainer.epoch_report": "per-step loss fetches, gauges and the "
+                            "epoch line (attr epoch)",
+    "trainer.checkpoint": "CheckpointManager.save at an epoch end "
+                          "(attr epoch)",
 }
 
 
@@ -115,8 +140,27 @@ class TraceContext(NamedTuple):
     parent_id: str | None = None
 
 
+# A span that is recorded in every run must make no system call: on a
+# host where one costs 6 us, ``os.urandom`` and ``os.getpid`` were half of
+# a span's 20 us. Ids come from a generator of this module's own (seeded
+# from ``os.urandom``; a program's ``random.seed`` cannot make two
+# processes draw the same ids) and the pid is read once, both again in a
+# forked child.
+_IDS = random.Random()
+_PID = os.getpid()
+
+
+def _after_fork() -> None:
+    global _PID
+    _IDS.seed()
+    _PID = os.getpid()
+
+
+os.register_at_fork(after_in_child=_after_fork)
+
+
 def _new_id() -> str:
-    return os.urandom(8).hex()
+    return "%016x" % _IDS.getrandbits(64)
 
 
 class FlightRecorder:
@@ -302,11 +346,11 @@ class _Span:
                                     parent.span_id)
         _stack().append(self.ctx)
         self._ts = time.time()
-        self._t0 = _pc()
+        self._t0 = _mono()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        dur = _pc() - self._t0
+        dur = _mono() - self._t0
         st = _stack()
         if st and st[-1] is self.ctx:
             st.pop()
@@ -320,9 +364,10 @@ class _Span:
             "span_id": self.ctx.span_id,
             "parent_id": self.ctx.parent_id,
             "ts": self._ts,
+            "mono": self._t0,
             "dur": dur,
             "role": _RECORDER.role,
-            "pid": os.getpid(),
+            "pid": _PID,
             "tid": threading.get_ident(),
         }
         if self.attrs:
@@ -331,15 +376,18 @@ class _Span:
         return False
 
 
-def trace_span(name: str, root: bool = False, **attrs):
+def trace_span(name: str, root: bool = False, *, always: bool = False,
+               **attrs):
     """Context manager recording one flight-recorder span around the body.
 
-    No-op (shared singleton, ~100 ns) when tracing is disabled. ``root``
+    No-op (shared singleton, ~100 ns) when tracing is disabled, unless the
+    call site passes ``always=True``: for spans coarse enough (a few a
+    step) that the bounded ring can take them in every run. ``root``
     opens a fresh ``trace_id`` regardless of the current context (worker
-    step / trainer step roots); otherwise the span parents on the
+    step / trainer epoch roots); otherwise the span parents on the
     thread-local current context (or becomes a root if there is none).
     """
-    if not _ENABLED:
+    if not (_ENABLED or always):
         return _NULL_SPAN
     return _Span(name, root, attrs)
 

@@ -32,6 +32,10 @@ from .steps import make_eval_step
 from .train_state import create_train_state
 
 
+#: images one evaluation batch of the sync trainer holds
+EVAL_BATCH = 1000
+
+
 @dataclass
 class DistributedConfig:
     mode: str = "sync"             # SERVER_MODE (server.py:407-417)
@@ -155,75 +159,105 @@ class SyncTrainer:
         tm_gstep = reg.gauge("dps_store_global_step", backend="spmd")
 
         # Goodput ledger (telemetry/goodput.py): the sync trainer's wall
-        # classifies into compute / checkpoint / other — no comms phases
-        # exist outside the compiled program, so a large residual here
-        # means host-side input/bookkeeping drag.
+        # classifies into compute / checkpoint / other. The host enqueues
+        # a step in about a millisecond and the device works while the
+        # host waits at the epoch end, so that wait is compute too; the
+        # residual is host-side input and bookkeeping.
         gp = GoodputAccount(reg)
         gp.start_wall()
 
+        # Phase spans (telemetry/trace.py): one root a pass of the loop
+        # below and one child a phase, all on this thread and recorded in
+        # every run (always=True), so that the root's self time is what is
+        # still unnamed. docs/OBSERVABILITY.md has the table.
+        def phase(name, **attrs):
+            return trace_span(name, always=True, **attrs)
+
+        # make_batches drops the remainder: this many batches an epoch
+        steps_per_epoch = len(self.dataset.x_train) // global_batch
+        eval_batches = -(-len(self.dataset.x_test) // EVAL_BATCH)
         t_start = time.time()
         per_worker_epochs = []   # per epoch: {"loss": [N], "accuracy": [N]}
         epoch_loss = None        # last epoch's mean train loss
         for epoch in range(start_epoch, cfg.num_epochs):
-            t0 = time.time()
-            losses = []
-            wl, wa = [], []
-            for xb, yb in make_batches(self.dataset.x_train,
+            with trace_span("trainer.epoch", root=True, always=True,
+                            epoch=epoch, first_step=self.global_steps):
+                t0 = time.time()
+                losses = []
+                per_worker = []   # per step: ([N] losses, [N] accuracies)
+                batches = make_batches(self.dataset.x_train,
                                        self.dataset.y_train, global_batch,
-                                       seed=cfg.seed * 997 + epoch):
-                bi, bl = self._shard((xb, yb))
-                t_step = _tnow()
-                # Root span per SPMD step: there are no comms phases here
-                # (the all-reduce is inside the compiled program), so the
-                # trace's value is the step-time series itself — same
-                # dispatch-to-return caveat as the histogram below.
-                with trace_span("trainer.step", root=True, mode="sync",
-                                step=self.global_steps, epoch=epoch), \
+                                       seed=cfg.seed * 997 + epoch)
+                for _ in range(steps_per_epoch):
+                    with phase("trainer.input", epoch=epoch,
+                               step=self.global_steps) as sp:
+                        xb, yb = next(batches)
+                        sp.attrs["bytes"] = xb.nbytes + yb.nbytes
+                        bi, bl = self._shard((xb, yb))
+                    t_step = _tnow()
+                    with phase("trainer.step", mode="sync", epoch=epoch,
+                               step=self.global_steps), gp.span("compute"):
+                        self.state, m = self._step(self.state, bi, bl, rng)
+                    losses.append(m["loss"])
+                    tm_step_s.observe(_tnow() - t_step)
+                    tm_steps.inc()
+                    tm_images.inc(len(xb))
+                    if not self.multihost:
+                        # Multihost: the [N] vectors span processes and
+                        # can't be fetched locally; per-worker rows stay
+                        # derived.
+                        per_worker.append((m["worker_loss"],
+                                           m["worker_accuracy"]))
+                    self.global_steps += 1
+                    tm_gstep.set(self.global_steps)
+                    gp.tick_wall()
+                # The epoch's first wait for the device. Each step's
+                # per-worker rows are fetched as that step ends, while
+                # the device works on the steps after it; only the last
+                # step's wait for the whole epoch. Between them the
+                # block_until_ready, whose return is the moment the host
+                # knows the epoch's steps are done (a trace's readers
+                # anchor the device's clock on it), gives multihost runs,
+                # which fetch nothing, the same span.
+                with phase("trainer.epoch_sync", epoch=epoch) as sp, \
                         gp.span("compute"):
-                    self.state, m = self._step(self.state, bi, bl, rng)
-                losses.append(m["loss"])
-                # Span = dispatch-to-return; appending m["loss"] keeps a
-                # handle the epoch print later forces, and the per-epoch
-                # wall time (t0 delta) bounds any async-dispatch slack.
-                tm_step_s.observe(_tnow() - t_step)
-                tm_steps.inc()
-                tm_images.inc(len(xb))
-                if not self.multihost:
-                    # Multihost: the [N] vectors span processes and can't
-                    # be fetched locally; per-worker rows stay derived.
-                    wl.append(m["worker_loss"])
-                    wa.append(m["worker_accuracy"])
-                self.global_steps += 1
-                tm_gstep.set(self.global_steps)
+                    rows = [np.asarray(p, np.float32)
+                            for p in per_worker[:-1]]
+                    jax.block_until_ready(losses[-1:])
+                    sp.attrs["ready_mono"] = time.monotonic()
+                    rows += [np.asarray(p, np.float32)
+                             for p in per_worker[-1:]]
+                    if rows:
+                        loss, accuracy = np.mean(rows, axis=0)
+                        per_worker_epochs.append(
+                            {"loss": loss, "accuracy": accuracy})
+                # In multihost mode only rank 0 pays for the full test
+                # pass — the state is replicated, so the others' evals
+                # would be identical duplicated work on the critical path.
+                if self.multihost and jax.process_index() != 0:
+                    acc = float("nan")
+                else:
+                    with phase("trainer.eval", epoch=epoch,
+                               batches=eval_batches), gp.span("compute"):
+                        acc = self.evaluate()
+                with phase("trainer.epoch_report", epoch=epoch):
+                    self.epoch_times.append(time.time() - t0)
+                    self.test_accuracies.append(acc)
+                    tm_epoch.set(epoch + 1)
+                    if acc == acc:  # skip non-evaluating ranks' NaN
+                        tm_acc.set(acc)
+                    epoch_loss = float(np.mean([float(l) for l in losses]))
+                    if jax.process_index() == 0:
+                        print(f"[sync x{cfg.num_workers}] epoch {epoch + 1}: "
+                              f"loss {epoch_loss:.4f} "
+                              f"test {acc:.2%} ({self.epoch_times[-1]:.1f}s)")
+                if mgr is not None and jax.process_index() == 0:
+                    # State is replicated; process 0's copy is the full
+                    # model.
+                    with phase("trainer.checkpoint", epoch=epoch), \
+                            gp.span("checkpoint"):
+                        mgr.save(self.state)
                 gp.tick_wall()
-            if wl:
-                per_worker_epochs.append({
-                    "loss": np.mean(np.asarray(wl, np.float32), axis=0),
-                    "accuracy": np.mean(np.asarray(wa, np.float32), axis=0),
-                })
-            # In multihost mode only rank 0 pays for the full test pass —
-            # the state is replicated, so the others' evals would be
-            # identical duplicated work on the critical path.
-            if self.multihost and jax.process_index() != 0:
-                acc = float("nan")
-            else:
-                with gp.span("compute"):
-                    acc = self.evaluate()
-            self.epoch_times.append(time.time() - t0)
-            self.test_accuracies.append(acc)
-            tm_epoch.set(epoch + 1)
-            if acc == acc:  # skip non-evaluating multihost ranks' NaN
-                tm_acc.set(acc)
-            epoch_loss = float(np.mean([float(l) for l in losses]))
-            if jax.process_index() == 0:
-                print(f"[sync x{cfg.num_workers}] epoch {epoch + 1}: "
-                      f"loss {epoch_loss:.4f} "
-                      f"test {acc:.2%} ({self.epoch_times[-1]:.1f}s)")
-            if mgr is not None and jax.process_index() == 0:
-                # State is replicated; process 0's copy is the full model.
-                with gp.span("checkpoint"):
-                    mgr.save(self.state)
-            gp.tick_wall()
         total = time.time() - t_start
         if mgr is not None:
             mgr.close()
@@ -294,7 +328,7 @@ class SyncTrainer:
             state = fetch_replicated(self.state)
         correct = total = 0
         for xb, yb in make_batches(self.dataset.x_test, self.dataset.y_test,
-                                   1000, shuffle=False,
+                                   EVAL_BATCH, shuffle=False,
                                    drop_remainder=False):
             c, t = self._eval_step(state, xb, yb)
             correct += int(c)

@@ -84,10 +84,11 @@ def make_train_step(augment: bool = True,
         # first (pure index permutations, zero pad in either domain) at 1/4
         # the gather bandwidth; the two batched gathers once cost ~45% of
         # the ResNet-18 step.
-        images = images_u8
-        if augment:
-            images = augment_batch(rng, images)
-        images = standardize(to_float(images))
+        with jax.named_scope("augment"):
+            images = images_u8
+            if augment:
+                images = augment_batch(rng, images)
+            images = standardize(to_float(images))
 
         if moe_aux_weight is not None:
             def loss_fn(p):
@@ -105,16 +106,22 @@ def make_train_step(augment: bool = True,
                               mutated.get("batch_stats", {}),
                               {"ce": ce, "aux": aux, "layers": layers})
             grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
-            (loss, (logits, new_stats, moe)), grads = grad_fn(state.params)
+            with jax.named_scope("forward_backward"):
+                (loss, (logits, new_stats, moe)), grads = grad_fn(
+                    state.params)
         else:
             grad_fn = jax.value_and_grad(
                 lambda p: _forward_loss(state, p, images, labels),
                 has_aux=True)
-            (loss, (logits, new_stats)), grads = grad_fn(state.params)
+            with jax.named_scope("forward_backward"):
+                (loss, (logits, new_stats)), grads = grad_fn(state.params)
             moe = None
 
-        state = state.apply_gradients(grads=grads)
-        state = state.replace(batch_stats=new_stats)
+        # "exchange", the third scope of parallel/sync_dp.py's step, has
+        # nothing to hold on one chip
+        with jax.named_scope("update"):
+            state = state.apply_gradients(grads=grads)
+            state = state.replace(batch_stats=new_stats)
         accuracy = jnp.mean(jnp.argmax(logits, -1) == labels)
         metrics = {"loss": loss, "accuracy": accuracy}
         if moe is not None and moe["layers"]:

@@ -54,7 +54,7 @@ def parse_metrics_lines(text: str | Iterable[str]) -> list[dict]:
 class Stopwatch:
     """Coarse wall-clock timing, the reference's only 'profiler'
     (SURVEY.md §5.1: time.time() deltas). For real tracing use
-    utils/tracing.py (jax.profiler)."""
+    telemetry/profiler.py (jax.profiler)."""
 
     def __init__(self):
         self.t0 = time.time()

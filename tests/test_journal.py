@@ -175,25 +175,33 @@ def _snapshot_payload(i, n_events=20):
 
 
 def test_retention_downsamples_into_coarse_tier(tmp_path):
+    # Retention is enforced when a segment is sealed by rotation, so the
+    # sizes must force a rotation with more than the cap sealed whatever
+    # the record length: every line carries the pid, and with 39 records
+    # (12 KB, just under three segments for a pid of one to three digits)
+    # a short pid left the third segment active and nothing to compact.
+    # 59 records are 19 KB: the third rotation finds 11 KB sealed.
+    n = 60
     w = _writer(tmp_path, max_segment_bytes=4096, retention_bytes=8192,
                 coarse_keep_every=5)
-    for i in range(1, 40):
+    for i in range(1, n):
         w.append("snapshot", _snapshot_payload(i))
         if i % 10 == 0:
             w.append("alert", {"rule": f"r{i}", "state": "fired"})
     w.seal()
     names = os.listdir(tmp_path)
-    coarse = [n for n in names if n.endswith(".coarse.jsonl")]
-    raw = [n for n in names if n.endswith(".jsonl") and n not in coarse]
+    coarse = [name for name in names if name.endswith(".coarse.jsonl")]
+    raw = [name for name in names
+           if name.endswith(".jsonl") and name not in coarse]
     assert coarse, "retention never compacted a segment"
-    raw_bytes = sum(os.path.getsize(tmp_path / n) for n in raw)
+    raw_bytes = sum(os.path.getsize(tmp_path / name) for name in raw)
     assert raw_bytes <= 8192 + 4096  # cap + one active segment of slack
     # ALL non-snapshot events survive downsampling — they ARE the record.
     alerts = read_journal(str(tmp_path), types=("alert",))
-    assert [r["rule"] for r in alerts] == ["r10", "r20", "r30"]
+    assert [r["rule"] for r in alerts] == [f"r{i}" for i in range(10, n, 10)]
     # snapshots thinned, not emptied
     snaps = read_journal(str(tmp_path), types=("snapshot",))
-    assert 0 < len(snaps) < 39
+    assert 0 < len(snaps) < n - 1
 
 
 def test_downsample_percentiles_stay_exact(tmp_path):
