@@ -4,7 +4,9 @@ Drives both exchange paths once through the normal CLI at the full width of
 ResNet-18 / CIFAR-100-shaped input (bf16, per-worker batch 128, augmentation
 on, a few steps), plus the Pallas kernels against their jax.numpy references:
 
-    kernels     flash attention fwd/bwd, int8 quantize family, DeviceCodec
+    kernels     flash and short attention fwd/bwd, the ViT-B/16 gradient with
+                the fused core against dense_core, int8 quantize family,
+                DeviceCodec
     sync        cli train --mode sync --workers <chips>
     sync-int8   the same with --compression int8 (quantize inside shard_map)
     async       cli train --mode async --workers max(2, <chips>)
@@ -52,7 +54,8 @@ _LIVE: dict[subprocess.Popen, str] = {}   # running child -> log name
 #: The kernel child's checks, by name; the parent requires every one.
 KERNEL_CHECKS = (
     "flash_fp32_T256", "flash_fp32_T256_causal", "flash_bf16_T4097",
-    "flash_bf16_T1024_causal", "quantize_2359296",
+    "flash_bf16_T1024_causal", "short_attn_bf16_T197",
+    "vit_b16_grads_fused_vs_dense", "quantize_2359296",
     "quantize_non_multiple_of_128", "device_codec_resnet18_tree")
 
 
@@ -376,7 +379,9 @@ def main() -> int:
 
 # -- the kernel child (the only code here that imports jax) -------------------
 
-def _child_kernels() -> int:
+def _child_kernels(only: tuple = ()) -> int:
+    """Every kernel check, or those named in ``only`` (for a builder who
+    iterates on one kernel; the parent always asks for all)."""
     import traceback
     from functools import partial
 
@@ -385,13 +390,13 @@ def _child_kernels() -> int:
     import numpy as np
 
     from distributed_parameter_server_for_ml_training_tpu.models import (
-        ResNet18)
+        ResNet18, get_model)
     from distributed_parameter_server_for_ml_training_tpu.ops import (
-        device_codec as dc)
+        attention as at, device_codec as dc)
     from distributed_parameter_server_for_ml_training_tpu.ops.compression \
         import ErrorFeedback, compress_push
     from distributed_parameter_server_for_ml_training_tpu.ops.pallas import (
-        flash_attention as fa, quantize as qz)
+        flash_attention as fa, quantize as qz, short_attention as sa)
     from distributed_parameter_server_for_ml_training_tpu.parallel \
         .ring_attention import dense_attention
     from distributed_parameter_server_for_ml_training_tpu.utils \
@@ -409,6 +414,7 @@ def _child_kernels() -> int:
         return 1
     # A pass must mean the kernel ran: no interpreter, no jnp stand-in.
     assert fa.INTERPRET is False and fa._on_tpu() and qz._on_tpu()
+    assert sa.INTERPRET is False and at._on_tpu()
     mosaic = 'custom_call_target="tpu_custom_call"'
     failed = []
 
@@ -429,6 +435,8 @@ def _child_kernels() -> int:
         assert name in KERNEL_CHECKS, name
 
         def deco(fn):
+            if only and name not in only:
+                return
             t0 = time.perf_counter()
             try:
                 info = fn()
@@ -499,6 +507,115 @@ def _child_kernels() -> int:
         lambda: flash_case(2, 4097, 12, 64, jnp.bfloat16, False, 3e-2))
     check("flash_bf16_T1024_causal")(
         lambda: flash_case(2, 1024, 12, 64, jnp.bfloat16, True, 3e-2))
+
+    def short_case(b, t, h, d, tol):
+        """The fused short-sequence kernel as ``attention_core`` calls it
+        (``[B, T, 3*H*D]`` in, ``[B, T, H*D]`` out, ``d qkv`` back) against
+        plain softmax attention in true fp32 matmuls, and ``dense_core``'s
+        distance from the same reference beside it."""
+        qkv = jax.random.normal(jax.random.PRNGKey(t), (b, t, 3 * h * d),
+                                jnp.bfloat16)
+        cot = jax.random.normal(jax.random.PRNGKey(t + 1), (b, t, h * d),
+                                jnp.bfloat16)
+
+        def weighted(attn, qkv, cot):
+            return jnp.sum(attn(qkv).astype(jnp.float32)
+                           * cot.astype(jnp.float32))
+
+        def split(attn):
+            def on_qkv(qkv):
+                x = qkv.reshape(b, t, 3, h, d)
+                return attn(x[:, :, 0], x[:, :, 1], x[:, :, 2]).reshape(
+                    b, t, h * d)
+            return on_qkv
+
+        assert at.select_core(on_tpu=True, causal=False, dtype=qkv.dtype,
+                              t=t, num_heads=h, head_dim=d) == "fused_short"
+        fused = partial(at.attention_core, num_heads=h)
+        both = lambda attn: lambda qkv, cot: (  # noqa: E731
+            attn(qkv), jax.grad(partial(weighted, attn))(qkv, cot))
+        exe, compile_s, calls = compiled(both(fused), qkv, cot)
+        assert calls >= 2, calls
+        # the kernels' names reach the compiled program (in a whole train
+        # step they are the instructions' names, PERF.md section 3)
+        text = exe.as_text()
+        assert "short_attention_fwd" in text and "short_attention_bwd" in text
+        got = exe(qkv, cot)
+        dense_bf16 = jax.jit(both(split(at.dense_core)))(qkv, cot)
+        with jax.default_matmul_precision("highest"):
+            want = jax.jit(both(split(dense_attention)))(
+                qkv.astype(jnp.float32), cot.astype(jnp.float32))
+        errs = {k: rel_err(g, w) for k, g, w in zip(("o", "dqkv"), got, want)}
+        dense_errs = {k: rel_err(g, w)
+                      for k, g, w in zip(("o", "dqkv"), dense_bf16, want)}
+        assert max(errs.values()) <= tol, (errs, tol)
+        return {"shape": [b, t, h, d], "compile_s": round(compile_s, 2),
+                "mosaic_calls": calls, "tol": tol,
+                "max_err_over_max_ref": {k: round(v, 5)
+                                         for k, v in errs.items()},
+                "dense_core_max_err_over_max_ref": {
+                    k: round(v, 5) for k, v in dense_errs.items()}}
+
+    # ViT-B/16 @224 px, the benchmark's shape: 197 tokens overhang the
+    # kernel's 256-row blocks, so this is also the check that what VMEM
+    # held beyond row 197 reaches no result.
+    check("short_attn_bf16_T197")(
+        lambda: short_case(8, 197, 12, 64, 3e-2))
+
+    def vit_grads_case(batch=32):
+        """`correct` has no independent reference yet (PERF.md section 7):
+        the whole ViT-B/16 loss gradient at published widths on one seeded
+        batch, with the fused kernel (what the registry model compiles to
+        here) against the same model with ``dense_core``, and both against
+        the fp32 model in true fp32 matmuls. Per parameter tensor:
+        max |a - b| / max |b|."""
+        from flax.traverse_util import flatten_dict
+        from distributed_parameter_server_for_ml_training_tpu.train.steps \
+            import cross_entropy_loss
+
+        fused = get_model("vit_b16", num_classes=1000, dtype=jnp.bfloat16,
+                          image_size=224)
+        dense = fused.clone(attention_fn=at.dense_core)
+        exact = get_model("vit_b16", num_classes=1000, dtype=jnp.float32,
+                          image_size=224).clone(attention_fn=dense_attention)
+        ks = jax.random.split(jax.random.PRNGKey(27), 3)
+        images = jax.random.normal(ks[0], (batch, 224, 224, 3), jnp.float32)
+        labels = jax.random.randint(ks[1], (batch,), 0, 1000)
+        params = jax.jit(partial(fused.init, train=False))(
+            ks[2], images[:1])["params"]
+
+        def grads(model):
+            return jax.grad(lambda p: cross_entropy_loss(
+                model.apply({"params": p}, images, train=True), labels))
+
+        exe, compile_s, calls = compiled(grads(fused), params)
+        assert calls == 24, calls
+        g_fused = flatten_dict(exe(params), sep="/")
+        exe_dense, _s, dense_calls = compiled(grads(dense), params)
+        assert dense_calls == 0, dense_calls
+        g_dense = flatten_dict(exe_dense(params), sep="/")
+        with jax.default_matmul_precision("highest"):
+            g_exact = flatten_dict(jax.jit(grads(exact))(params), sep="/")
+
+        def worst(a, b):
+            diffs = {k: rel_err(a[k], b[k]) for k in b}
+            k = max(diffs, key=diffs.get)
+            return {"max": round(diffs[k], 5), "tensor": k,
+                    "median": round(float(np.median(list(diffs.values()))),
+                                    5)}
+
+        out = {"batch": batch, "tensors": len(g_exact),
+               "compile_s": round(compile_s, 2), "mosaic_calls": calls,
+               "fused_vs_dense": worst(g_fused, g_dense),
+               "fused_vs_fp32": worst(g_fused, g_exact),
+               "dense_vs_fp32": worst(g_dense, g_exact)}
+        # the kernel keeps its logits in fp32, so it may not be further
+        # from the truth than dense_core is by more than rounding
+        assert out["fused_vs_fp32"]["max"] <= max(
+            0.05, 1.25 * out["dense_vs_fp32"]["max"]), out
+        return out
+
+    check("vit_b16_grads_fused_vs_dense")(vit_grads_case)
 
     def codes_off(got, want) -> int:
         """How many int8 codes differ between a kernel and its reference.
@@ -646,6 +763,6 @@ def _child_kernels() -> int:
 
 
 if __name__ == "__main__":
-    if sys.argv[1:] == ["--child-kernels"]:
-        raise SystemExit(_child_kernels())
+    if sys.argv[1:2] == ["--child-kernels"]:
+        raise SystemExit(_child_kernels(tuple(sys.argv[2:])))
     raise SystemExit(main())

@@ -87,12 +87,15 @@ class SwitchMoEMlp(nn.Module):
 class SelfAttention(nn.Module):
     """Multi-head self-attention with a fused qkv projection.
 
-    einsum formulation keeps everything MXU-shaped; the qkv/out kernels are
-    the TP split points (see parallel/tensor.py rules). ``attention_fn``
-    swaps the dense softmax for an alternative core with the same
-    [B, T, H, D] x3 -> [B, T, H, D] contract — ring attention
-    (parallel/ring_attention.py) for sequence parallelism, or the Pallas
-    flash kernel (ops/pallas/flash_attention.py).
+    The qkv/out kernels are the TP split points (see parallel/tensor.py
+    rules). With no ``attention_fn`` the fused ``qkv`` activation goes to
+    ops/attention.py:attention_core as it is ([B, T, 3*H*D] in, [B, T, H*D]
+    out), which runs the fused short-sequence kernel or the dense einsum
+    core by backend and static shape. ``attention_fn`` swaps in an
+    alternative core with the [B, T, H, D] x3 -> [B, T, H, D] contract —
+    ring attention (parallel/ring_attention.py) for sequence parallelism,
+    the Pallas flash kernel (ops/pallas/flash_attention.py), or
+    ``dense_core`` itself where a kernel call cannot be partitioned.
     """
 
     num_heads: int
@@ -107,14 +110,16 @@ class SelfAttention(nn.Module):
 
         qkv = nn.Dense(3 * d, dtype=self.dtype, param_dtype=jnp.float32,
                        name="qkv")(x)
-        qkv = qkv.reshape(b, t, 3, self.num_heads, head_dim)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
 
         if self.attention_fn is not None:
+            qkv = qkv.reshape(b, t, 3, self.num_heads, head_dim)
+            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
             out = self.attention_fn(q, k, v).reshape(b, t, d)
         else:
-            from ..ops.attention import dense_core
-            out = dense_core(q, k, v).reshape(b, t, d)
+            # the core takes the activation as the Dense wrote it and
+            # picks its own schedule (ops/attention.py:select_core)
+            from ..ops.attention import attention_core
+            out = attention_core(qkv, self.num_heads)
         return nn.Dense(d, dtype=self.dtype, param_dtype=jnp.float32,
                         name="out")(out)
 
@@ -262,12 +267,15 @@ class EncoderStage(nn.Module):
     num_heads: int
     mlp_ratio: int = 4
     dtype: Dtype = jnp.float32
+    attention_fn: Callable | None = None
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
         for i in range(self.num_blocks):
             x = EncoderBlock(self.num_heads, self.mlp_ratio,
-                             dtype=self.dtype, name=f"block_{i}")(x)
+                             dtype=self.dtype,
+                             attention_fn=self.attention_fn,
+                             name=f"block_{i}")(x)
         return x
 
 

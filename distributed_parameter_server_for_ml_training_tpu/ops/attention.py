@@ -1,20 +1,27 @@
-"""The framework's ONE dense attention core (input-dtype, MXU-native).
+"""The framework's attention cores and the one rule that picks between them.
 
 ``dense_core`` is the softmax-attention formulation every dense path
 shares: logits in the INPUT dtype (bf16 matmuls stay on the fast MXU
-path — fp32 upcasts cost a measured 7-10% of a ViT-B/16 @224 step),
-softmax in fp32, probabilities cast back. Users:
+path; fp32 upcasts cost 7-10% of a ViT-B/16 @224 step: 740-753 against
+813-823 images/s on the round-4 chip, an earlier installation than the
+ledger's), softmax in fp32, probabilities cast back. Users:
 
-- models/vit.py:SelfAttention (the default core when no ``attention_fn``),
+- ``attention_core`` below, wherever the fused short-sequence kernel
+  (ops/pallas/short_attention.py) does not apply,
 - ops/pallas/flash_attention.flash_attention's below-crossover dispatch
-  (so ``attention_fn=flash_attention`` compiles to the IDENTICAL program
-  below the crossover — asserted bitwise by tests/test_flash_attention),
+  under its own ``[B, T, H, D]`` contract,
+- train/model_parallel.py:TPTrainer (GSPMD cannot partition a kernel call),
 - experiments/measure_mfu.py's crossover bench dense arm (the baseline
   the Pallas kernel must beat is the core the dispatch actually runs,
   not the fp32-upcast test reference in parallel/ring_attention).
 
-Kept dependency-free (jnp only) so models, ops and experiments can all
-import it without cycles.
+``attention_core`` is what models/vit.py:SelfAttention runs when no
+``attention_fn`` is set: one softmax attention, two ways to schedule it,
+chosen by the backend and static shapes alone (``select_core``).
+
+Kept light (jnp, the telemetry registry; the kernel module is imported
+only when chosen) so models, ops and experiments can all import it without
+cycles.
 """
 
 from __future__ import annotations
@@ -39,3 +46,60 @@ def dense_core(q: jax.Array, k: jax.Array, v: jax.Array, *,
         logits = jnp.where(mask[None, None], logits, _NEG_INF)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q.dtype), v)
+
+
+#: ``impl`` label of ``dps_attention_core_total`` -> one-line meaning
+#: (docs/OBSERVABILITY.md documents exactly these rows; tools/dpslint's
+#: catalog-drift check pins the two to each other both directions).
+ATTENTION_CORE_IMPLS = {
+    "fused_short": "ops/pallas/short_attention.py: one fused Pallas "
+                   "kernel each way, scores in VMEM, fed by the qkv "
+                   "activation",
+    "dense": "dense_core: XLA's einsum / softmax / einsum",
+}
+
+
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def select_core(*, on_tpu: bool, causal: bool, dtype, t: int,
+                num_heads: int, head_dim: int) -> str:
+    """Which core a call compiles to; a key of ``ATTENTION_CORE_IMPLS``.
+
+    The fused short-sequence kernel when the backend is a TPU, the
+    attention is not causal, the operands are bf16 (its MXU operands;
+    an fp32 model keeps its fp32 einsums), and the static shapes are the
+    ones it is written for: 128 % D == 0, H*D % 128 == 0 (whole 128-lane
+    groups of heads) and T <= short_attention.MAX_T (one head's scores
+    in VMEM; the constant's comment derives it). ``dense_core``
+    otherwise. Nothing else is consulted: no flag, no environment
+    variable, no measured-crossover file."""
+    from .pallas.short_attention import supports
+    if (on_tpu and not causal and dtype == jnp.bfloat16
+            and supports(t, num_heads, head_dim)):
+        return "fused_short"
+    return "dense"
+
+
+def attention_core(qkv: jax.Array, num_heads: int, *,
+                   causal: bool = False) -> jax.Array:
+    """``[B, T, 3*H*D]`` as the fused ``qkv`` Dense writes it (columns in
+    (3, H, D) order) -> ``[B, T, H*D]`` as the ``out`` Dense reads it.
+
+    Counts the choice in ``dps_attention_core_total{impl}`` at trace time
+    (12 a compile of the ViT-B/16 step), so a run's snapshot says which
+    core its program holds."""
+    from ..telemetry import get_registry
+    b, t, width = qkv.shape
+    head_dim = width // (3 * num_heads)
+    impl = select_core(on_tpu=_on_tpu(), causal=causal, dtype=qkv.dtype,
+                       t=t, num_heads=num_heads, head_dim=head_dim)
+    get_registry().counter("dps_attention_core_total", impl=impl).inc()
+    if impl == "fused_short":
+        from .pallas.short_attention import short_attention
+        return short_attention(qkv, num_heads)
+    qkv = qkv.reshape(b, t, 3, num_heads, head_dim)
+    out = dense_core(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2],
+                     causal=causal)
+    return out.reshape(b, t, num_heads * head_dim)

@@ -129,7 +129,8 @@ def flash_preferred(t: int) -> bool:
     This is the dispatch predicate ``flash_attention`` (``use_pallas=None``)
     and ``train.model_parallel.SPTrainer`` consult, closing the round-3 gap
     where flash was auto-selected below its measured crossover and LOST to
-    dense (ViT-B/16 @224px, 197 tokens: 28.4% vs 43.8% MFU).
+    dense (ViT-B/16 @224px, 197 tokens: 28.4% vs 43.8% MFU on the
+    round-3 chip, an earlier installation than the ledger's).
 
     Non-128-multiple lengths pay a PADDING TAX the crossover table (which
     is measured at clean multiples) doesn't see: the kernel computes the
@@ -514,12 +515,17 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     ``use_pallas=None`` (the default) dispatches on the MEASURED
     dense/flash crossover (``flash_preferred``): below it the dispatch
     returns the PLAIN dense formulation under native XLA autodiff —
-    short sequences are dominated by the padding + fusion-barrier
-    overhead of a custom kernel, and even the custom-VJP fallback costs
-    ~7% vs letting XLA fuse the backward itself (measured, ViT-B/16
-    @224: 762 vs 822 img/s). Explicit True/False overrides force the
-    Pallas kernels / the custom-VJP fallback (the CPU tests exercise
-    the latter's kernel-identical math).
+    THIS kernel's padding in HBM, its transposes and its key-block loop
+    dominate a short sequence, and even the custom-VJP fallback costs
+    ~7% vs letting XLA fuse the backward itself (ViT-B/16 @224: 762 vs
+    822 img/s on the round-4 chip, an earlier installation than the
+    ledger's). The short regime has a kernel of its own,
+    ops/pallas/short_attention.py, which ``ops.attention.attention_core``
+    picks under its ``[B, T, 3*H*D]`` contract; this function's
+    ``[B, T, H, D]`` contract keeps returning ``dense_core``. Explicit
+    True/False overrides force the Pallas kernels / the custom-VJP
+    fallback (the CPU tests exercise the latter's kernel-identical
+    math).
     """
     b, t, h, d = q.shape
     for name, blk in (("block_q", block_q), ("block_k", block_k)):
@@ -529,14 +535,17 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                 f"tile constraint; defaults via pick_block satisfy it)")
     if use_pallas is None:
         if not flash_preferred(t):
-            # THE shared dense core (ops/attention.dense_core) — the same
-            # function models/vit.py:SelfAttention runs with no
-            # attention_fn, so below the crossover
-            # ``attention_fn=flash_attention`` compiles to the identical
-            # program (asserted bitwise by tests). Upcasting (fp32 logits
-            # or fp32 q/k/v) costs 7-10% of the ViT-B/16 @224 step: the
-            # fp32 cotangents push the backward matmuls off the bf16 MXU
-            # rate (measured 740-753 vs 813-823 img/s).
+            # THE shared dense core (ops/attention.dense_core) — what
+            # models/vit.py:SelfAttention runs with no attention_fn
+            # wherever the fused short kernel does not apply, so below
+            # the crossover ``attention_fn=flash_attention`` compiles to
+            # the identical program OFF a TPU (asserted bitwise by the
+            # CPU tests); on a TPU the default model may hold the fused
+            # kernel instead. Upcasting (fp32 logits or fp32 q/k/v)
+            # costs 7-10% of the ViT-B/16 @224 step: the fp32 cotangents
+            # push the backward matmuls off the bf16 MXU rate (740-753
+            # vs 813-823 img/s on the round-4 chip, an earlier
+            # installation than the ledger's).
             from ..attention import dense_core
             return dense_core(q, k, v, causal=causal)
         use_pallas = True

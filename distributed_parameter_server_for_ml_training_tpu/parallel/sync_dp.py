@@ -36,7 +36,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..data.cifar import augment_batch, standardize, to_float
 from ..ops.compression import compress_for_allreduce, decompress_from_allreduce
-from ..train.steps import cross_entropy_loss
+from ..train.steps import cross_entropy_loss, make_eval_step
 from ..train.train_state import TrainState
 from .mesh import DATA_AXIS
 
@@ -210,3 +210,22 @@ def make_sync_dp_step(mesh: Mesh, *, axis: str = DATA_AXIS,
     # Donating the state lets XLA update params/opt_state in place instead of
     # holding both generations in HBM (same as train/baseline.py's step).
     return jax.jit(sharded, donate_argnums=0)
+
+
+def make_sync_dp_eval_step(mesh: Mesh) -> Callable:
+    """Build ``eval_step(state, images_u8, labels) -> (correct, total)`` for
+    the state ``make_sync_dp_step`` leaves replicated on every chip.
+
+    On one chip it is the plain jitted step. On several, every chip
+    evaluates the whole batch on its own copy of the state, which is what a
+    plain ``jit`` over replicated arrays compiles to as well; but that
+    would be a program for GSPMD to partition, and it cannot partition a
+    kernel call (the fused attention core, ops/attention.py). Written as a
+    ``shard_map`` with nothing sharded, the same program is manual and may
+    hold one."""
+    eval_step = make_eval_step()
+    if mesh.size > 1:
+        eval_step = jax.shard_map(eval_step, mesh=mesh,
+                                  in_specs=(P(), P(), P()), out_specs=P(),
+                                  check_vma=False)
+    return jax.jit(eval_step)

@@ -22,7 +22,8 @@ import numpy as np
 
 from ..data.cifar import Dataset, make_batches
 from ..parallel.mesh import make_mesh
-from ..parallel.sync_dp import make_sync_dp_step, shard_batch
+from ..parallel.sync_dp import (make_sync_dp_eval_step, make_sync_dp_step,
+                                shard_batch)
 from ..ps.store import ParameterStore, StoreConfig
 from ..ps.worker import WorkerConfig, run_workers
 from ..utils.metrics import device_fields, emit_metrics_json
@@ -110,7 +111,9 @@ class SyncTrainer:
         self._step = make_sync_dp_step(self.mesh,
                                        compression=cfg.compression,
                                        augment=cfg.augment)
-        self._eval_step = jax.jit(make_eval_step())
+        # multi-host evaluates on a fetched copy, process-locally
+        self._eval_step = (jax.jit(make_eval_step()) if self.multihost
+                           else make_sync_dp_eval_step(self.mesh))
         self.epoch_times: list[float] = []
         self.test_accuracies: list[float] = []
         self.global_steps = 0

@@ -34,6 +34,7 @@ import numpy as np
 from ..data.cifar import (Dataset, augment_batch, make_batches, standardize,
                           to_float)
 from ..models.vit import EncoderStage, ViTEpilogue, ViTPrologue
+from ..ops.attention import dense_core
 from ..parallel.mesh import make_mesh
 from ..parallel.pipeline import make_pipeline_apply, stack_stage_params
 from ..parallel.tensor import shard_train_state
@@ -219,8 +220,12 @@ class TPTrainer(_EpochTrainer):
         from ..models import get_model
         dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
         h, w = dataset.x_train.shape[1:3]
+        # GSPMD partitions the einsums of dense_core along 'model' (heads
+        # follow the qkv split); a kernel call it cannot partition, so the
+        # fused core attention_core would pick on a TPU is not offered here.
         self.model = get_model(cfg.model, num_classes=cfg.num_classes,
-                               dtype=dtype, image_size=h)
+                               dtype=dtype, image_size=h
+                               ).clone(attention_fn=dense_core)
         state = create_train_state(self.model, jax.random.PRNGKey(cfg.seed),
                                    server_sgd(cfg.learning_rate),
                                    input_shape=(1, h, w, 3))
@@ -306,8 +311,11 @@ class PipelineTrainer(_EpochTrainer):
         self.prologue = ViTPrologue(patch_size=shape["patch_size"],
                                     hidden_dim=shape["hidden_dim"],
                                     dtype=dtype)
+        # the stages run under a shard_map that leaves the 'model' axis to
+        # GSPMD, which cannot partition a kernel call (see TPTrainer)
         self.stage = EncoderStage(num_blocks=shape["depth"] // n_stages,
-                                  num_heads=shape["num_heads"], dtype=dtype)
+                                  num_heads=shape["num_heads"], dtype=dtype,
+                                  attention_fn=dense_core)
         self.epilogue = ViTEpilogue(num_classes=cfg.num_classes, dtype=dtype)
 
         rng = jax.random.PRNGKey(cfg.seed)
@@ -577,6 +585,9 @@ class MoETrainer(_EpochTrainer):
                          depth=shape["depth"], num_heads=shape["num_heads"],
                          num_classes=cfg.num_classes, dtype=dtype,
                          pool="gap",
+                         # a GSPMD program around the MoE shard_map: no
+                         # kernel call outside it (see TPTrainer)
+                         attention_fn=dense_core,
                          moe_fn=make_moe_ffn(self.mesh,
                                              capacity=self.capacity,
                                              data_axis=data_axis),

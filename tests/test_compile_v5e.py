@@ -1,0 +1,164 @@
+"""Compile for a TPU v5e that is described, not attached.
+
+The TPU's compiler is installed in the sandbox and compiles for a chip given
+by its topology alone (guide ``on-chip-measurement``, section 2, rehearsal
+3). Nothing runs, so these tests say nothing of results or times; they pin
+what the compiled ViT-B/16 train step *is*: which instructions, which
+layouts, how many bytes of temporaries. All compiles for the described chip
+live in this one file, behind fixtures: the worker that is handed the file
+loads the TPU's library, no other does.
+"""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from distributed_parameter_server_for_ml_training_tpu.ops import (
+    attention as at)
+from distributed_parameter_server_for_ml_training_tpu.ops.pallas import (
+    short_attention as sa)
+
+#: ``memory_analysis().temp_size_in_bytes`` of the same step with
+#: ``dense_core`` (PR 27's compile of the parent for the described chip).
+DENSE_TEMP_BYTES = 9.31e9
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure to describe: skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    """A compile for a described chip can be written to the persistent
+    cache but not read back without a chip; keep these out of it."""
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture()
+def as_on_tpu(monkeypatch):
+    """The tests run with JAX_PLATFORMS=cpu, so the dispatch would take its
+    CPU branch: steer the backend probe here, never through an option."""
+    monkeypatch.setattr(at, "_on_tpu", lambda: True)
+    assert sa.INTERPRET is False
+
+
+def _vit_b16_state(sharding):
+    """The benchmark's ViT configuration (224 px, 1000 classes, bf16, SGD)
+    as shapes on ``sharding``: nothing is initialised."""
+    from distributed_parameter_server_for_ml_training_tpu.models import (
+        get_model)
+    from distributed_parameter_server_for_ml_training_tpu.train.optimizers \
+        import server_sgd
+    from distributed_parameter_server_for_ml_training_tpu.train.train_state \
+        import create_train_state
+
+    model = get_model("vit_b16", num_classes=1000, dtype=jnp.bfloat16,
+                      axis_name="data", image_size=224)
+    state = jax.eval_shape(lambda: create_train_state(
+        model, jax.random.PRNGKey(0), server_sgd(0.03),
+        input_shape=(1, 224, 224, 3)))
+    return jax.tree_util.tree_map(
+        lambda x: _shaped(x.shape, x.dtype, sharding), state)
+
+
+def _shaped(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("b,t,h,d", [
+    (4, 197, 12, 64),        # ViT-B/16 @224: blocks overhang 197 rows
+    (1, sa.MAX_T, 12, 64),   # the longest sequence the dispatch sends
+    (2, sa.MAX_T, 2, 128),   # one head a group
+])
+def test_short_attention_kernels_compile(b, t, h, d, one_chip,
+                                         no_compile_cache):
+    """Forward and backward fit the VMEM they ask for (``MAX_T``'s
+    comment) and lower with no unaligned slice."""
+    qkv = _shaped((b, t, 3 * h * d), jnp.bfloat16, one_chip)
+    text = jax.jit(jax.grad(lambda x: jnp.sum(
+        sa.short_attention(x, h).astype(jnp.float32)))).lower(
+            qkv).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    assert "short_attention_fwd" in text and "short_attention_bwd" in text
+
+
+def test_vit_b16_step_holds_the_fused_core(topo, as_on_tpu,
+                                           no_compile_cache):
+    """The benchmark's ViT cells' program (``worker_step``, batch 128,
+    224 px, 1000 classes, bf16, SGD) for one v5e: 24 kernel calls, the
+    scores never in HBM, no layout copy around the kernels, and at least
+    3 GB fewer temporaries than with ``dense_core``."""
+    from distributed_parameter_server_for_ml_training_tpu.parallel.sync_dp \
+        import make_sync_dp_step
+
+    mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+    replicated, split = (NamedSharding(mesh, P()),
+                         NamedSharding(mesh, P("data")))
+    step = make_sync_dp_step(mesh, compression="bf16", augment=True)
+    compiled = step.lower(
+        _vit_b16_state(replicated),
+        _shaped((128, 224, 224, 3), jnp.uint8, split),
+        _shaped((128,), jnp.int32, split),
+        _shaped((2,), jnp.uint32, replicated)).compile()
+    text = compiled.as_text()
+
+    assert text.count('custom_call_target="tpu_custom_call"') == 24
+    # the instructions carry the kernels' names, not the flax scope's
+    # (``block_5.2``): a profile's breakdown then has two rows, not twelve
+    names = set(re.findall(
+        r"%([\w\-]+?)(?:\.\d+)? = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        text))
+    assert names == {"short_attention_fwd", "short_attention_bwd"}
+    assert "[128,12,197,197]" not in text
+    # The entry computation's instructions are the device's kernels. (The
+    # layout changes XLA fuses into a matmul's operand read stay inside
+    # nested fused computations and cost no pass of their own.)
+    entry = text[text.index("\nENTRY "):]
+    copies = re.findall(r"= (\S+) copy\(", entry)
+    assert 0 < len(copies) < 20, copies  # augmentation's; 92 with dense_core
+    for shape in ("[128,197,2304]", "[128,197,3,12,64]",
+                  "[128,197,1,12,64]"):
+        assert not [c for c in copies if shape in c], (shape, copies)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= DENSE_TEMP_BYTES - 3e9, temp
+
+
+def test_vit_b16_evaluation_on_four_chips_holds_the_fused_core(
+        topo, as_on_tpu, no_compile_cache):
+    """``SyncTrainer``'s evaluation of a state replicated over a four-chip
+    mesh. As a plain ``jit`` it was a program for GSPMD, which refuses a
+    kernel call ("Mosaic kernels cannot be automatically partitioned"); as
+    the ``shard_map`` ``make_sync_dp_eval_step`` builds it lowers, with the
+    forward kernel in each of the 12 blocks."""
+    from distributed_parameter_server_for_ml_training_tpu.parallel.sync_dp \
+        import make_sync_dp_eval_step
+
+    mesh = Mesh(np.array(topo.devices[:4]), ("data",))
+    replicated = NamedSharding(mesh, P())
+    text = make_sync_dp_eval_step(mesh).lower(
+        _vit_b16_state(replicated),
+        _shaped((128, 224, 224, 3), jnp.uint8, replicated),
+        _shaped((128,), jnp.int32, replicated)).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 12
+    assert "[128,12,197,197]" not in text

@@ -375,6 +375,18 @@ def check_goodput_categories(ctx: DriftContext) -> list[Finding]:
                         "### Goodput categories", "goodput category")
 
 
+def check_attention_core_impls(ctx: DriftContext) -> list[Finding]:
+    """ATTENTION_CORE_IMPLS (ops/attention.py), the values of
+    ``dps_attention_core_total``'s ``impl`` label, pinned to the
+    docs/OBSERVABILITY.md table: a snapshot's label must say which core
+    the compiled program holds in words the doc explains."""
+    return _table_check(ctx, "attention-core-impl",
+                        f"{_PKG}/ops/attention.py",
+                        "ATTENTION_CORE_IMPLS", "docs/OBSERVABILITY.md",
+                        "#### Attention core implementations",
+                        "attention core implementation")
+
+
 def check_profile_record(ctx: DriftContext) -> list[Finding]:
     """PROFILE_RECORD_FIELDS (telemetry/proftrigger.py) pinned to the
     docs/OBSERVABILITY.md profile-ledger table — the committed
@@ -406,6 +418,7 @@ CHECKS = {
     "incident-manifest": check_incident_manifest,
     "goodput-categories": check_goodput_categories,
     "profile-record": check_profile_record,
+    "attention-core-impls": check_attention_core_impls,
 }
 
 
