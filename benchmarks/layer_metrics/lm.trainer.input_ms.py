@@ -1,0 +1,19 @@
+"""``trainer.input_ms`` for the cells of the token driver: the same reading
+(``layer_metrics/trainer.input_ms.py``, whose entry lists the image cells), under a
+name of its own because a reader declares its drivers. Here a step's input is
+16,392 int32 tokens, 66 KB."""
+
+from harness import spec
+
+LAYER = "trainer"
+UNIT = "ms"
+BETTER = "lower"
+SOURCE = "program_span"
+MOVES = "images_per_s_per_chip"
+DRIVERS = ("sync_mesh_tokens",)
+CHIPS = None
+
+
+def read(run):
+    return spec.load_module("layer_metrics", "trainer.input_ms",
+                            run.cell.bench_dir).read(run)

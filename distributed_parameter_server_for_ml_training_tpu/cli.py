@@ -118,8 +118,17 @@ def build_parser() -> argparse.ArgumentParser:
                        default="bfloat16")
         q.add_argument("--model",
                        choices=["resnet18", "resnet50", "vit_b16",
-                                "vit_tiny"],
-                       default="resnet18")
+                                "vit_tiny", "joyai_llm_flash"],
+                       default="resnet18",
+                       help="joyai_llm_flash (train --mode sync only) is "
+                            "the decoder LM: it trains on seeded synthetic "
+                            "documents with AdamW; give it --lr 3e-3 (tiny) or 3e-6 "
+                            "(ep16), --batch-size in sequences")
+        q.add_argument("--model-preset", default=None,
+                       help="joyai_llm_flash: 'tiny' (default; CPU runs, "
+                            "sequences of 64) or 'ep16' (the published "
+                            "widths, one of 16 chips' share, sequences of "
+                            "4,096: a TPU's memory)")
         q.add_argument("--dataset", choices=["cifar100", "imagenet-synth"],
                        default="cifar100",
                        help="imagenet-synth = ImageNet-shaped synthetic "
@@ -1195,7 +1204,20 @@ def _profiler_session(profile_dir: str | None):
 def _load_dataset(args):
     from .data import load_cifar100, synthetic_cifar100
     from .data.cifar import synthetic_imagenet
+    from .models.registry import family_of, lm_config
 
+    if family_of(getattr(args, "model", "resnet18")) == "lm":
+        # the decoder LM's task: packed token rows (data/tokens.py)
+        from .data.tokens import synthetic_documents
+        config = lm_config(args.model, getattr(args, "model_preset", None))
+        # packed rows of 4,096 tokens at the published widths, 64 for tiny
+        seq_len = 4096 if config.hidden_size >= 1024 else 64
+        return synthetic_documents(
+            vocab_size=config.vocab_size, seq_len=seq_len,
+            n_train=getattr(args, "num_train", None) or 64,
+            n_test=getattr(args, "num_test", None) or 8,
+            seed=getattr(args, "seed", 0),
+            median_len=min(400.0, seq_len / 2))
     if getattr(args, "dataset", "cifar100") == "imagenet-synth":
         ds = synthetic_imagenet(
             n_train=getattr(args, "num_train", None) or 10_000,
@@ -1228,14 +1250,19 @@ def _cmd_train(args) -> int:
         initialize_multihost(args.coordinator, args.num_processes,
                              args.process_id)
 
+    from .models.registry import family_of
     dataset = _load_dataset(args)
-    if dataset.synthetic and getattr(args, "dataset",
-                                     "cifar100") == "cifar100" \
+    if family_of(args.model) != "lm" and dataset.synthetic \
+            and getattr(args, "dataset", "cifar100") == "cifar100" \
             and not getattr(args, "synthetic", False):
         print("note: CIFAR-100 not found on disk; using the synthetic "
               "dataset", file=sys.stderr)
 
-    num_classes = dataset.num_classes
+    if family_of(args.model) == "lm" and args.mode != "sync":
+        raise SystemExit(f"--model {args.model} trains with --mode sync "
+                         f"(the sync trainer takes the decoder-LM task); "
+                         f"--mode {args.mode} is for image models")
+    num_classes = getattr(dataset, "num_classes", 0)
 
     if args.mode == "baseline":
         from .train.baseline import BaselineConfig, BaselineTrainer
@@ -1295,7 +1322,7 @@ def _cmd_train(args) -> int:
         overlap=args.overlap, delta_fetch=not args.no_delta_fetch,
         store_backend=args.store_backend, augment=not args.no_augment,
         dtype=args.dtype, model=args.model, num_classes=num_classes,
-        seed=args.seed)
+        model_config=getattr(args, "model_preset", None), seed=args.seed)
     trainer = (SyncTrainer if args.mode == "sync" else AsyncTrainer)(
         dataset, cfg)
     with _profiler_session(getattr(args, "profile_dir", None)):

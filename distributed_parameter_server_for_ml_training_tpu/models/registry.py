@@ -5,12 +5,21 @@ Configs covered (BASELINE.json ``configs``):
   - resnet50  — ResNet-50 (pod-scale sync config; ImageNet-1k shapes)
   - vit_b16   — ViT-B/16 (transformer / non-conv MXU path)
   - vit_tiny  — small ViT for CIFAR-resolution runs and tests
+  - joyai_llm_flash — the decoder LM (models/joyai.py): latent attention,
+    a top-k expert layer told which experts it holds, multi-token
+    prediction; built from a ``JoyAIConfig`` (``config=``, or one of its
+    ``PRESETS`` by name)
+
+A model's *family* (``family_of``) says which task trains it
+(train/tasks.py): ``image`` (uint8 images and labels) or ``lm`` (packed
+token rows).
 """
 
 from __future__ import annotations
 
 import jax.numpy as jnp
 
+from .joyai import PRESETS as JOYAI_PRESETS, JoyAIConfig, JoyAILM
 from .resnet import ResNet18, ResNet50
 from .vit import ViT_B16, ViT_Tiny
 
@@ -30,14 +39,42 @@ _REGISTRY = {
         num_classes=num_classes, dtype=dtype),
 }
 
-MODEL_NAMES = tuple(_REGISTRY)
+#: decoder LMs: name -> (module, configuration class, presets)
+_LM_REGISTRY = {"joyai_llm_flash": (JoyAILM, JoyAIConfig, JOYAI_PRESETS)}
+
+MODEL_NAMES = tuple(_REGISTRY) + tuple(_LM_REGISTRY)
+
+
+def family_of(name: str) -> str:
+    """``image`` or ``lm``: the task that trains the model."""
+    if name in _LM_REGISTRY:
+        return "lm"
+    if name in _REGISTRY:
+        return "image"
+    raise ValueError(f"unknown model {name!r}; have {MODEL_NAMES}")
+
+
+def lm_config(name: str, config=None):
+    """The configuration a decoder LM is built from: ``config`` itself, a
+    preset's name, or the ``tiny`` preset when nothing is given."""
+    _module, config_cls, presets = _LM_REGISTRY[name]
+    if isinstance(config, config_cls):
+        return config
+    if (config or "tiny") not in presets:
+        raise ValueError(f"{name} has presets {tuple(presets)}, not "
+                         f"{config!r}")
+    return presets[config or "tiny"]
 
 
 def get_model(name: str, num_classes: int = 100, dtype=jnp.bfloat16,
-              axis_name: str | None = None, image_size: int = 32):
+              axis_name: str | None = None, image_size: int = 32,
+              config=None):
     """Build a model by registry name. ViT models ignore ``axis_name``
     (LayerNorm needs no cross-replica sync; BN models use it).
-    ``image_size`` selects resolution-dependent choices (ResNet-50 stem)."""
+    ``image_size`` selects resolution-dependent choices (ResNet-50 stem).
+    A decoder LM takes ``config`` (``lm_config``) and ``dtype`` alone."""
+    if name in _LM_REGISTRY:
+        return _LM_REGISTRY[name][0](lm_config(name, config), dtype=dtype)
     if name not in _REGISTRY:
         raise ValueError(f"unknown model {name!r}; have {MODEL_NAMES}")
     return _REGISTRY[name](num_classes, dtype, axis_name, image_size)

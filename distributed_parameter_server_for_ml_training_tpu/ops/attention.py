@@ -16,8 +16,10 @@ ledger's), softmax in fp32, probabilities cast back. Users:
   not the fp32-upcast test reference in parallel/ring_attention).
 
 ``attention_core`` is what models/vit.py:SelfAttention runs when no
-``attention_fn`` is set: one softmax attention, two ways to schedule it,
-chosen by the backend and static shapes alone (``select_core``).
+``attention_fn`` is set, ``heads_attention_core`` what the decoder LM's
+latent attention runs (models/joyai.py: separate q, k and v, v narrower
+than q and k): one softmax attention, three ways to schedule it, chosen
+by the backend and static shapes alone (``select_core``).
 
 Kept light (jnp, the telemetry registry; the kernel module is imported
 only when chosen) so models, ops and experiments can all import it without
@@ -36,7 +38,8 @@ _NEG_INF = -1e30
 def dense_core(q: jax.Array, k: jax.Array, v: jax.Array, *,
                causal: bool = False) -> jax.Array:
     """[B, T, H, D] x3 -> [B, T, H, D] softmax attention in the input
-    dtype (fp32 softmax)."""
+    dtype (fp32 softmax). ``v`` may have a width of its own; the scale is
+    1/sqrt of q's."""
     d = q.shape[-1]
     scale = 1.0 / np.sqrt(d)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
@@ -56,7 +59,16 @@ ATTENTION_CORE_IMPLS = {
                    "kernel each way, scores in VMEM, fed by the qkv "
                    "activation",
     "dense": "dense_core: XLA's einsum / softmax / einsum",
+    "flash": "ops/pallas/flash_attention.py: the streaming Pallas kernels "
+             "(one forward, two backward), scores in VMEM, causal blocks "
+             "skipped; long sequences",
 }
+
+#: from this many tokens on, a bf16 sequence on a TPU goes to the flash
+#: kernels: a head's [T, T] scores no longer fit the fused short kernel's
+#: VMEM (``short_attention.MAX_T``) and, written to HBM, are 32 MiB a head
+#: in bf16 at 4,096 tokens
+FLASH_MIN_T = 1024
 
 
 def _on_tpu() -> bool:
@@ -64,22 +76,55 @@ def _on_tpu() -> bool:
 
 
 def select_core(*, on_tpu: bool, causal: bool, dtype, t: int,
-                num_heads: int, head_dim: int) -> str:
+                num_heads: int, head_dim: int,
+                v_head_dim: int | None = None) -> str:
     """Which core a call compiles to; a key of ``ATTENTION_CORE_IMPLS``.
+    ``v_head_dim`` is given by callers whose v is not as wide as q and k.
 
     The fused short-sequence kernel when the backend is a TPU, the
     attention is not causal, the operands are bf16 (its MXU operands;
     an fp32 model keeps its fp32 einsums), and the static shapes are the
     ones it is written for: 128 % D == 0, H*D % 128 == 0 (whole 128-lane
     groups of heads) and T <= short_attention.MAX_T (one head's scores
-    in VMEM; the constant's comment derives it). ``dense_core``
+    in VMEM; the constant's comment derives it). The flash kernels
+    under the same backend and dtype, causal or not, when
+    ``T >= FLASH_MIN_T`` and T is whole 128-row tiles (they would pad it
+    otherwise). ``dense_core``
     otherwise. Nothing else is consulted: no flag, no environment
     variable, no measured-crossover file."""
     from .pallas.short_attention import supports
-    if (on_tpu and not causal and dtype == jnp.bfloat16
+    v_head_dim = head_dim if v_head_dim is None else v_head_dim
+    if not (on_tpu and dtype == jnp.bfloat16):
+        return "dense"
+    if (not causal and v_head_dim == head_dim
             and supports(t, num_heads, head_dim)):
         return "fused_short"
+    if t >= FLASH_MIN_T and t % 128 == 0:
+        return "flash"
     return "dense"
+
+
+def _count(impl: str) -> None:
+    from ..telemetry import get_registry
+    get_registry().counter("dps_attention_core_total", impl=impl).inc()
+
+
+def heads_attention_core(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                         causal: bool = False) -> jax.Array:
+    """``[B, T, H, Dqk]`` x2 and ``[B, T, H, Dv]`` -> ``[B, T, H, Dv]``,
+    by the same rule and counted in the same counter as
+    ``attention_core``."""
+    _b, t, num_heads, head_dim = q.shape
+    impl = select_core(on_tpu=_on_tpu(), causal=causal, dtype=q.dtype, t=t,
+                       num_heads=num_heads, head_dim=head_dim,
+                       v_head_dim=v.shape[-1])
+    if impl == "fused_short":    # needs the packed qkv activation
+        impl = "dense"
+    _count(impl)
+    if impl == "flash":
+        from .pallas.flash_attention import flash_attention
+        return flash_attention(q, k, v, causal=causal, use_pallas=True)
+    return dense_core(q, k, v, causal=causal)
 
 
 def attention_core(qkv: jax.Array, num_heads: int, *,
@@ -90,16 +135,19 @@ def attention_core(qkv: jax.Array, num_heads: int, *,
     Counts the choice in ``dps_attention_core_total{impl}`` at trace time
     (12 a compile of the ViT-B/16 step), so a run's snapshot says which
     core its program holds."""
-    from ..telemetry import get_registry
     b, t, width = qkv.shape
     head_dim = width // (3 * num_heads)
     impl = select_core(on_tpu=_on_tpu(), causal=causal, dtype=qkv.dtype,
                        t=t, num_heads=num_heads, head_dim=head_dim)
-    get_registry().counter("dps_attention_core_total", impl=impl).inc()
+    _count(impl)
     if impl == "fused_short":
         from .pallas.short_attention import short_attention
         return short_attention(qkv, num_heads)
     qkv = qkv.reshape(b, t, 3, num_heads, head_dim)
-    out = dense_core(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2],
-                     causal=causal)
+    q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+    if impl == "flash":
+        from .pallas.flash_attention import flash_attention
+        out = flash_attention(q, k, v, causal=causal, use_pallas=True)
+    else:
+        out = dense_core(q, k, v, causal=causal)
     return out.reshape(b, t, num_heads * head_dim)

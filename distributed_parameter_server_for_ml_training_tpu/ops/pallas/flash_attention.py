@@ -18,6 +18,9 @@ Design (standard flash attention, TPU-shaped):
   ambiguity): dQ tiles over query blocks, dK/dV tiles over key blocks,
   each streaming the opposite operand. delta = rowsum(dO * O) is a cheap
   elementwise precompute.
+- ``v`` (and so ``o``) may have a head width of its own: latent attention
+  (models/joyai.py) has q/k heads of 192 and v heads of 128. The softmax
+  scale is 1/sqrt of the q/k width.
 - sequence lengths that aren't block multiples are zero-padded; padded KEY
   positions are masked to -inf in every kernel, padded QUERY rows fall out
   of the backward because their dO/delta are zero.
@@ -48,6 +51,8 @@ _NEG_INF = -1e30
 # nearly halve the backward at T>=2048 vs 128 (bigger serial-loop bodies
 # keep the MXU fed); short sequences clamp down so padding stays small.
 MAX_BLOCK = 512
+#: scoped VMEM the kernels may use (``_compiler_params``)
+VMEM_LIMIT_BYTES = 64 << 20
 
 # Fallback when no measured crossover has been recorded (conservative:
 # well above the short-sequence regime where dense decisively wins; the
@@ -181,7 +186,7 @@ def _fwd_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
 
     m0 = jnp.full((bq, 1), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((bq, 1), jnp.float32)
-    acc0 = jnp.zeros((bq, q.shape[1]), jnp.float32)
+    acc0 = jnp.zeros((bq, v_ref.shape[2]), jnp.float32)  # v's own width
 
     def body(i, carry):
         m, l, acc = carry
@@ -307,8 +312,9 @@ def _bwd_dkv_kernel(pos_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             preferred_element_type=jnp.float32) * scale    # [BK, D]
         return dk, dv
 
-    zero = jnp.zeros((bk, kb.shape[1]), jnp.float32)
-    dk, dv = jax.lax.fori_loop(lo_q, hi_q, body, (zero, zero))
+    dk, dv = jax.lax.fori_loop(
+        lo_q, hi_q, body, (jnp.zeros(kb.shape, jnp.float32),
+                           jnp.zeros(vb.shape, jnp.float32)))
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
@@ -353,6 +359,18 @@ def pick_block(t: int) -> int:
     return max(b for b in range(128, MAX_BLOCK + 1, 128) if t % b == 0)
 
 
+def _compiler_params():
+    """Mosaic parameters of the three kernels. Each program keeps one
+    (batch, head)'s whole K and V (the dK/dV kernel: whole Q, dO, LSE and
+    delta) in VMEM, double-buffered; the ``[T, 1]`` float32 rows pad to 128
+    lanes. At T = 4096 with 192-wide q/k that passes the compiler's default
+    scoped limit of 16 MiB (the dK/dV kernel asks for about 24), so the
+    limit is raised to what the kernels are sized for; a v5e core has
+    128 MiB."""
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
 # -- core op on [BH, T_pad, D] with custom VJP --------------------------------
 
 def _pos_scalars(q_offset, k_offset):
@@ -371,6 +389,7 @@ def _flash_core(q, k, v, kv_len, block_q, block_k, use_pallas, causal):
 def _flash_fwd_impl(q, k, v, kv_len, block_q, block_k, use_pallas,
                     out_dtype=None, causal=False, q_offset=0, k_offset=0):
     bh, tp, d = q.shape
+    dv = v.shape[2]        # v (and o) may be narrower than q and k (MLA)
     scale = 1.0 / np.sqrt(d)
     if not use_pallas:
         # out_dtype reaches the FINAL cast — an intermediate round-trip
@@ -386,8 +405,12 @@ def _flash_fwd_impl(q, k, v, kv_len, block_q, block_k, use_pallas,
     blk_pos = pl.BlockSpec(memory_space=pltpu.SMEM)
     blk_q = pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0),
                          memory_space=pltpu.VMEM)
-    blk_full = pl.BlockSpec((1, tp, d), lambda b, i: (b, 0, 0),
-                            memory_space=pltpu.VMEM)
+    blk_o = pl.BlockSpec((1, block_q, dv), lambda b, i: (b, i, 0),
+                         memory_space=pltpu.VMEM)
+    blk_kfull = pl.BlockSpec((1, tp, d), lambda b, i: (b, 0, 0),
+                             memory_space=pltpu.VMEM)
+    blk_vfull = pl.BlockSpec((1, tp, dv), lambda b, i: (b, 0, 0),
+                             memory_space=pltpu.VMEM)
     # LSE rides as [BH, T, 1]: a (1, BLOCK_Q, 1) block keeps the last
     # two dims tileable ((BLOCK_Q, 1): sublanes % 8 == 0, lane dim == array).
     blk_lse = pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0),
@@ -396,11 +419,12 @@ def _flash_fwd_impl(q, k, v, kv_len, block_q, block_k, use_pallas,
         partial(_fwd_kernel, scale=scale, block_q=block_q, block_k=block_k,
                 kv_len=kv_len, causal=causal),
         grid=(bh, n_q),
-        in_specs=[blk_pos, blk_q, blk_full, blk_full],
-        out_specs=(blk_q, blk_lse),
-        out_shape=(jax.ShapeDtypeStruct(q.shape, out_dtype or q.dtype),
+        in_specs=[blk_pos, blk_q, blk_kfull, blk_vfull],
+        out_specs=(blk_o, blk_lse),
+        out_shape=(jax.ShapeDtypeStruct((bh, tp, dv), out_dtype or q.dtype),
                    jax.ShapeDtypeStruct((bh, tp, 1), jnp.float32)),
-        interpret=INTERPRET,
+        compiler_params=_compiler_params(),
+        interpret=INTERPRET, name="flash_attention_fwd",
     )(_pos_scalars(q_offset, k_offset), q, k, v)
     return o, lse
 
@@ -423,7 +447,7 @@ def _flash_bwd_impl(q, k, v, do, lse, delta, kv_len, block_q, block_k,
     dK/dV kernel skips those blocks); defaults to the padded length,
     i.e. no skipping."""
     bh, tq, d = q.shape
-    tk = k.shape[1]
+    tk, dv = k.shape[1], v.shape[2]
     q_len = tq if q_len is None else q_len
     scale = 1.0 / np.sqrt(d)
     dts = [out_dtype or x.dtype for x in (q, k, v)]
@@ -445,11 +469,19 @@ def _flash_bwd_impl(q, k, v, do, lse, delta, kv_len, block_q, block_k,
 
     blk_q = pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0),
                          memory_space=pltpu.VMEM)
+    blk_do = pl.BlockSpec((1, block_q, dv), lambda b, i: (b, i, 0),
+                          memory_space=pltpu.VMEM)
     blk_k = pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0),
+                         memory_space=pltpu.VMEM)
+    blk_v = pl.BlockSpec((1, block_k, dv), lambda b, i: (b, i, 0),
                          memory_space=pltpu.VMEM)
     blk_qfull = pl.BlockSpec((1, tq, d), lambda b, i: (b, 0, 0),
                              memory_space=pltpu.VMEM)
+    blk_dofull = pl.BlockSpec((1, tq, dv), lambda b, i: (b, 0, 0),
+                              memory_space=pltpu.VMEM)
     blk_kfull = pl.BlockSpec((1, tk, d), lambda b, i: (b, 0, 0),
+                             memory_space=pltpu.VMEM)
+    blk_vfull = pl.BlockSpec((1, tk, dv), lambda b, i: (b, 0, 0),
                              memory_space=pltpu.VMEM)
     blk_row_q = pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0),
                              memory_space=pltpu.VMEM)
@@ -463,23 +495,25 @@ def _flash_bwd_impl(q, k, v, do, lse, delta, kv_len, block_q, block_k,
         partial(_bwd_dq_kernel, scale=scale, block_q=block_q,
                 block_k=block_k, kv_len=kv_len, causal=causal),
         grid=(bh, tq // block_q),
-        in_specs=[blk_pos, blk_q, blk_kfull, blk_kfull, blk_q, blk_row_q,
+        in_specs=[blk_pos, blk_q, blk_kfull, blk_vfull, blk_do, blk_row_q,
                   blk_row_q],
         out_specs=blk_q,
         out_shape=jax.ShapeDtypeStruct(q.shape, dts[0]),
-        interpret=INTERPRET,
+        compiler_params=_compiler_params(),
+        interpret=INTERPRET, name="flash_attention_bwd_dq",
     )(pos, q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
         partial(_bwd_dkv_kernel, scale=scale, block_q=block_q,
                 kv_len=kv_len, q_len=q_len, causal=causal),
         grid=(bh, tk // block_k),
-        in_specs=[blk_pos, blk_qfull, blk_k, blk_k, blk_qfull,
+        in_specs=[blk_pos, blk_qfull, blk_k, blk_v, blk_dofull,
                   blk_row_qfull, blk_row_qfull],
-        out_specs=(blk_k, blk_k),
+        out_specs=(blk_k, blk_v),
         out_shape=(jax.ShapeDtypeStruct(k.shape, dts[1]),
                    jax.ShapeDtypeStruct(v.shape, dts[2])),
-        interpret=INTERPRET,
+        compiler_params=_compiler_params(),
+        interpret=INTERPRET, name="flash_attention_bwd_dkv",
     )(pos, q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -527,7 +561,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     fallback (the CPU tests exercise the latter's kernel-identical
     math).
     """
-    b, t, h, d = q.shape
+    b, t, h, _d = q.shape
     for name, blk in (("block_q", block_q), ("block_k", block_k)):
         if blk is not None and (blk <= 0 or blk % 128):
             raise ValueError(
@@ -564,10 +598,10 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     tp = -(-t // block) * block
 
     def to3(x):
-        x = jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, t, d)
+        x = jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, t, x.shape[-1])
         return jnp.pad(x, ((0, 0), (0, tp - t), (0, 0))) if tp != t else x
 
     o3 = _flash_core(to3(q), to3(k), to3(v), t, block_q, block_k,
                      bool(use_pallas), bool(causal))
-    o = o3[:, :t].reshape(b, h, t, d)
+    o = o3[:, :t].reshape(b, h, t, v.shape[-1])
     return jnp.transpose(o, (0, 2, 1, 3)).astype(q.dtype)

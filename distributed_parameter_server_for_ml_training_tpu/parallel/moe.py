@@ -1,6 +1,17 @@
-"""Expert parallelism: Switch-style top-1 MoE FFN over an ``expert`` axis.
+"""Expert parallelism, two layers.
 
-Net-new capability (the reference has no MoE — SURVEY.md §2 checklist, EP
+**The Switch layer** (``make_moe_ffn``): top-1 over an ``expert`` mesh axis,
+one expert a mesh slot, capacity buffers that drop, two ``all_to_all`` hops.
+``MoETrainer`` (--mode moe) runs it inside a ViT. Described next.
+
+**The held-experts layer** (``route_top_k`` + ``held_expert_ffn``, further
+down): top-k over the router's whole published width, many experts a chip,
+the layer *told which experts it holds*, no token dropped whatever the
+imbalance. It returns the partial sum the held experts give; on one chip it
+runs without its exchange (the other shares' partial sums live on the chips
+that hold them). The decoder LM (models/joyai.py) runs it.
+
+The Switch layer: net-new capability (the reference has no MoE — SURVEY.md §2 checklist, EP
 row). One expert per mesh slot; each device routes its resident tokens,
 packs them into capacity-limited per-expert buffers, and two
 ``lax.all_to_all`` hops move tokens to their expert and back:
@@ -165,3 +176,195 @@ def dense_reference(params, tokens, capacity: int | None = None):
     out = jnp.einsum("nh,nhd->nd", h, params["w2"][expert_idx]) \
         + params["b2"][expert_idx]
     return out * gate[:, None].astype(tokens.dtype)
+
+
+# -- the held-experts layer ---------------------------------------------------
+
+def route_top_k(scores: jax.Array, bias: jax.Array, k: int, *,
+                scaling: float = 1.0, normalize: bool = True):
+    """Choose ``k`` experts a token and weigh them.
+
+    ``scores`` ``[N, E]`` float32 are the router's affinities (the caller's
+    sigmoid or softmax over the *whole* published width ``E``), ``bias``
+    ``[E]`` the balancing bias that only steers the choice (``noaux_tc``:
+    it takes no gradient and is updated from the counted loads, see
+    :func:`bias_update`). Returns ``(idx [N, k] int32, weights [N, k]
+    float32)``: the ``k`` largest of ``scores + bias``, weighted by their
+    ``scores`` (without the bias), divided by their sum if ``normalize``,
+    times ``scaling``.
+    """
+    _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias)[None], k)
+    weights = jnp.take_along_axis(scores, idx, axis=1)
+    if normalize:
+        weights = weights / jnp.sum(weights, axis=1, keepdims=True)
+    return idx.astype(jnp.int32), weights * scaling
+
+
+def expert_loads(idx: jax.Array, n_experts: int) -> jax.Array:
+    """``[E]`` int32: assignments each of the ``E`` experts was given."""
+    return jnp.zeros((n_experts,), jnp.int32).at[idx.reshape(-1)].add(1)
+
+
+def bias_update(bias: jax.Array, loads: jax.Array, gamma: float):
+    """``b_e += gamma * sign(mean load - load_e)``: an expert with less than
+    its share is made likelier, one with more less likely."""
+    loads = loads.astype(jnp.float32)
+    return bias + gamma * jnp.sign(jnp.mean(loads) - loads)
+
+
+def pass_plan(n_tokens: int, k: int, held: int, n_experts: int,
+              capacity_factor: float = 0.0):
+    """``(rows, min_passes)`` for :func:`held_expert_ffn`: a pass is half of
+    what the held experts get under even routing, in whole 512-row tiles and
+    never more than the most they can get. ``capacity_factor`` is the layer's
+    static capacity in units of the even load, as a deployment with
+    fixed-shape exchange buffers states one: that many rows are computed in
+    every step, slack as zero rows, so that a step's time does not follow
+    the routing while the load is within it (``min_passes``); 0, the default,
+    computes what the routing needs and no more. Beyond the capacity the
+    layer runs more passes; it never drops."""
+    most = n_tokens * min(k, held)
+    even = n_tokens * k * held // n_experts
+    rows = min(most, max(512, -(-even // 1024) * 512))
+    floor = round(capacity_factor * even / rows) if capacity_factor else 1
+    return rows, min(-(-most // rows), max(1, floor))
+
+
+def _pass_rows(p, order, starts, ends, total, rows: int):
+    """Pass ``p`` of the sorted assignment list: ``(at [rows], valid
+    [rows], group [C])``: the assignments' indices into the flat ``[N * k]``
+    list, which of them lie before the list's end, and how many rows of the
+    pass each held expert has. Rows past the list's end (the last pass's, or
+    a stated capacity's slack) are zero rows given to the last held expert,
+    so that a pass is the same work wherever the list ends."""
+    lo = p * rows
+    at = jax.lax.dynamic_slice(order, (lo,), (rows,))
+    valid = lo + jnp.arange(rows) < total
+    group = jnp.clip(ends, lo, lo + rows) - jnp.clip(starts, lo, lo + rows)
+    return at, valid, group.at[-1].add(rows - jnp.sum(group))
+
+
+def _pass_out(rows_x, rows_w, experts, group, valid):
+    """The pass's rows through their experts' SwiGLU, weighted: three
+    grouped matmuls (``jax.lax.ragged_dot``; on the TPU XLA's own grouped
+    kernel, which visits only the tiles a group fills)."""
+    with jax.named_scope("moe_experts"):
+        wg, wu, wd = (experts[name].astype(rows_x.dtype)
+                      for name in ("gate", "up", "down"))
+        hidden = (jax.nn.silu(jax.lax.ragged_dot(rows_x, wg, group))
+                  * jax.lax.ragged_dot(rows_x, wu, group))
+        out = jax.lax.ragged_dot(hidden, wd, group)
+    # rows past the last group's end hold whatever the kernel left
+    return jnp.where(valid[:, None], out, 0) * rows_w[:, None].astype(
+        rows_x.dtype)
+
+
+def _passes(total, rows: int, min_passes: int):
+    return jnp.maximum(-(-total // rows), min_passes)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+def _work_off(x, flat_weights, experts, order, starts, ends, total,
+              k: int, rows: int, min_passes: int):
+    """``(y [N, D] float32, processed)``: the sorted list worked off
+    ``rows`` at a time, ``min_passes`` passes or as many as the list is long
+    (a loop with a dynamic trip count, hence the hand-written backward pass
+    below: the same loop, each pass's forward computed again and
+    differentiated by ``jax.vjp``), and the assignments the passes computed,
+    counted pass by pass: a trip count that stops short shows as fewer than
+    ``total``. Only one pass's rows and the ``[N, D]`` sums are live,
+    whatever the imbalance, and a pass beyond both costs nothing."""
+    n, d = x.shape
+
+    def one_pass(p, carry):
+        y, processed = carry
+        at, valid, group = _pass_rows(p, order, starts, ends, total, rows)
+        with jax.named_scope("moe_route"):
+            tokens = at // k
+            rows_x = jnp.where(valid[:, None], x[tokens], 0)
+            rows_w = flat_weights[at]
+        part = _pass_out(rows_x, rows_w, experts, group, valid)
+        with jax.named_scope("moe_route"):
+            return (y.at[tokens].add(part.astype(jnp.float32)),
+                    processed + jnp.sum(valid, dtype=jnp.int32))
+
+    return jax.lax.fori_loop(0, _passes(total, rows, min_passes), one_pass,
+                             (jnp.zeros((n, d), jnp.float32), jnp.int32(0)))
+
+
+def _work_off_fwd(x, flat_weights, experts, order, starts, ends, total,
+                  k, rows, min_passes):
+    out = _work_off(x, flat_weights, experts, order, starts, ends, total,
+                    k, rows, min_passes)
+    return out, (x, flat_weights, experts, order, starts, ends, total)
+
+
+def _work_off_bwd(k, rows, min_passes, residuals, cotangents):
+    x, flat_weights, experts, order, starts, ends, total = residuals
+    dy, _ = cotangents          # the count takes none
+
+    def one_pass(p, sums):
+        dx, dw, de = sums
+        at, valid, group = _pass_rows(p, order, starts, ends, total, rows)
+        with jax.named_scope("moe_route"):
+            tokens = at // k
+            rows_x = jnp.where(valid[:, None], x[tokens], 0)
+            rows_w = flat_weights[at]
+            d_part = dy[tokens].astype(x.dtype)
+        _out, vjp = jax.vjp(
+            lambda rx, rw, ex: _pass_out(rx, rw, ex, group, valid),
+            rows_x, rows_w, experts)
+        d_rows_x, d_rows_w, d_experts = vjp(d_part)
+        with jax.named_scope("moe_route"):
+            dx = dx.at[tokens].add(jnp.where(
+                valid[:, None], d_rows_x, 0).astype(jnp.float32))
+            dw = dw.at[at].add(jnp.where(valid, d_rows_w, 0))
+        return dx, dw, jax.tree_util.tree_map(jnp.add, de, d_experts)
+
+    dx, dw, de = jax.lax.fori_loop(
+        0, _passes(total, rows, min_passes), one_pass,
+        (jnp.zeros(x.shape, jnp.float32), jnp.zeros_like(flat_weights),
+         jax.tree_util.tree_map(jnp.zeros_like, experts)))
+    return dx.astype(x.dtype), dw, de, None, None, None, None
+
+
+_work_off.defvjp(_work_off_fwd, _work_off_bwd)
+
+
+def held_expert_ffn(x: jax.Array, idx: jax.Array, weights: jax.Array,
+                    experts: dict, first: int, *, rows: int,
+                    min_passes: int = 1):
+    """The held experts' part of a top-k expert layer's output.
+
+    ``x`` ``[N, D]`` tokens, ``idx`` / ``weights`` ``[N, k]`` from
+    :func:`route_top_k`, ``experts`` the stacked SwiGLU weights of the
+    experts held here (``gate`` / ``up`` ``[C, D, F]``, ``down``
+    ``[C, F, D]``), which are the experts ``first .. first + C - 1`` of the
+    router's numbering. Returns ``(y [N, D], processed)``: for each token the
+    weighted sum over the held experts among its ``k``, zero for a token that
+    chose none of them, and the number of assignments the passes computed,
+    counted as they ran: it equals the number given to held experts, because
+    nothing is dropped.
+
+    How: the assignments are sorted by held expert (absent experts' last),
+    and the sorted list, at most ``N * min(k, C)`` long, is worked off in
+    passes of ``rows`` rows (:func:`_work_off`): gather the rows' tokens,
+    three grouped matmuls over the held experts, weigh, add into the
+    tokens' sums. As many passes run as the list needs, found at run time,
+    so only the worst case pays for the worst case; ``min_passes`` is the
+    floor a stated capacity sets (:func:`pass_plan`), 1 without one.
+    """
+    k, c = idx.shape[1], experts["gate"].shape[0]
+    with jax.named_scope("moe_route"):
+        local = idx - first
+        held = (local >= 0) & (local < c)
+        flat = jnp.where(held, local, c).reshape(-1)     # absent sort last
+        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+        sizes = jnp.zeros((c + 1,), jnp.int32).at[flat].add(1)[:c]
+        ends = jnp.cumsum(sizes)
+        starts, total = ends - sizes, ends[-1]
+        # the last pass, or a capacity's floor, may reach past the end
+        order = jnp.pad(order, (0, rows * min_passes))
+    y, processed = _work_off(x, weights.reshape(-1), experts, order, starts,
+                             ends, total, k, rows, min_passes)
+    return y.astype(x.dtype), processed

@@ -34,9 +34,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..data.cifar import augment_batch, standardize, to_float
 from ..ops.compression import compress_for_allreduce, decompress_from_allreduce
-from ..train.steps import cross_entropy_loss, make_eval_step
+from ..train.tasks import ImageTask
 from ..train.train_state import TrainState
 from .mesh import DATA_AXIS
 
@@ -119,46 +118,30 @@ def shard_batch(mesh: Mesh, batch, axis: str = DATA_AXIS):
 
 def make_sync_dp_step(mesh: Mesh, *, axis: str = DATA_AXIS,
                       compression: str = "bf16",
-                      augment: bool = True) -> Callable:
-    """Build the sync data-parallel ``step(state, images_u8, labels, rng)``.
+                      augment: bool = True, task=None) -> Callable:
+    """Build the sync data-parallel ``step(state, *batch, rng)``.
+
+    ``task`` (train/tasks.py) is the model family's part of the step: the
+    forward and backward pass on one worker's shard of ``batch`` and what
+    it reports. Without one it is the image task, ``step(state, images_u8,
+    labels, rng)``, with ``augment``.
 
     ``state`` must be built from a model constructed with
     ``axis_name=axis`` so BatchNorm statistics sync across workers (the
     sane resolution of the reference's frozen-BN defect, SURVEY.md §7(b)).
     Returns ``(state, metrics)`` with metrics pmean'd across workers.
     """
+    task = task or ImageTask(augment)
 
-    def worker_step(state: TrainState, images_u8, labels, rng):
+    def worker_step(state: TrainState, *args):
+        *batch, rng = args
         # Per-worker RNG: fold in the worker index (distinct augmentation
         # per shard) and the global step.
         widx = jax.lax.axis_index(axis)
         rng = jax.random.fold_in(jax.random.fold_in(rng, widx), state.step)
 
-        # torchvision order (worker.py:145-154): crop/flip raw pixels
-        # (zero pad = black), then per-channel standardize. Gathers run
-        # on uint8 — bit-identical floats at 1/4 the bandwidth
-        # (train/steps.py).
-        # The four named scopes (also in train/steps.py:make_train_step)
-        # tag each instruction's metadata with the phase it belongs to,
-        # for a profile's readers; they cost nothing at run time.
-        with jax.named_scope("augment"):
-            images = images_u8
-            if augment:
-                images = augment_batch(rng, images)
-            images = standardize(to_float(images))
-
-        def loss_fn(params):
-            from ..train.steps import _variables
-            outputs, mutated = state.apply_fn(
-                _variables(params, state.batch_stats),
-                images, train=True, mutable=["batch_stats"],
-            )
-            loss = cross_entropy_loss(outputs, labels)
-            return loss, (outputs, mutated.get("batch_stats", {}))
-
-        with jax.named_scope("forward_backward"):
-            (loss, (logits, new_stats)), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(state.params)
+        loss, grads, new_stats, judged, extra = task.forward_backward(
+            state, batch, rng, axis)
 
         # == server.py:145-169 aggregate_gradients_sync, as one all-reduce,
         # with compression on the wire (the reference cast fp16,
@@ -184,7 +167,7 @@ def make_sync_dp_step(mesh: Mesh, *, axis: str = DATA_AXIS,
             state = state.apply_gradients(grads=grads)
             state = state.replace(batch_stats=new_stats)
 
-        acc = jnp.mean(jnp.argmax(logits, -1) == labels)
+        acc = task.accuracy(judged)
         metrics = {
             "loss": jax.lax.pmean(loss, axis),
             "accuracy": jax.lax.pmean(acc, axis),
@@ -195,15 +178,17 @@ def make_sync_dp_step(mesh: Mesh, *, axis: str = DATA_AXIS,
             # worker.py:350-366).
             "worker_loss": loss[None],
             "worker_accuracy": acc[None],
+            **extra,    # the task's own, replicated
         }
         return state, metrics
 
     metric_specs = {"loss": P(), "accuracy": P(),
-                    "worker_loss": P(axis), "worker_accuracy": P(axis)}
+                    "worker_loss": P(axis), "worker_accuracy": P(axis),
+                    **{name: P() for name in task.extra_metrics}}
     sharded = jax.shard_map(
         worker_step,
         mesh=mesh,
-        in_specs=(P(), P(axis), P(axis), P()),
+        in_specs=(P(),) + (P(axis),) * task.batch_arity + (P(),),
         out_specs=(P(), metric_specs),
         check_vma=False,
     )
@@ -212,9 +197,10 @@ def make_sync_dp_step(mesh: Mesh, *, axis: str = DATA_AXIS,
     return jax.jit(sharded, donate_argnums=0)
 
 
-def make_sync_dp_eval_step(mesh: Mesh) -> Callable:
-    """Build ``eval_step(state, images_u8, labels) -> (correct, total)`` for
-    the state ``make_sync_dp_step`` leaves replicated on every chip.
+def make_sync_dp_eval_step(mesh: Mesh, task=None) -> Callable:
+    """Build ``eval_step(state, *batch) -> (correct, total)`` (the image
+    task's ``(state, images_u8, labels)`` without a ``task``) for the state
+    ``make_sync_dp_step`` leaves replicated on every chip.
 
     On one chip it is the plain jitted step. On several, every chip
     evaluates the whole batch on its own copy of the state, which is what a
@@ -223,9 +209,10 @@ def make_sync_dp_eval_step(mesh: Mesh) -> Callable:
     kernel call (the fused attention core, ops/attention.py). Written as a
     ``shard_map`` with nothing sharded, the same program is manual and may
     hold one."""
-    eval_step = make_eval_step()
+    task = task or ImageTask()
+    eval_step = task.eval_step()
     if mesh.size > 1:
         eval_step = jax.shard_map(eval_step, mesh=mesh,
-                                  in_specs=(P(), P(), P()), out_specs=P(),
-                                  check_vma=False)
+                                  in_specs=(P(),) * (1 + task.batch_arity),
+                                  out_specs=P(), check_vma=False)
     return jax.jit(eval_step)

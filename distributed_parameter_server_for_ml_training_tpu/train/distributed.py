@@ -20,7 +20,7 @@ from dataclasses import dataclass
 import jax
 import numpy as np
 
-from ..data.cifar import Dataset, make_batches
+from ..data.cifar import Dataset
 from ..parallel.mesh import make_mesh
 from ..parallel.sync_dp import (make_sync_dp_eval_step, make_sync_dp_step,
                                 shard_batch)
@@ -28,13 +28,7 @@ from ..ps.store import ParameterStore, StoreConfig
 from ..ps.worker import WorkerConfig, run_workers
 from ..utils.metrics import device_fields, emit_metrics_json
 from ..utils.pytree import flatten_params
-from .optimizers import server_sgd
-from .steps import make_eval_step
-from .train_state import create_train_state
-
-
-#: images one evaluation batch of the sync trainer holds
-EVAL_BATCH = 1000
+from .tasks import task_for
 
 
 @dataclass
@@ -63,11 +57,24 @@ class DistributedConfig:
     num_classes: int = 100
     dtype: str = "bfloat16"
     model: str = "resnet18"        # models/registry.py name
+    # What a decoder LM is built from (models/registry.py:lm_config): a
+    # configuration object or a preset's name; image models take none.
+    model_config: object = None
+    # The optimizer's values beside the learning rate, for a task whose
+    # optimizer has any (train/optimizers.py:adamw's keywords); None: its
+    # defaults. The image task's SGD takes none.
+    optimizer: dict | None = None
     seed: int = 0
 
 
 class SyncTrainer:
     """Sync data-parallel training over a device mesh (no server process).
+
+    What is trained comes with the model's family as a task
+    (train/tasks.py): images and labels for the ResNets and ViTs
+    (``dataset`` a ``data.cifar.Dataset``), packed token rows for a decoder
+    LM (a ``data.tokens.TokenDataset``). The epoch loop, the spans, the
+    exchange and the update below are the same for both.
 
     Multi-host: when the process has already joined a multi-controller job
     (``parallel.initialize_multihost``; ``jax.process_count() > 1``), the
@@ -96,24 +103,24 @@ class SyncTrainer:
             self.mesh = make_mesh(cfg.num_workers)
         import jax.numpy as jnp
 
-        from ..models import get_model
         dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
-        self.model = get_model(cfg.model, num_classes=cfg.num_classes,
-                               dtype=dtype, axis_name="data",
-                               image_size=dataset.x_train.shape[1])
-        h, w = dataset.x_train.shape[1:3]
-        self.state = create_train_state(
+        self.task = task = task_for(cfg.model, augment=cfg.augment,
+                                    model_config=cfg.model_config)
+        self.model = task.make_model(cfg, dataset, dtype, "data")
+        self.state = task.init_state(
             self.model, jax.random.PRNGKey(cfg.seed),
-            server_sgd(cfg.learning_rate), input_shape=(1, h, w, 3))
+            task.make_optimizer(cfg), dataset)
         if self.multihost:
             from ..parallel.multihost import replicate_to_mesh
             self.state = replicate_to_mesh(self.mesh, self.state)
+        else:
+            self.state = task.place_state(self.mesh, self.state)
         self._step = make_sync_dp_step(self.mesh,
                                        compression=cfg.compression,
-                                       augment=cfg.augment)
+                                       task=task)
         # multi-host evaluates on a fetched copy, process-locally
-        self._eval_step = (jax.jit(make_eval_step()) if self.multihost
-                           else make_sync_dp_eval_step(self.mesh))
+        self._eval_step = (jax.jit(task.eval_step()) if self.multihost
+                           else make_sync_dp_eval_step(self.mesh, task))
         self.epoch_times: list[float] = []
         self.test_accuracies: list[float] = []
         self.global_steps = 0
@@ -157,6 +164,7 @@ class SyncTrainer:
         tm_step_s = reg.histogram("dps_trainer_step_seconds", mode="sync")
         tm_steps = reg.counter("dps_trainer_steps_total", mode="sync")
         tm_images = reg.counter("dps_trainer_images_total", mode="sync")
+        task = self.task
         tm_epoch = reg.gauge("dps_trainer_epoch", mode="sync")
         tm_acc = reg.gauge("dps_trainer_test_accuracy", mode="sync")
         tm_gstep = reg.gauge("dps_store_global_step", backend="spmd")
@@ -178,7 +186,7 @@ class SyncTrainer:
 
         # make_batches drops the remainder: this many batches an epoch
         steps_per_epoch = len(self.dataset.x_train) // global_batch
-        eval_batches = -(-len(self.dataset.x_test) // EVAL_BATCH)
+        eval_batches = task.eval_batch_count(self.dataset, global_batch)
         t_start = time.time()
         per_worker_epochs = []   # per epoch: {"loss": [N], "accuracy": [N]}
         epoch_loss = None        # last epoch's mean train loss
@@ -188,23 +196,25 @@ class SyncTrainer:
                 t0 = time.time()
                 losses = []
                 per_worker = []   # per step: ([N] losses, [N] accuracies)
-                batches = make_batches(self.dataset.x_train,
-                                       self.dataset.y_train, global_batch,
-                                       seed=cfg.seed * 997 + epoch)
+                step_metrics = []  # per step: what the task's step reports
+                batches = task.train_batches(self.dataset, global_batch,
+                                             seed=cfg.seed * 997 + epoch)
                 for _ in range(steps_per_epoch):
                     with phase("trainer.input", epoch=epoch,
                                step=self.global_steps) as sp:
-                        xb, yb = next(batches)
-                        sp.attrs["bytes"] = xb.nbytes + yb.nbytes
-                        bi, bl = self._shard((xb, yb))
+                        batch = next(batches)
+                        sp.attrs["bytes"] = sum(a.nbytes for a in batch)
+                        placed = self._shard(batch)
                     t_step = _tnow()
                     with phase("trainer.step", mode="sync", epoch=epoch,
                                step=self.global_steps), gp.span("compute"):
-                        self.state, m = self._step(self.state, bi, bl, rng)
+                        self.state, m = self._step(self.state, *placed, rng)
                     losses.append(m["loss"])
                     tm_step_s.observe(_tnow() - t_step)
                     tm_steps.inc()
-                    tm_images.inc(len(xb))
+                    tm_images.inc(len(batch[0]))
+                    if task.extra_metrics:
+                        step_metrics.append(m)
                     if not self.multihost:
                         # Multihost: the [N] vectors span processes and
                         # can't be fetched locally; per-worker rows stay
@@ -234,6 +244,7 @@ class SyncTrainer:
                         loss, accuracy = np.mean(rows, axis=0)
                         per_worker_epochs.append(
                             {"loss": loss, "accuracy": accuracy})
+                    task.record_epoch(reg, step_metrics)
                 # In multihost mode only rank 0 pays for the full test
                 # pass — the state is replicated, so the others' evals
                 # would be identical duplicated work on the critical path.
@@ -330,10 +341,10 @@ class SyncTrainer:
             from ..parallel.multihost import fetch_replicated
             state = fetch_replicated(self.state)
         correct = total = 0
-        for xb, yb in make_batches(self.dataset.x_test, self.dataset.y_test,
-                                   EVAL_BATCH, shuffle=False,
-                                   drop_remainder=False):
-            c, t = self._eval_step(state, xb, yb)
+        for batch in self.task.eval_batches(
+                self.dataset, self.config.batch_size
+                * self.config.num_workers):
+            c, t = self._eval_step(state, *batch)
             correct += int(c)
             total += int(t)
         return correct / max(total, 1)

@@ -12,12 +12,18 @@ We reproduce both *deliberately*: :func:`server_sgd` is the distributed-mode
 optimizer (matching the server math), :func:`baseline_optimizer` is the
 baseline recipe, and callers may opt into the full recipe for distributed
 training too (the "corrected" choice the reference never made).
+
+:func:`adamw` is the decoder-LM task's optimizer (train/tasks.py chooses
+the optimizer with the model family): the reference has no language model
+and no Adam.
 """
 
 from __future__ import annotations
 
 from typing import Sequence
 
+import jax
+import jax.numpy as jnp
 import optax
 
 
@@ -45,4 +51,22 @@ def baseline_optimizer(
     return optax.chain(
         optax.add_decayed_weights(weight_decay),
         optax.sgd(schedule, momentum=momentum),
+    )
+
+
+def adamw(learning_rate: float, *, b1: float = 0.9, b2: float = 0.95,
+          eps: float = 1e-8,
+          weight_decay: float = 0.1) -> optax.GradientTransformation:
+    """AdamW with float32 moments and decoupled weight decay on matrices
+    only (norm gains and other vectors are not decayed): with float32
+    weights and gradients, 16 bytes a parameter. It is ``optax.adamw``'s
+    update (a test holds the two equal on one tensor), written as its three
+    steps so that the mask and the moments' type are in sight."""
+    return optax.chain(
+        optax.scale_by_adam(b1=b1, b2=b2, eps=eps, mu_dtype=jnp.float32),
+        optax.add_decayed_weights(
+            weight_decay,
+            mask=lambda params: jax.tree_util.tree_map(
+                lambda p: p.ndim >= 2, params)),
+        optax.scale_by_learning_rate(learning_rate),
     )

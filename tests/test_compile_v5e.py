@@ -162,3 +162,93 @@ def test_vit_b16_evaluation_on_four_chips_holds_the_fused_core(
         _shaped((128,), jnp.int32, replicated)).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 12
     assert "[128,12,197,197]" not in text
+
+
+# -- the decoder LM's cell (joyai-flash-ep16-sync-1chip) ----------------------
+
+#: what one v5e's 16 GB leave a program (arguments + temporaries)
+LM_MEMORY_LIMIT = 15.7e9
+
+
+@pytest.fixture(scope="module")
+def lm_programs(topo):
+    """The cell's step and evaluation programs (``worker_step`` /
+    ``eval_step`` with the LM task, the ``ep16`` preset, 4 sequences of
+    4,096 tokens, bf16, AdamW) compiled for one described v5e; about a
+    minute and a half, once for the tests below."""
+    from distributed_parameter_server_for_ml_training_tpu.parallel.sync_dp \
+        import make_sync_dp_eval_step, make_sync_dp_step
+    from distributed_parameter_server_for_ml_training_tpu.train.distributed \
+        import DistributedConfig
+    from distributed_parameter_server_for_ml_training_tpu.train.tasks import (
+        LMTask)
+
+    class Data:
+        vocab_size, seq_len = 16160, 4096
+
+    was_tpu, at._on_tpu = at._on_tpu, lambda: True
+    was_cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+        replicated, split = (NamedSharding(mesh, P()),
+                             NamedSharding(mesh, P("data")))
+        task = LMTask("ep16")
+        cfg = DistributedConfig(model="joyai_llm_flash", learning_rate=3e-4)
+        model = task.make_model(cfg, Data, jnp.bfloat16, "data")
+        state = jax.eval_shape(lambda: task.init_state(
+            model, jax.random.PRNGKey(0), task.make_optimizer(cfg), Data))
+        state = jax.tree_util.tree_map(
+            lambda x: _shaped(x.shape, x.dtype, replicated), state)
+        step = make_sync_dp_step(mesh, compression="none", task=task).lower(
+            state, _shaped((4, 4098), jnp.int32, split),
+            _shaped((2,), jnp.uint32, replicated)).compile()
+        evaluation = make_sync_dp_eval_step(mesh, task).lower(
+            state, _shaped((4, 4098), jnp.int32, replicated)).compile()
+    finally:
+        at._on_tpu = was_tpu
+        jax.config.update("jax_enable_compilation_cache", was_cache)
+    return state, step, evaluation
+
+
+def test_lm_step_fits_one_chip_and_keeps_no_score_matrix(lm_programs):
+    state, step, _evaluation = lm_programs
+    n = sum(int(np.prod(x.shape))
+            for x in jax.tree_util.tree_leaves(state.params))
+    assert n + 5 * 256 == 680_441_088
+    memory = step.memory_analysis()
+    # parameters and the two float32 moments are the arguments, donated;
+    # the float32 gradients are among the temporaries
+    assert memory.argument_size_in_bytes >= 12 * n
+    assert memory.alias_size_in_bytes >= 12 * n
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < LM_MEMORY_LIMIT), memory
+    text = step.as_text()
+    assert not re.search(r"\[\d+,32,4096,4096\]", text)
+    assert not re.search(r"\[128,4096,4096\]", text)
+    names = set(re.findall(
+        r"%([\w\-]+?)(?:\.\d+)? = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        text))
+    # the three flash kernels (six blocks: 12 forward runs with the
+    # recomputation, 6 and 6 backward) and XLA's grouped-matmul kernels
+    assert {"flash_attention_fwd", "flash_attention_bwd_dq",
+            "flash_attention_bwd_dkv"} <= names
+    assert any(name.startswith("ragged-dot") for name in names)
+    for kernel, calls in (("flash_attention_fwd", 12),
+                          ("flash_attention_bwd_dq", 6),
+                          ("flash_attention_bwd_dkv", 6)):
+        assert len(re.findall(rf"%{kernel}(?:\.\d+)? = ", text)) == calls
+
+
+def test_lm_evaluation_fits_beside_the_state(lm_programs):
+    state, _step, evaluation = lm_programs
+    memory = evaluation.memory_analysis()
+    held = sum(int(np.prod(x.shape)) * x.dtype.itemsize
+               for x in jax.tree_util.tree_leaves(state))
+    # the whole state stays resident while the evaluation runs
+    assert held + memory.temp_size_in_bytes < LM_MEMORY_LIMIT, memory
+    text = evaluation.as_text()
+    assert not re.search(r"\[\d+,32,4096,4096\]", text)
+    # five blocks: the accuracy is the main head's, so the MTP module's
+    # block is dead code in this program
+    assert len(re.findall(r"%flash_attention_fwd(?:\.\d+)? = ", text)) == 5

@@ -199,3 +199,87 @@ def test_causal_gradients_match_dense():
         np.testing.assert_allclose(np.asarray(gf), np.asarray(gd),
                                    atol=1e-4, rtol=1e-4,
                                    err_msg=f"d{name} mismatch")
+
+
+# -- v narrower than q and k (latent attention) -------------------------------
+
+def _dense_f32(q, k, v, causal):
+    """Softmax attention in float32, the scale from q's width."""
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    if causal:
+        t = q.shape[1]
+        s = jnp.where(jnp.tril(jnp.ones((t, t), bool))[None, None], s,
+                      -jnp.inf)
+    return jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
+
+
+@pytest.mark.parametrize("use_pallas,t", [(True, 256), (True, 200),
+                                          (False, 200)])
+def test_v_may_have_a_width_of_its_own(use_pallas, t, monkeypatch):
+    """q/k heads of 48 and v heads of 32 (the LM's are 192 and 128), causal:
+    the kernels in interpret mode and the fallback, forward and all three
+    gradients; o and dv have v's width."""
+    import distributed_parameter_server_for_ml_training_tpu.ops.pallas.flash_attention as fa
+
+    monkeypatch.setattr(fa, "INTERPRET", True)
+    ks = jax.random.split(jax.random.PRNGKey(3), 4)
+    q, k = (jax.random.normal(x, (2, t, 2, 48)) for x in ks[:2])
+    v = jax.random.normal(ks[2], (2, t, 2, 32))
+    cot = jax.random.normal(ks[3], v.shape)
+
+    def run(fn):
+        return jax.value_and_grad(
+            lambda a, b, c: jnp.sum(fn(a, b, c) * cot),
+            argnums=(0, 1, 2))(q, k, v)
+
+    out = flash_attention(q, k, v, causal=True, use_pallas=use_pallas)
+    assert out.shape == v.shape
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(_dense_f32(q, k, v, True)),
+                               atol=2e-3, rtol=2e-3)
+    (_l, got), (_l2, want) = (
+        run(lambda a, b, c: flash_attention(a, b, c, causal=True,
+                                            use_pallas=use_pallas)),
+        run(lambda a, b, c: _dense_f32(a, b, c, True)))
+    for g, w, name in zip(got, want, "qkv"):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=5e-3,
+                                   rtol=5e-3, err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("on_tpu,dtype,t,causal,dv,want", [
+    (True, jnp.bfloat16, 4096, True, 128, "flash"),     # the LM's cell
+    (True, jnp.bfloat16, 4096, False, 192, "flash"),
+    (True, jnp.bfloat16, 1024, True, 128, "flash"),
+    (True, jnp.bfloat16, 1000, True, 128, "dense"),     # not whole tiles
+    (True, jnp.bfloat16, 512, True, 128, "dense"),      # short and causal
+    (True, jnp.float32, 4096, True, 128, "dense"),      # an fp32 model
+    (False, jnp.bfloat16, 4096, True, 128, "dense"),    # every CPU run
+    (True, jnp.bfloat16, 197, False, 32, "dense"),      # v narrower: not
+])                                                      # the fused kernel
+def test_select_core_sends_long_bf16_sequences_to_flash(on_tpu, dtype, t,
+                                                        causal, dv, want):
+    from distributed_parameter_server_for_ml_training_tpu.ops import (
+        attention as at)
+    assert at.select_core(on_tpu=on_tpu, causal=causal, dtype=dtype, t=t,
+                          num_heads=32, head_dim=64 if dv == 32 else 192,
+                          v_head_dim=dv) == want
+
+
+def test_heads_attention_core_counts_and_computes(monkeypatch):
+    from distributed_parameter_server_for_ml_training_tpu.ops import (
+        attention as at)
+    from distributed_parameter_server_for_ml_training_tpu.telemetry import (
+        get_registry)
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    q, k = (jax.random.normal(x, (1, 40, 2, 24)) for x in ks[:2])
+    v = jax.random.normal(ks[2], (1, 40, 2, 16))
+    counter = get_registry().counter("dps_attention_core_total",
+                                     impl="dense")
+    before = counter.value
+    out = jax.jit(lambda a, b, c: at.heads_attention_core(
+        a, b, c, causal=True))(q, k, v)
+    assert counter.value == before + 1
+    np.testing.assert_allclose(np.asarray(out),
+                               np.asarray(_dense_f32(q, k, v, True)),
+                               atol=1e-5)
