@@ -54,7 +54,8 @@ _LIVE: dict[subprocess.Popen, str] = {}   # running child -> log name
 #: The kernel child's checks, by name; the parent requires every one.
 KERNEL_CHECKS = (
     "flash_fp32_T256", "flash_fp32_T256_causal", "flash_bf16_T4097",
-    "flash_bf16_T1024_causal", "short_attn_bf16_T197",
+    "flash_bf16_T1024_causal", "flash_heads_major_bf16_T4096_causal",
+    "short_attn_bf16_T197",
     "vit_b16_grads_fused_vs_dense", "quantize_2359296",
     "quantize_non_multiple_of_128", "device_codec_resnet18_tree")
 
@@ -507,6 +508,51 @@ def _child_kernels(only: tuple = ()) -> int:
         lambda: flash_case(2, 4097, 12, 64, jnp.bfloat16, False, 3e-2))
     check("flash_bf16_T1024_causal")(
         lambda: flash_case(2, 1024, 12, 64, jnp.bfloat16, True, 3e-2))
+
+    def heads_major_case(b, t, h, d, dv, tol):
+        """The heads-major entry (q, k ``[B, H, T, D]``; v, o ``[B, T,
+        H*Dv]`` read a head's lanes at a time by the kernels' block specs)
+        against ``flash_attention`` on the same numbers as ``[B, T, H,
+        D]``, causal, at latent attention's widths: the same kernels on the
+        same blocks, so the outputs are equal and the gradients differ by
+        rounding at most."""
+        ks = jax.random.split(jax.random.PRNGKey(t), 4)
+        q, k = (jax.random.normal(kk, (b, t, h, d), jnp.bfloat16)
+                for kk in ks[:2])
+        v, cot = (jax.random.normal(kk, (b, t, h, dv), jnp.bfloat16)
+                  for kk in ks[2:])
+
+        def bthd(q, k, v):
+            return fa.flash_attention(q, k, v, causal=True, use_pallas=True)
+
+        def heads_major(q, k, v):
+            return fa.flash_attention_heads_major(
+                q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                v.reshape(b, t, h * dv), causal=True).reshape(b, t, h, dv)
+
+        def both(fn, kernels):
+            exe, seconds, calls = compiled(
+                lambda q, k, v, cot: jax.value_and_grad(
+                    lambda q, k, v: jnp.sum(fn(q, k, v).astype(jnp.float32)
+                                            * cot.astype(jnp.float32)),
+                    argnums=(0, 1, 2))(q, k, v), q, k, v, cot)
+            assert calls == kernels, calls
+            return exe(q, k, v, cot), seconds
+
+        (want_l, want), _s = both(bthd, 3)
+        # one more: delta = rowsum(dO * O) a head, read out of [B, T, H*Dv]
+        (got_l, got), seconds = both(heads_major, 4)
+        errs = {key: rel_err(g, w) for key, g, w in
+                zip(("dq", "dk", "dv"), got, want)}
+        errs["o"] = rel_err(jax.jit(heads_major)(q, k, v),
+                            jax.jit(bthd)(q, k, v))
+        assert errs["o"] == 0.0 and max(errs.values()) <= tol, (errs, tol)
+        return {"shape": [b, t, h, d, dv], "compile_s": round(seconds, 2),
+                "tol": tol, "max_err_over_max_bthd": {
+                    k: round(v, 6) for k, v in errs.items()}}
+
+    check("flash_heads_major_bf16_T4096_causal")(
+        lambda: heads_major_case(1, 4096, 4, 192, 128, 1e-2))
 
     def short_case(b, t, h, d, tol):
         """The fused short-sequence kernel as ``attention_core`` calls it

@@ -38,6 +38,7 @@ from typing import Any
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..ops.attention import heads_attention_core
 from ..parallel import moe
@@ -142,15 +143,21 @@ def rms_norm(x, gain, eps):
     return (x32 * scale * gain).astype(x.dtype)
 
 
+def _rope_cos_sin(t: int, r: int, theta: float):
+    """``cos`` and ``sin`` of ``pos * theta^(-2i/R)``, each ``[T, R/2]``
+    float32: pair ``i``'s angle at every position."""
+    freqs = theta ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
+    angles = jnp.arange(t, dtype=jnp.float32)[:, None] * freqs[None]
+    return jnp.cos(angles), jnp.sin(angles)
+
+
 def rope_interleaved(x, theta: float):
     """RoPE on ``[B, T, ..., R]``, position = index along axis 1, pairs
     ``(x[2i], x[2i+1])`` rotated by ``pos * theta^(-2i/R)``
     (``rope_interleave``), no scaling; float32 inside."""
     t, r = x.shape[1], x.shape[-1]
-    freqs = theta ** (-jnp.arange(0, r, 2, dtype=jnp.float32) / r)
-    angles = jnp.arange(t, dtype=jnp.float32)[:, None] * freqs[None]
     shape = (1, t) + (1,) * (x.ndim - 3) + (r // 2,)
-    cos, sin = jnp.cos(angles).reshape(shape), jnp.sin(angles).reshape(shape)
+    cos, sin = (a.reshape(shape) for a in _rope_cos_sin(t, r, theta))
     pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (r // 2, 2))
     even, odd = pairs[..., 0], pairs[..., 1]
     out = jnp.stack([even * cos - odd * sin, even * sin + odd * cos], -1)
@@ -194,42 +201,117 @@ class SwiGLU(nn.Module):
                       name="down")(jax.nn.silu(gate) * up)
 
 
+def half_split_lanes(r: int) -> np.ndarray:
+    """The permutation of ``r`` rotary lanes from interleaved to half-split
+    order, ``(0, 2, 4, ..., 1, 3, 5, ...)``: after it the pair ``(x[2i],
+    x[2i+1])`` that ``rope_interleaved`` rotates is ``(x'[i], x'[i +
+    r/2])``."""
+    return np.concatenate([np.arange(0, r, 2), np.arange(1, r, 2)])
+
+
+def rope_half_split(x, theta: float):
+    """``rope_interleaved`` on lanes that ``half_split_lanes`` has permuted:
+    ``x`` is ``[..., T, R]``, position = index along axis -2, and pair ``i``
+    is ``(x[i], x[i + R/2])``, rotated by the same ``pos * theta^(-2i/R)``;
+    float32 inside. The same products and angles, lane for lane, with the
+    two halves contiguous: no ``(R/2, 2)`` reshape and no ``stack``, which
+    on a TPU are a stride-2 shuffle across lanes."""
+    t, r = x.shape[-2], x.shape[-1]
+    cos, sin = _rope_cos_sin(t, r, theta)
+    x32 = x.astype(jnp.float32)
+    lo, hi = x32[..., :r // 2], x32[..., r // 2:]
+    return jnp.concatenate([lo * cos - hi * sin, lo * sin + hi * cos],
+                           axis=-1).astype(x.dtype)
+
+
+class Kernel(nn.Module):
+    """A ``Linear``'s parameter (same name, shape, float32 storage and
+    initialiser) as ``dtype`` operand, for a caller that cuts it before the
+    matmul."""
+    shape: tuple
+    cfg: JoyAIConfig
+    dtype: Dtype
+
+    @nn.compact
+    def __call__(self):
+        return self.param("kernel", _normal(self.cfg), self.shape,
+                          jnp.float32).astype(self.dtype)
+
+
 class MLA(nn.Module):
     """Latent attention, unabsorbed. ``c_q = RMSNorm(W_qa u)``; ``q = W_qb
     c_q`` as H heads of ``[q_nope; q_rope]``. ``[c_kv; k_rope] = W_kva u``;
     ``c_kv = RMSNorm(c_kv)``; ``W_kvb c_kv`` as H heads of ``[k_nope; v]``;
     ``k_rope`` is ONE vector shared by all heads. RoPE on ``q_rope`` and
     ``k_rope``. Scores ``q.k / sqrt(nope + rope)``, causal, softmax in
-    float32; ``W_o`` on the heads' ``P v``."""
+    float32; ``W_o`` on the heads' ``P v``.
+
+    **Layouts.** ``u`` and the result are ``[B, T, hidden]``. Between the
+    projections and the attention core every array is written once, in the
+    layout the flash kernels read (``ops.attention.heads_attention_core``):
+    q and k heads-major ``[B, H, T, nope+rope]``, v and ``o`` ``[B, T,
+    H*v]`` as their matmuls write and read them (the kernels take a head's
+    128 lanes of those as a block). The parameters keep their published
+    shapes (``q_b`` ``[q_lora, H*(nope+rope)]``, ``kv_a`` ``[hidden,
+    kv_lora+rope]``, ``kv_b`` ``[kv_lora, H*(nope+v)]``); what is cut and
+    reordered is the cast weight, a few million elements, never an
+    activation of ``B*T`` rows:
+
+    - ``q_b`` and ``kv_b`` are viewed as ``[C, H, D]`` and cut into their
+      parts (``q_nope | q_rope``, ``k_nope | v``); ``q_nope``, ``q_rope``
+      and ``k_nope`` are projected per head straight to ``[B, H, T, D]``,
+      and ``v`` by a plain matmul to ``[B, T, H*v]``, from where it goes to
+      the kernel as it is; ``o`` comes back the same way into ``W_o``;
+    - scores sum ``q_rope . k_rope`` over the rotary lanes, so one
+      permutation of those lanes on both sides changes nothing: the rotary
+      columns of ``q_b`` (per head) and of ``kv_a`` are put in half-split
+      order (``half_split_lanes``) and the rotation is
+      ``rope_half_split``, the products and angles of
+      ``rope_interleaved`` (the definition the tests and the reference hold
+      this to) on two contiguous halves;
+    - ``q = [q_nope; rot(q_rope)]`` and ``k = [k_nope; rot(k_rope) for
+      every head]`` are one fusion each, which writes the kernel's operand;
+      ``k_rope`` is rotated once as ``[B, T, rope]`` and never exists per
+      head on its own."""
     cfg: JoyAIConfig
     dtype: Dtype
 
     @nn.compact
     def __call__(self, u):
         cfg, dt = self.cfg, self.dtype
-        b, t, _ = u.shape
+        d = u.shape[-1]
         h, nope, rope, vd = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                              cfg.qk_rope_head_dim, cfg.v_head_dim)
+        lanes = half_split_lanes(rope)
         c_q = RMSNorm(cfg.rms_norm_eps, name="q_a_norm")(
             Linear(cfg.q_lora_rank, cfg, dt, name="q_a")(u))
-        q = Linear(h * (nope + rope), cfg, dt, name="q_b")(c_q).reshape(
-            b, t, h, nope + rope)
-        kv_a = Linear(cfg.kv_lora_rank + rope, cfg, dt, name="kv_a")(u)
+        w_q = Kernel((cfg.q_lora_rank, h * (nope + rope)), cfg, dt,
+                     name="q_b")().reshape(cfg.q_lora_rank, h, nope + rope)
+        # cut, then permute the cut: one gather over the uncut lanes
+        # (``nope + lanes``) has XLA re-lay ``q_b`` and both its moments
+        q_nope = jnp.einsum("btc,chd->bhtd", c_q, w_q[..., :nope])
+        q_rope = jnp.einsum("btc,chd->bhtd", c_q, w_q[..., nope:][..., lanes])
+        w_kv_a = Kernel((d, cfg.kv_lora_rank + rope), cfg, dt, name="kv_a")()
+        u = u.astype(dt)
         c_kv = RMSNorm(cfg.rms_norm_eps, name="kv_a_norm")(
-            kv_a[..., :cfg.kv_lora_rank])
-        k_rope = rope_interleaved(kv_a[..., None, cfg.kv_lora_rank:],
-                                  cfg.rope_theta)            # [B, T, 1, R]
-        kv = Linear(h * (nope + vd), cfg, dt, name="kv_b")(c_kv).reshape(
-            b, t, h, nope + vd)
+            u @ w_kv_a[:, :cfg.kv_lora_rank])
+        k_rope = rope_half_split(
+            u @ w_kv_a[:, cfg.kv_lora_rank:][:, lanes],
+            cfg.rope_theta)                                  # [B, T, R]
+        w_kv = Kernel((cfg.kv_lora_rank, h * (nope + vd)), cfg, dt,
+                      name="kv_b")().reshape(cfg.kv_lora_rank, h, nope + vd)
+        k_nope = jnp.einsum("btc,chd->bhtd", c_kv, w_kv[..., :nope])
+        v = c_kv @ w_kv[..., nope:].reshape(cfg.kv_lora_rank, h * vd)
         q = jnp.concatenate(
-            [q[..., :nope], rope_interleaved(q[..., nope:], cfg.rope_theta)],
-            axis=-1)
-        k = jnp.concatenate(
-            [kv[..., :nope], jnp.broadcast_to(k_rope, (b, t, h, rope))],
-            axis=-1)
-        o = heads_attention_core(q, k, kv[..., nope:], causal=True)
-        return Linear(cfg.hidden_size, cfg, dt, name="o")(
-            o.reshape(b, t, h * vd))
+            [q_nope, rope_half_split(q_rope, cfg.rope_theta)], axis=-1)
+        # [k_nope; k_rope for every head] as a sum of two zero-padded terms:
+        # the broadcast over heads then happens inside the one fusion that
+        # writes k (a concatenate's operands are materialised first, and
+        # k_rope per head is 67 MB a block)
+        k = (jnp.pad(k_nope, ((0, 0),) * 3 + ((0, rope),))
+             + jnp.pad(k_rope, ((0, 0), (0, 0), (nope, 0)))[:, None])
+        o = heads_attention_core(q, k, v, causal=True)       # [B, T, H*vd]
+        return Linear(cfg.hidden_size, cfg, dt, name="o")(o)
 
 
 class ExpertLayer(nn.Module):

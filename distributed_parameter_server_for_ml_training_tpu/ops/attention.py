@@ -21,6 +21,17 @@ latent attention runs (models/joyai.py: separate q, k and v, v narrower
 than q and k): one softmax attention, three ways to schedule it, chosen
 by the backend and static shapes alone (``select_core``).
 
+Two layouts come in at the door. ``[B, T, H, D]`` is what a fused or
+per-tensor Dense writes and what ``dense_core``, ``flash_attention`` and
+``attention_core``'s callers hold; the flash kernels read ``[B*H, T, D]``,
+so that door transposes q, k, v and ``o``. models/joyai.py:MLA, the one
+heads-major caller, hands ``heads_attention_core`` the arrays the kernels
+read as they are: q and k heads-major ``[B, H, T, D]`` (projected per head
+and concatenated straight into it), v as ``[B, T, H*Dv]`` the way its
+matmul writes it, and takes ``o`` back as ``[B, T, H*Dv]`` the way the
+output matmul reads it (a head's ``Dv`` lanes are a block of the kernels'
+block specs): nothing is transposed on the way to a kernel or back.
+
 Kept light (jnp, the telemetry registry; the kernel module is imported
 only when chosen) so models, ops and experiments can all import it without
 cycles.
@@ -36,19 +47,23 @@ _NEG_INF = -1e30
 
 
 def dense_core(q: jax.Array, k: jax.Array, v: jax.Array, *,
-               causal: bool = False) -> jax.Array:
+               causal: bool = False, heads_major: bool = False) -> jax.Array:
     """[B, T, H, D] x3 -> [B, T, H, D] softmax attention in the input
-    dtype (fp32 softmax). ``v`` may have a width of its own; the scale is
-    1/sqrt of q's."""
+    dtype (fp32 softmax); with ``heads_major`` the arrays the caller holds
+    are ``[B, H, T, D]``, in and out: the same einsums with their letters
+    in that order. ``v`` may have a width of its own; the scale is 1/sqrt
+    of q's."""
     d = q.shape[-1]
     scale = 1.0 / np.sqrt(d)
-    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    qk, pv = (("bhqd,bhkd->bhqk", "bhqk,bhkd->bhqd") if heads_major
+              else ("bqhd,bkhd->bhqk", "bhqk,bkhd->bqhd"))
+    logits = jnp.einsum(qk, q, k) * scale
     if causal:
-        t = q.shape[1]
+        t = logits.shape[-1]
         mask = jnp.tril(jnp.ones((t, t), bool))
         logits = jnp.where(mask[None, None], logits, _NEG_INF)
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q.dtype), v)
+    return jnp.einsum(pv, probs.astype(q.dtype), v)
 
 
 #: ``impl`` label of ``dps_attention_core_total`` -> one-line meaning
@@ -111,20 +126,30 @@ def _count(impl: str) -> None:
 
 def heads_attention_core(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          causal: bool = False) -> jax.Array:
-    """``[B, T, H, Dqk]`` x2 and ``[B, T, H, Dv]`` -> ``[B, T, H, Dv]``,
-    by the same rule and counted in the same counter as
-    ``attention_core``."""
-    _b, t, num_heads, head_dim = q.shape
+    """For a caller that projects per head (models/joyai.py:MLA): q and k
+    heads-major ``[B, H, T, Dqk]``, ``v`` ``[B, T, H*Dv]`` as its Dense
+    writes it -> ``o`` ``[B, T, H*Dv]`` as the output Dense reads it; by
+    the same rule and counted in the same counter as ``attention_core``.
+
+    These are the arrays the flash kernels read as they are (heads-major
+    q/k is ``[B*H, T, D]`` with its leading axes taken as one; a head's
+    ``Dv`` lanes of ``v`` and ``o`` are a block of theirs), so nothing is
+    transposed on the way to a kernel or back; ``dense_core`` gets ``v``
+    split into heads, which on the CPU costs nothing that matters."""
+    b, num_heads, t, head_dim = q.shape
+    v_head_dim = v.shape[-1] // num_heads
     impl = select_core(on_tpu=_on_tpu(), causal=causal, dtype=q.dtype, t=t,
                        num_heads=num_heads, head_dim=head_dim,
-                       v_head_dim=v.shape[-1])
+                       v_head_dim=v_head_dim)
     if impl == "fused_short":    # needs the packed qkv activation
         impl = "dense"
     _count(impl)
     if impl == "flash":
-        from .pallas.flash_attention import flash_attention
-        return flash_attention(q, k, v, causal=causal, use_pallas=True)
-    return dense_core(q, k, v, causal=causal)
+        from .pallas.flash_attention import flash_attention_heads_major
+        return flash_attention_heads_major(q, k, v, causal=causal)
+    o = dense_core(q, k, v.reshape(b, t, num_heads, v_head_dim).transpose(
+        0, 2, 1, 3), causal=causal, heads_major=True)
+    return o.transpose(0, 2, 1, 3).reshape(b, t, num_heads * v_head_dim)
 
 
 def attention_core(qkv: jax.Array, num_heads: int, *,

@@ -21,6 +21,12 @@ Design (standard flash attention, TPU-shaped):
 - ``v`` (and so ``o``) may have a head width of its own: latent attention
   (models/joyai.py) has q/k heads of 192 and v heads of 128. The softmax
   scale is 1/sqrt of the q/k width.
+- two doors to one set of kernels: ``flash_attention`` takes ``[B, T, H,
+  D]`` and transposes to the kernels' ``[B*H, T, D]`` and back;
+  ``flash_attention_heads_major`` takes what a caller that projects per
+  head holds (q, k ``[B, H, T, D]``; v, ``o`` ``[B, T, H*Dv]``) and moves
+  nothing: the kernel bodies see the same blocks, and the block specs of v,
+  ``o``, ``dO`` and ``dv`` pick a head's lanes (``v_heads`` below).
 - sequence lengths that aren't block multiples are zero-padded; padded KEY
   positions are masked to -inf in every kernel, padded QUERY rows fall out
   of the backward because their dO/delta are zero.
@@ -379,22 +385,64 @@ def _pos_scalars(q_offset, k_offset):
                       jnp.asarray(k_offset, jnp.int32)]).reshape(1, 2)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_core(q, k, v, kv_len, block_q, block_k, use_pallas, causal):
+def _split_heads(x, heads: int):
+    """``[B, T, H*W]`` -> ``[B*H, T, W]`` (the jnp fallback's view of a
+    ``v_heads`` array; the kernels read the array as it is)."""
+    b, t, width = x.shape
+    return x.reshape(b, t, heads, width // heads).transpose(
+        0, 2, 1, 3).reshape(b * heads, t, width // heads)
+
+
+def _merge_heads(x, heads: int):
+    """The inverse of ``_split_heads``."""
+    bh, t, w = x.shape
+    return x.reshape(bh // heads, heads, t, w).transpose(
+        0, 2, 1, 3).reshape(bh // heads, t, heads * w)
+
+
+def _head_block(rows, width: int, heads: int, whole: bool):
+    """The BlockSpec of one (batch, head)'s ``[rows, width]`` block for
+    grid ``(b*H + h, i)``: of a ``[B*H, T, width]`` array when ``heads`` is
+    0, else of a ``[B, T, H*width]`` array, the ``h``-th ``width`` lanes of
+    batch ``b`` (``width`` is whole 128-lane tiles there, so the block is
+    lane-aligned). ``whole``: all of T whatever ``i``."""
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    def index(g, i):
+        row = 0 if whole else i
+        return (g // heads, row, g % heads) if heads else (g, row, 0)
+
+    return pl.BlockSpec((1, rows, width), index, memory_space=pltpu.VMEM)
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_core(q, k, v, kv_len, block_q, block_k, use_pallas, causal,
+                v_heads=0):
     o, _ = _flash_fwd_impl(q, k, v, kv_len, block_q, block_k, use_pallas,
-                           causal=causal)
+                           causal=causal, v_heads=v_heads)
     return o
 
 
 def _flash_fwd_impl(q, k, v, kv_len, block_q, block_k, use_pallas,
-                    out_dtype=None, causal=False, q_offset=0, k_offset=0):
+                    out_dtype=None, causal=False, q_offset=0, k_offset=0,
+                    v_heads=0):
+    """``q``, ``k`` ``[B*H, T, D]``. ``v`` (and the ``o`` returned)
+    ``[B*H, T, Dv]`` or, with ``v_heads`` = H, ``[B, T, H*Dv]`` as a Dense
+    writes and reads them: the kernel bodies are the same, the block specs
+    pick head ``h``'s lanes of batch ``b``."""
     bh, tp, d = q.shape
-    dv = v.shape[2]        # v (and o) may be narrower than q and k (MLA)
+    # v (and o) may be narrower than q and k (MLA)
+    dv = v.shape[2] // v_heads if v_heads else v.shape[2]
     scale = 1.0 / np.sqrt(d)
     if not use_pallas:
         # out_dtype reaches the FINAL cast — an intermediate round-trip
         # through q.dtype would quantize the fp32 partials the ring merge
         # depends on.
+        if v_heads:
+            o, lse = _dense_fwd(q, k, _split_heads(v, v_heads), kv_len,
+                                scale, out_dtype, causal, q_offset, k_offset)
+            return _merge_heads(o, v_heads), lse
         return _dense_fwd(q, k, v, kv_len, scale, out_dtype,
                           causal, q_offset, k_offset)
 
@@ -405,12 +453,10 @@ def _flash_fwd_impl(q, k, v, kv_len, block_q, block_k, use_pallas,
     blk_pos = pl.BlockSpec(memory_space=pltpu.SMEM)
     blk_q = pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0),
                          memory_space=pltpu.VMEM)
-    blk_o = pl.BlockSpec((1, block_q, dv), lambda b, i: (b, i, 0),
-                         memory_space=pltpu.VMEM)
+    blk_o = _head_block(block_q, dv, v_heads, whole=False)
     blk_kfull = pl.BlockSpec((1, tp, d), lambda b, i: (b, 0, 0),
                              memory_space=pltpu.VMEM)
-    blk_vfull = pl.BlockSpec((1, tp, dv), lambda b, i: (b, 0, 0),
-                             memory_space=pltpu.VMEM)
+    blk_vfull = _head_block(tp, dv, v_heads, whole=True)
     # LSE rides as [BH, T, 1]: a (1, BLOCK_Q, 1) block keeps the last
     # two dims tileable ((BLOCK_Q, 1): sublanes % 8 == 0, lane dim == array).
     blk_lse = pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0),
@@ -421,7 +467,8 @@ def _flash_fwd_impl(q, k, v, kv_len, block_q, block_k, use_pallas,
         grid=(bh, n_q),
         in_specs=[blk_pos, blk_q, blk_kfull, blk_vfull],
         out_specs=(blk_o, blk_lse),
-        out_shape=(jax.ShapeDtypeStruct((bh, tp, dv), out_dtype or q.dtype),
+        out_shape=(jax.ShapeDtypeStruct((v.shape[0], tp, v.shape[2]),
+                                        out_dtype or q.dtype),
                    jax.ShapeDtypeStruct((bh, tp, 1), jnp.float32)),
         compiler_params=_compiler_params(),
         interpret=INTERPRET, name="flash_attention_fwd",
@@ -429,15 +476,17 @@ def _flash_fwd_impl(q, k, v, kv_len, block_q, block_k, use_pallas,
     return o, lse
 
 
-def _flash_core_fwd(q, k, v, kv_len, block_q, block_k, use_pallas, causal):
+def _flash_core_fwd(q, k, v, kv_len, block_q, block_k, use_pallas, causal,
+                    v_heads=0):
     o, lse = _flash_fwd_impl(q, k, v, kv_len, block_q, block_k, use_pallas,
-                             causal=causal)
+                             causal=causal, v_heads=v_heads)
     return o, (q, k, v, o, lse)
 
 
 def _flash_bwd_impl(q, k, v, do, lse, delta, kv_len, block_q, block_k,
                     use_pallas, out_dtype=None,
-                    causal=False, q_offset=0, k_offset=0, q_len=None):
+                    causal=False, q_offset=0, k_offset=0, q_len=None,
+                    v_heads=0):
     """Flash backward given EXTERNAL (lse, delta) — shared by the custom
     VJP below and by ring attention's per-hop backward
     (parallel/ring_attention.py), where lse/delta come from the MERGED
@@ -445,13 +494,21 @@ def _flash_bwd_impl(q, k, v, do, lse, delta, kv_len, block_q, block_k,
     dtype (the ring accumulates partials in fp32). ``q_len`` is the
     UNPADDED query length (padded query rows carry zero dO/delta, so the
     dK/dV kernel skips those blocks); defaults to the padded length,
-    i.e. no skipping."""
+    i.e. no skipping. ``v_heads`` = H: ``v``, ``do`` and the ``dv``
+    returned are ``[B, T, H*Dv]`` (``_flash_fwd_impl``)."""
     bh, tq, d = q.shape
-    tk, dv = k.shape[1], v.shape[2]
+    tk = k.shape[1]
+    dv = v.shape[2] // v_heads if v_heads else v.shape[2]
     q_len = tq if q_len is None else q_len
     scale = 1.0 / np.sqrt(d)
     dts = [out_dtype or x.dtype for x in (q, k, v)]
     if not use_pallas:
+        if v_heads:
+            dq, dk, dv = _flash_bwd_impl(
+                q, k, _split_heads(v, v_heads), _split_heads(do, v_heads),
+                lse, delta, kv_len, block_q, block_k, False, out_dtype,
+                causal, q_offset, k_offset, q_len)
+            return dq, dk, _merge_heads(dv, v_heads)
         qf, kf, vf = (x.astype(jnp.float32) for x in (q, k, v))
         dof = do.astype(jnp.float32)
         s = jnp.einsum("bqd,bkd->bqk", qf, kf) * scale
@@ -469,20 +526,16 @@ def _flash_bwd_impl(q, k, v, do, lse, delta, kv_len, block_q, block_k,
 
     blk_q = pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0),
                          memory_space=pltpu.VMEM)
-    blk_do = pl.BlockSpec((1, block_q, dv), lambda b, i: (b, i, 0),
-                          memory_space=pltpu.VMEM)
+    blk_do = _head_block(block_q, dv, v_heads, whole=False)
     blk_k = pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0),
                          memory_space=pltpu.VMEM)
-    blk_v = pl.BlockSpec((1, block_k, dv), lambda b, i: (b, i, 0),
-                         memory_space=pltpu.VMEM)
+    blk_v = _head_block(block_k, dv, v_heads, whole=False)
     blk_qfull = pl.BlockSpec((1, tq, d), lambda b, i: (b, 0, 0),
                              memory_space=pltpu.VMEM)
-    blk_dofull = pl.BlockSpec((1, tq, dv), lambda b, i: (b, 0, 0),
-                              memory_space=pltpu.VMEM)
+    blk_dofull = _head_block(tq, dv, v_heads, whole=True)
     blk_kfull = pl.BlockSpec((1, tk, d), lambda b, i: (b, 0, 0),
                              memory_space=pltpu.VMEM)
-    blk_vfull = pl.BlockSpec((1, tk, dv), lambda b, i: (b, 0, 0),
-                             memory_space=pltpu.VMEM)
+    blk_vfull = _head_block(tk, dv, v_heads, whole=True)
     blk_row_q = pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0),
                              memory_space=pltpu.VMEM)
     blk_row_qfull = pl.BlockSpec((1, tq, 1), lambda b, i: (b, 0, 0),
@@ -518,14 +571,52 @@ def _flash_bwd_impl(q, k, v, do, lse, delta, kv_len, block_q, block_k,
     return dq, dk, dv
 
 
-def _flash_core_bwd(kv_len, block_q, block_k, use_pallas, causal, res, do):
+def _delta_kernel(do_ref, o_ref, delta_ref):
+    delta_ref[0] = jnp.sum(do_ref[0].astype(jnp.float32)
+                           * o_ref[0].astype(jnp.float32),
+                           axis=-1, keepdims=True)
+
+
+def _delta_of_heads(do, o, heads: int, block_q: int, use_pallas: bool):
+    """``delta = rowsum(dO * O)`` a head, ``[B, T, H*Dv]`` x2 -> ``[B*H, T,
+    1]`` float32, as the backward kernels read it. A kernel of its own on
+    the chip: XLA sums a head's 128 lanes out of a ``[B, T, H*128]`` array
+    only after writing the whole product in float32 and copying it to
+    another tiling (three passes and 0.8 GB a block at latent attention's
+    sizes where the kernels' own ``[B*H, T, Dv]`` layout takes one); this
+    reads dO and O once, through the block specs the other kernels use."""
+    b, t, width = do.shape
+    dv = width // heads
+    if not use_pallas:
+        prod = do.astype(jnp.float32) * o.astype(jnp.float32)
+        return jnp.sum(prod.reshape(b, t, heads, dv), axis=-1).transpose(
+            0, 2, 1).reshape(b * heads, t, 1)
+
+    import jax.experimental.pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    blk = _head_block(block_q, dv, heads, whole=False)
+    return pl.pallas_call(
+        _delta_kernel, grid=(b * heads, t // block_q), in_specs=[blk, blk],
+        out_specs=pl.BlockSpec((1, block_q, 1), lambda g, i: (g, i, 0),
+                               memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct((b * heads, t, 1), jnp.float32),
+        interpret=INTERPRET, name="flash_attention_bwd_delta",
+    )(do, o)
+
+
+def _flash_core_bwd(kv_len, block_q, block_k, use_pallas, causal, v_heads,
+                    res, do):
     q, k, v, o, lse = res
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1, keepdims=True)            # [BH, T, 1]
+    if v_heads:
+        delta = _delta_of_heads(do, o, v_heads, block_q, use_pallas)
+    else:
+        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                        axis=-1, keepdims=True)        # [BH, T, 1]
     # Self-attention: q and k share the unpadded length, so q_len=kv_len.
     return _flash_bwd_impl(q, k, v, do, lse, delta, kv_len, block_q,
                            block_k, use_pallas, causal=causal,
-                           q_len=kv_len)
+                           q_len=kv_len, v_heads=v_heads)
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
@@ -533,12 +624,86 @@ _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
 
 # -- public op ----------------------------------------------------------------
 
+def _check_blocks(block_q, block_k) -> None:
+    for name, blk in (("block_q", block_q), ("block_k", block_k)):
+        if blk is not None and (blk <= 0 or blk % 128):
+            raise ValueError(
+                f"{name}={blk} must be a positive multiple of 128 (TPU "
+                f"tile constraint; defaults via pick_block satisfy it)")
+
+
+def _flash_heads_first(q3, k3, v3, block_q, block_k, use_pallas, causal,
+                       v_heads=0):
+    """``[B*H, T, D]`` x2 and ``[B*H, T, Dv]`` -> ``[B*H, T, Dv]`` (with
+    ``v_heads`` = H: ``[B, T, H*Dv]`` -> ``[B, T, H*Dv]``): the block
+    sizes, the padding of T and the core op, shared by both public entries
+    below."""
+    t = q3.shape[1]
+    # Default blocks: the largest 128-multiple <= MAX_BLOCK that DIVIDES the
+    # 128-rounded sequence length — a bare min() would pad e.g. T=768 up to
+    # 1024 (1.78x the attention FLOPs); 384 divides it exactly.
+    tp128 = -(-t // 128) * 128
+    if block_q is None:
+        block_q = pick_block(tp128)
+    if block_k is None:
+        block_k = pick_block(tp128)
+    # Pad to a multiple of BOTH block sizes — the kernels floor-divide the
+    # padded length by each, so a non-divisible combination would silently
+    # skip trailing blocks.
+    block = np.lcm(block_q, block_k)
+    tp = -(-t // block) * block
+
+    def pad(x):
+        return jnp.pad(x, ((0, 0), (0, tp - t), (0, 0))) if tp != t else x
+
+    o3 = _flash_core(pad(q3), pad(k3), pad(v3), t, block_q, block_k,
+                     bool(use_pallas), bool(causal), v_heads)
+    return o3[:, :t] if tp != t else o3
+
+
+def flash_attention_heads_major(q: jax.Array, k: jax.Array, v: jax.Array, *,
+                                causal: bool = False,
+                                block_q: int | None = None,
+                                block_k: int | None = None,
+                                use_pallas: bool = True) -> jax.Array:
+    """Fused attention for a caller that projects per head: q and k
+    heads-major ``[B, H, T, D]``, ``v`` as its Dense writes it, ``[B, T,
+    H*Dv]``, and ``o`` returned as the output Dense reads it, ``[B, T,
+    H*Dv]``.
+
+    The kernels read ``[B*H, T, D]``, which is heads-major q/k with the two
+    leading axes taken as one, and (``Dv`` whole 128-lane tiles) head
+    ``h``'s ``Dv`` lanes of ``v``, ``o``, ``dO`` and ``dv`` through their
+    block specs: no array is transposed between a projection and a kernel,
+    where ``flash_attention``'s ``[B, T, H, D]`` contract costs a pass over
+    each of q, k, v and o, forward and backward. A ``Dv`` that is not whole
+    tiles is split into heads here first. Same kernels, custom VJP, block
+    sizes and padding as ``flash_attention``; no dispatch: the caller
+    (``ops.attention.heads_attention_core``) has already chosen, and
+    ``use_pallas=False`` is the kernel-identical jnp fallback the CPU tests
+    compare with."""
+    b, h, t, d = q.shape
+    dv = v.shape[-1] // h
+    _check_blocks(block_q, block_k)
+    q3, k3 = q.reshape(b * h, t, d), k.reshape(b * h, t, d)
+    if dv % 128:
+        o3 = _flash_heads_first(q3, k3, _split_heads(v, h), block_q, block_k,
+                                use_pallas, causal)
+        return _merge_heads(o3, h).astype(q.dtype)
+    return _flash_heads_first(q3, k3, v, block_q, block_k, use_pallas,
+                              causal, v_heads=h).astype(q.dtype)
+
+
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = False,
                     block_q: int | None = None,
                     block_k: int | None = None,
                     use_pallas: bool | None = None) -> jax.Array:
-    """Fused attention over ``[B, T, H, D]`` q/k/v (causal optional).
+    """Fused attention over ``[B, T, H, D]`` q/k/v (causal optional): the
+    layout a Dense writes. The kernels read heads-major ``[B*H, T, D]``, so
+    this entry transposes q, k and v on the way in and ``o`` on the way
+    out; a caller that can hold ``[B, H, T, D]`` uses
+    ``flash_attention_heads_major`` and moves nothing.
 
     Same contract as parallel/ring_attention.dense_attention — plug into
     models/vit.py:SelfAttention via ``attention_fn=flash_attention`` (or
@@ -562,11 +727,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     math).
     """
     b, t, h, _d = q.shape
-    for name, blk in (("block_q", block_q), ("block_k", block_k)):
-        if blk is not None and (blk <= 0 or blk % 128):
-            raise ValueError(
-                f"{name}={blk} must be a positive multiple of 128 (TPU "
-                f"tile constraint; defaults via pick_block satisfy it)")
+    _check_blocks(block_q, block_k)
     if use_pallas is None:
         if not flash_preferred(t):
             # THE shared dense core (ops/attention.dense_core) — what
@@ -583,25 +744,11 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             from ..attention import dense_core
             return dense_core(q, k, v, causal=causal)
         use_pallas = True
-    # Default blocks: the largest 128-multiple <= MAX_BLOCK that DIVIDES the
-    # 128-rounded sequence length — a bare min() would pad e.g. T=768 up to
-    # 1024 (1.78x the attention FLOPs); 384 divides it exactly.
-    tp128 = -(-t // 128) * 128
-    if block_q is None:
-        block_q = pick_block(tp128)
-    if block_k is None:
-        block_k = pick_block(tp128)
-    # Pad to a multiple of BOTH block sizes — the kernels floor-divide the
-    # padded length by each, so a non-divisible combination would silently
-    # skip trailing blocks.
-    block = np.lcm(block_q, block_k)
-    tp = -(-t // block) * block
 
     def to3(x):
-        x = jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, t, x.shape[-1])
-        return jnp.pad(x, ((0, 0), (0, tp - t), (0, 0))) if tp != t else x
+        return jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, t, x.shape[-1])
 
-    o3 = _flash_core(to3(q), to3(k), to3(v), t, block_q, block_k,
-                     bool(use_pallas), bool(causal))
-    o = o3[:, :t].reshape(b, h, t, v.shape[-1])
+    o3 = _flash_heads_first(to3(q), to3(k), to3(v), block_q, block_k,
+                            use_pallas, causal)
+    o = o3.reshape(b, h, t, v.shape[-1])
     return jnp.transpose(o, (0, 2, 1, 3)).astype(q.dtype)

@@ -266,6 +266,62 @@ def test_select_core_sends_long_bf16_sequences_to_flash(on_tpu, dtype, t,
                           v_head_dim=dv) == want
 
 
+def _heads_major(q, k, v):
+    """``[B, T, H, D]`` x3 as the heads-major entry takes them: q and k
+    ``[B, H, T, D]``, v ``[B, T, H*Dv]``."""
+    b, t, h, dv = v.shape
+    return (q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+            v.reshape(b, t, h * dv))
+
+
+@pytest.mark.parametrize("use_pallas,t,dv", [
+    (False, 200, 32),    # the jnp fallback: bitwise
+    (False, 256, 128),
+    (True, 256, 128),    # the kernels, v/o/dO/dv a head's lanes of [B,T,H*Dv]
+    (True, 256, 32),     # Dv not whole lane tiles: split into heads first
+])
+def test_heads_major_entry_equals_the_bthd_entry(use_pallas, t, dv,
+                                                 monkeypatch):
+    """``flash_attention_heads_major`` (q, k ``[B, H, T, D]``; v, o ``[B,
+    T, H*Dv]``) against ``flash_attention`` on the same numbers as ``[B,
+    T, H, D]``: forward and all three gradients, causal, v narrower than q
+    and k. Both reach the same core, so the fallback is equal bit for bit;
+    through the kernels (interpret mode) the v side is read by other block
+    specs."""
+    import distributed_parameter_server_for_ml_training_tpu.ops.pallas.flash_attention as fa
+
+    monkeypatch.setattr(fa, "INTERPRET", True)
+    b, h, d = 2, 2, 48
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    q, k = (jax.random.normal(x, (b, t, h, d)) for x in ks[:2])
+    v = jax.random.normal(ks[2], (b, t, h, dv))
+    cot = jax.random.normal(ks[3], v.shape)
+
+    def bthd(q, k, v):
+        return flash_attention(q, k, v, causal=True, use_pallas=use_pallas)
+
+    def heads_major(q, k, v):
+        o = fa.flash_attention_heads_major(*_heads_major(q, k, v),
+                                           causal=True,
+                                           use_pallas=use_pallas)
+        assert o.shape == (b, t, h * dv)
+        return o.reshape(b, t, h, dv)
+
+    want, got = (jax.value_and_grad(
+        lambda a, b_, c: jnp.sum(fn(a, b_, c) * cot),
+        argnums=(0, 1, 2))(q, k, v) for fn in (bthd, heads_major))
+    np.testing.assert_array_equal(np.asarray(heads_major(q, k, v)),
+                                  np.asarray(bthd(q, k, v)))
+    for g, w, name in zip(got[1], want[1], "qkv"):
+        if use_pallas:     # dv sums dO blocks fetched in another order
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                       atol=1e-5, rtol=1e-5,
+                                       err_msg=f"d{name}")
+        else:
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                          err_msg=f"d{name}")
+
+
 def test_heads_attention_core_counts_and_computes(monkeypatch):
     from distributed_parameter_server_for_ml_training_tpu.ops import (
         attention as at)
@@ -278,8 +334,9 @@ def test_heads_attention_core_counts_and_computes(monkeypatch):
                                      impl="dense")
     before = counter.value
     out = jax.jit(lambda a, b, c: at.heads_attention_core(
-        a, b, c, causal=True))(q, k, v)
+        a, b, c, causal=True))(*_heads_major(q, k, v))
     assert counter.value == before + 1
-    np.testing.assert_allclose(np.asarray(out),
-                               np.asarray(_dense_f32(q, k, v, True)),
-                               atol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(out),
+        np.asarray(_dense_f32(q, k, v, True)).reshape(1, 40, 2 * 16),
+        atol=1e-5)

@@ -185,6 +185,171 @@ def test_rope_is_interleaved_not_half_split():
                                atol=1e-7)     # position 0 is not rotated
 
 
+@pytest.mark.parametrize("shape", [(2, 9, 4, 8), (2, 9, 1, 64)],
+                         ids=["q_rope[B,T,H,R]", "k_rope[B,T,1,R]"])
+def test_half_split_rotation_is_the_interleaved_one_on_permuted_lanes(shape):
+    """``rope_half_split`` of lanes in ``half_split_lanes`` order is that
+    permutation of ``rope_interleaved``: the same products and angles.
+    The model holds the array with T next to the lanes (heads-major q, the
+    shared k_rope without a head axis)."""
+    x = jnp.asarray(np.random.default_rng(1).normal(size=shape), jnp.float32)
+    lanes = joyai.half_split_lanes(shape[-1])
+    assert sorted(lanes) == list(range(shape[-1]))
+    np.testing.assert_array_equal(lanes[:4], [0, 2, 4, 6])
+    want = joyai.rope_interleaved(x, 32e6)[..., lanes]       # [B, T, H, R]
+    got = joyai.rope_half_split(
+        jnp.swapaxes(x[..., lanes], 1, 2), 32e6)             # [B, H, T, R]
+    np.testing.assert_allclose(np.asarray(jnp.swapaxes(got, 1, 2)),
+                               np.asarray(want), atol=1e-6)
+    assert float(jnp.max(jnp.abs(want - x[..., lanes]))) > 0.1
+
+
+def test_mla_and_its_gradients_match_the_reference(f32_case):
+    """``MLA`` in float32 against ``ref.mla``: the output to 1e-5 and the
+    gradient with respect to every parameter, the four whose weights are
+    cut and reordered inside (``q_b``, ``kv_a``, ``kv_b``, ``o``) to every
+    column, rotary ones included."""
+    cfg, _model, params, _bias, _tokens, _want = f32_case
+    p = params["layer_1"]["attn"]
+    r = np.random.default_rng(4)
+    u = jnp.asarray(r.normal(size=(2, T, 64)), jnp.float32)
+    cot = jnp.asarray(r.normal(size=(2, T, 64)), jnp.float32)
+    mla = joyai.MLA(cfg, jnp.float32)
+
+    def program(p):
+        return jnp.sum(mla.apply({"params": p}, u) * cot)
+
+    def reference(p):
+        return jnp.sum(jnp.stack([ref.mla(p, x, cfg, None) for x in u]) * cot)
+
+    np.testing.assert_allclose(
+        np.asarray(mla.apply({"params": p}, u)),
+        np.asarray(jnp.stack([ref.mla(p, x, cfg, None) for x in u])),
+        atol=1e-5)
+    got, want = jax.grad(program)(p), jax.grad(reference)(p)
+    assert set(got) == {"q_a", "q_a_norm", "q_b", "kv_a", "kv_a_norm",
+                        "kv_b", "o"}
+    for name in got:
+        (g,), (w,) = (jax.tree_util.tree_leaves(t[name])
+                      for t in (got, want))
+        np.testing.assert_allclose(
+            np.asarray(g), np.asarray(w), err_msg=name,
+            atol=1e-5 * float(jnp.max(jnp.abs(w))))
+    rope = cfg.qk_rope_head_dim           # the rotary columns carry signal
+    assert float(jnp.min(jnp.max(jnp.abs(
+        want["kv_a"]["kernel"][:, -rope:]), axis=0))) > 0
+    assert float(jnp.min(jnp.max(jnp.abs(want["q_b"]["kernel"]),
+                                 axis=0))) > 0
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in (value if isinstance(value, (list, tuple))
+                        else (value,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
+
+
+@pytest.mark.parametrize("core", ["flash", "dense"])
+def test_mla_forward_moves_no_activation_between_layouts(core, monkeypatch):
+    """What ``MLA``'s forward holds between its matmuls and its attention
+    core, read off the jaxpr at the tiny preset's widths (v heads of 128 so
+    that the kernels' block specs apply, 1,024 tokens so that
+    ``select_core`` says ``flash`` for bf16 once the backend probe is
+    steered): no reshape of anything to a trailing axis of 2 (the
+    interleaved rotation's lane shuffle), and no transpose but the three
+    per-head projections' own (``einsum("btc,chd->bhtd")`` is a
+    ``dot_general`` and the transpose that names its output heads-major:
+    the compiler writes the matmul's result in that order). q and k as
+    concatenated, v and o are never transposed or cut. On the dense path
+    (``core="dense"``, what the CPU runs) ``dense_core`` is handed v split
+    into heads and hands o back: two more, on the way into and out of its
+    einsums."""
+    from distributed_parameter_server_for_ml_training_tpu.ops import (
+        attention as at)
+    cfg = replace(TINY, v_head_dim=128)
+    dtype = jnp.bfloat16 if core == "flash" else jnp.float32
+    monkeypatch.setattr(at, "_on_tpu", lambda: core == "flash")
+    mla = joyai.MLA(cfg, dtype)
+    u = jnp.zeros((1, 1024, cfg.hidden_size), dtype)
+    params = jax.eval_shape(lambda: mla.init(jax.random.PRNGKey(0), u))
+    eqns = list(_equations(jax.make_jaxpr(
+        lambda p, u: mla.apply(p, u))(params, u).jaxpr))
+    names = [e.primitive.name for e in eqns]
+    assert ("pallas_call" in names) == (core == "flash")
+    by_output = {id(v): e for e in eqns for v in e.outvars}
+    def activation(v):       # T rows; no weight of this size has 1,024
+        return hasattr(v, "aval") and 1024 in v.aval.shape
+
+    for e in eqns:
+        if e.primitive.name == "reshape" and activation(e.invars[0]):
+            assert e.outvars[0].aval.shape[-1] != 2, e
+    transposed = [e for e in eqns if e.primitive.name == "transpose"
+                  and activation(e.invars[0])]
+    from_matmul = [e for e in transposed if by_output.get(
+        id(e.invars[0])) is not None and by_output[
+            id(e.invars[0])].primitive.name == "dot_general"]
+    h, width = cfg.num_attention_heads, cfg.qk_nope_head_dim \
+        + cfg.qk_rope_head_dim
+    from_matmul = [e for e in from_matmul if e.outvars[0].aval.shape in (
+        (1, h, 1024, cfg.qk_nope_head_dim),
+        (1, h, 1024, cfg.qk_rope_head_dim))]
+    assert len(from_matmul) == 3                 # q_nope, q_rope, k_nope
+    rest = [e for e in transposed if e not in from_matmul]
+    if core == "flash":
+        assert not rest, rest
+    else:
+        # v into dense_core's heads-major einsum, the einsum's own output
+        # order, o back
+        assert len(rest) <= 3 and all(
+            e.invars[0].aval.shape[-1] == cfg.v_head_dim for e in rest), rest
+    sliced = [e for e in eqns if e.primitive.name == "slice"
+              and activation(e.invars[0])
+              and e.invars[0].aval.shape[-1] in (
+                  width, cfg.qk_nope_head_dim + cfg.v_head_dim)]
+    assert not sliced, sliced            # weights are cut, not q or kv
+
+
+#: the parameter tree of ``JoyAILM`` at the tiny preset as PR 28 wrote it
+#: (sorted ``path shape dtype`` lines, sha256): a checkpoint of that tree
+#: restores into this model
+TINY_TREE = (67, "72c3edbf679dd0f557ba8727124a52fe3c4ad86adaeb00e9d44d6a1c"
+                 "48018ad5")
+
+
+def test_the_parameter_tree_is_what_checkpoints_hold(f32_case):
+    import hashlib
+    cfg, _model, params, _bias, _tokens, _want = f32_case
+    rows = sorted(f"{jax.tree_util.keystr(path)} {tuple(leaf.shape)} "
+                  f"{leaf.dtype}" for path, leaf in
+                  jax.tree_util.tree_leaves_with_path(params))
+    assert (len(rows), hashlib.sha256(
+        "\n".join(rows).encode()).hexdigest()) == TINY_TREE
+    h, nope, rope, vd = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                         cfg.qk_rope_head_dim, cfg.v_head_dim)
+    attn = {name: tuple(jax.tree_util.tree_leaves(leaf)[0].shape)
+            for name, leaf in params["layer_0"]["attn"].items()}
+    assert attn == {
+        "q_a": (cfg.hidden_size, cfg.q_lora_rank),
+        "q_a_norm": (cfg.q_lora_rank,),
+        "q_b": (cfg.q_lora_rank, h * (nope + rope)),
+        "kv_a": (cfg.hidden_size, cfg.kv_lora_rank + rope),
+        "kv_a_norm": (cfg.kv_lora_rank,),
+        "kv_b": (cfg.kv_lora_rank, h * (nope + vd)),
+        "o": (h * vd, cfg.hidden_size)}
+    # a state dict of that tree (what a checkpoint holds) restores
+    from flax import serialization
+    state = serialization.to_state_dict(
+        jax.tree_util.tree_map(np.asarray, params))
+    restored = serialization.from_state_dict(params, state)
+    assert jax.tree_util.tree_structure(restored) \
+        == jax.tree_util.tree_structure(params)
+
+
 def test_k_rope_is_one_vector_shared_by_the_heads(f32_case):
     """The program against a variant of the reference in which each head
     takes its own slice of a wider ``k_rope``: the shared one matches, and
