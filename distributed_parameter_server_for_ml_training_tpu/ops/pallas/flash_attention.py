@@ -44,9 +44,7 @@ per hop on T/N-sized blocks.
 
 from __future__ import annotations
 
-import json
-import os
-from functools import lru_cache, partial
+from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -60,102 +58,10 @@ MAX_BLOCK = 512
 #: scoped VMEM the kernels may use (``_compiler_params``)
 VMEM_LIMIT_BYTES = 64 << 20
 
-# Fallback when no measured crossover has been recorded (conservative:
-# well above the short-sequence regime where dense decisively wins; the
-# measured file usually records a smaller value — 512 on the round-4
-# chip).
-DEFAULT_CROSSOVER_T = 2048
-_CROSSOVER_FILE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                               "attn_crossover.json")
-
 # Run the Pallas kernels in interpreter mode (CPU emulation of the exact
 # kernel code, loop bounds and SMEM scalars included). Tests flip this to
 # exercise the kernel-side logic without a chip; never set on TPU.
 INTERPRET = False
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-@lru_cache(maxsize=1)
-def _crossover_record() -> dict:
-    try:
-        with open(_CROSSOVER_FILE) as f:
-            return json.load(f)
-    except (OSError, ValueError):
-        return {}
-
-
-def flash_crossover() -> int:
-    """Measured dense->flash crossover sequence length.
-
-    Read from ``attn_crossover.json`` next to this module — REGENERATED (not
-    hand-coded) by ``experiments/measure_mfu.py``, which times dense vs
-    Pallas fwd+bwd across sequence lengths on the attached chip and records
-    the smallest T from which flash sustains >= 0.95x dense (statistical
-    ties break to flash: same wall clock, O(T) memory). Falls back to
-    ``DEFAULT_CROSSOVER_T`` when the file is absent.
-    """
-    try:
-        return int(_crossover_record()["crossover_t"])
-    except (KeyError, ValueError, TypeError):
-        return DEFAULT_CROSSOVER_T
-
-
-# The tie threshold shared by the MEASUREMENT side (experiments/
-# measure_mfu.py derives crossover_t as "sustains >= this x dense") and
-# the DISPATCH side (flash_preferred compares the padding-taxed speedup
-# against it). One constant so the two can't drift.
-FLASH_TIE_THRESHOLD = 0.95
-
-
-def _measured_speedup(tp: int) -> float:
-    """Flash fwd+bwd speedup vs dense at PADDED length ``tp``, piecewise-
-    linearly interpolated over the recorded bench table (clamped to its
-    edge values); 1.0 when no table was recorded (or it is malformed —
-    same conservative fallback class as ``flash_crossover``)."""
-    table = _crossover_record().get("measured_speedups_fwd_bwd") or {}
-    try:
-        pts = sorted((int(k), float(v)) for k, v in table.items())
-    except (ValueError, TypeError):
-        pts = []
-    if not pts:
-        return 1.0
-    if tp <= pts[0][0]:
-        return pts[0][1]
-    if tp >= pts[-1][0]:
-        return pts[-1][1]
-    for (t0, s0), (t1, s1) in zip(pts, pts[1:]):
-        if t0 <= tp <= t1:
-            w = (tp - t0) / (t1 - t0)
-            return s0 + w * (s1 - s0)
-    return 1.0
-
-
-def flash_preferred(t: int) -> bool:
-    """True when the Pallas flash path is expected to BEAT dense attention
-    at sequence length ``t`` on the attached backend.
-
-    This is the dispatch predicate ``flash_attention`` (``use_pallas=None``)
-    and ``train.model_parallel.SPTrainer`` consult, closing the round-3 gap
-    where flash was auto-selected below its measured crossover and LOST to
-    dense (ViT-B/16 @224px, 197 tokens: 28.4% vs 43.8% MFU on the
-    round-3 chip, an earlier installation than the ledger's).
-
-    Non-128-multiple lengths pay a PADDING TAX the crossover table (which
-    is measured at clean multiples) doesn't see: the kernel computes the
-    padded length's FLOPs, so its effective speedup is the table value at
-    the padded length times (t/t_padded)^2. Measured reality check
-    (on-chip): T=576 pads to 640 -> flash 0.89x dense despite
-    576 >= crossover 512. The predicate applies that tax and keeps the
-    same >= 0.95 tie-break threshold.
-    """
-    if not _on_tpu() or t < flash_crossover():
-        return False
-    tp = -(-t // 128) * 128
-    return (_measured_speedup(tp) * (t / tp) ** 2
-            >= FLASH_TIE_THRESHOLD)
 
 
 # -- forward ------------------------------------------------------------------
@@ -711,38 +617,24 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     backward). T is padded to a block multiple internally; default block
     sizes adapt to T (128-tile-rounded, capped at MAX_BLOCK).
 
-    ``use_pallas=None`` (the default) dispatches on the MEASURED
-    dense/flash crossover (``flash_preferred``): below it the dispatch
-    returns the PLAIN dense formulation under native XLA autodiff —
-    THIS kernel's padding in HBM, its transposes and its key-block loop
-    dominate a short sequence, and even the custom-VJP fallback costs
-    ~7% vs letting XLA fuse the backward itself (ViT-B/16 @224: 762 vs
-    822 img/s on the round-4 chip, an earlier installation than the
-    ledger's). The short regime has a kernel of its own,
-    ops/pallas/short_attention.py, which ``ops.attention.attention_core``
-    picks under its ``[B, T, 3*H*D]`` contract; this function's
-    ``[B, T, H, D]`` contract keeps returning ``dense_core``. Explicit
-    True/False overrides force the Pallas kernels / the custom-VJP
-    fallback (the CPU tests exercise the latter's kernel-identical
-    math).
+    ``use_pallas=None`` (the default) asks the one rule,
+    ``ops.attention.select_core``, and where it does not say ``flash`` runs
+    the PLAIN dense formulation under native XLA autodiff, ``dense_core`` —
+    what models/vit.py:SelfAttention runs with no ``attention_fn`` off a TPU,
+    so there ``attention_fn=flash_attention`` is the identical program
+    (asserted bitwise by the CPU tests). ``fused_short`` reads ``dense``
+    here: that kernel needs the packed ``[B, T, 3*H*D]`` activation
+    ``attention_core`` is given. Explicit True/False force the Pallas
+    kernels / the custom-VJP fallback (the CPU tests exercise the latter's
+    kernel-identical math; the ring's per-hop calls force theirs).
     """
-    b, t, h, _d = q.shape
+    b, t, h, d = q.shape
     _check_blocks(block_q, block_k)
     if use_pallas is None:
-        if not flash_preferred(t):
-            # THE shared dense core (ops/attention.dense_core) — what
-            # models/vit.py:SelfAttention runs with no attention_fn
-            # wherever the fused short kernel does not apply, so below
-            # the crossover ``attention_fn=flash_attention`` compiles to
-            # the identical program OFF a TPU (asserted bitwise by the
-            # CPU tests); on a TPU the default model may hold the fused
-            # kernel instead. Upcasting (fp32 logits or fp32 q/k/v)
-            # costs 7-10% of the ViT-B/16 @224 step: the fp32 cotangents
-            # push the backward matmuls off the bf16 MXU rate (740-753
-            # vs 813-823 img/s on the round-4 chip, an earlier
-            # installation than the ledger's).
-            from ..attention import dense_core
-            return dense_core(q, k, v, causal=causal)
+        from .. import attention
+        if attention.core_for_separate_qkv(causal, q.dtype, t, h, d,
+                                           v.shape[-1]) == "dense":
+            return attention.dense_core(q, k, v, causal=causal)
         use_pallas = True
 
     def to3(x):

@@ -167,7 +167,7 @@ def make_ring_flash_attention(mesh: Mesh, axis: str = "seq",
     """
     axis_size = mesh.shape[axis]
     if use_pallas is None:
-        from ..ops.pallas.flash_attention import _on_tpu
+        from ..ops.attention import _on_tpu
         use_pallas = _on_tpu()
     perm = [(i, (i + 1) % axis_size) for i in range(axis_size)]
 

@@ -9,16 +9,15 @@ needed ~17 min/epoch on an M1 CPU (BASELINE.md), a v5e chip does it in ~3 s.
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..data.cifar import Dataset, make_batches
-
-from ..utils.metrics import device_fields, emit_metrics_json
+from ..data.cifar import Dataset
+from ..utils.metrics import device_fields
+from .loop import EpochLoop, phase
 from .optimizers import baseline_optimizer, server_sgd
 from .steps import make_eval_step, make_train_step
 from .train_state import create_train_state
@@ -92,8 +91,12 @@ class TrainingMetrics:
         plt.close(fig)
 
 
-class BaselineTrainer:
-    """The reference's baseline_training.py main loop as a class."""
+class BaselineTrainer(EpochLoop):
+    """The reference's baseline_training.py main loop as a class: the one
+    epoch loop (train/loop.py) with the reference's line, its percentages
+    and its ``TrainingMetrics`` record."""
+
+    mode = "baseline"
 
     def __init__(self, dataset: Dataset, config: BaselineConfig | None = None,
                  model=None):
@@ -115,8 +118,8 @@ class BaselineTrainer:
         self.state = create_train_state(
             self.model, jax.random.PRNGKey(cfg.seed), tx,
             input_shape=(1, h, w, 3))
-        self._train_step = jax.jit(make_train_step(augment=cfg.augment),
-                                   donate_argnums=0)
+        self._step = jax.jit(make_train_step(augment=cfg.augment),
+                             donate_argnums=0)
         self._eval_step = jax.jit(make_eval_step())
         self._device_loop = None
         if cfg.device_loop:
@@ -125,87 +128,63 @@ class BaselineTrainer:
                 dataset, make_train_step(augment=cfg.augment),
                 batch_size=cfg.batch_size)
         self.metrics = TrainingMetrics()
+        self._init_loop()
+        self._train_acc = 0.0      # the newest epoch's, in %
 
-    def train_epoch(self, epoch: int) -> tuple[float, float]:
-        """One epoch (baseline_training.py:149-179). Returns (loss, acc%)."""
-        cfg = self.config
-        rng = jax.random.PRNGKey(cfg.seed + 1)
-        losses, accs = [], []
-        for xb, yb in make_batches(self.dataset.x_train,
-                                   self.dataset.y_train, cfg.batch_size,
-                                   seed=cfg.seed * 997 + epoch):
-            self.state, m = self._train_step(self.state, xb, yb, rng)
-            losses.append(m["loss"])
-            accs.append(m["accuracy"])
-        losses = [float(x) for x in losses]
-        accs = [float(x) for x in accs]
-        return float(np.mean(losses)), 100.0 * float(np.mean(accs))
+    # -- what the one epoch loop (train/loop.py) is handed --------------------
+    def _train_batches(self, seed: int):
+        return super()._train_batches(seed + 1)    # by the 1-based epoch
 
-    def test_epoch(self) -> float:
-        """Full test-set top-1 in % (baseline_training.py:181-199)."""
-        correct = total = 0
-        for xb, yb in make_batches(self.dataset.x_test, self.dataset.y_test,
-                                   1000, shuffle=False,
-                                   drop_remainder=False):
-            c, t = self._eval_step(self.state, xb, yb)
-            correct += int(c)
-            total += int(t)
-        return 100.0 * correct / max(total, 1)
+    def _fetch_step(self, m: dict) -> float:
+        return float(m["accuracy"])
+
+    def _epoch_synced(self, metrics: list, fetched: list) -> None:
+        self._train_acc = 100.0 * float(np.mean(fetched))
+
+    def _run_epoch(self, epoch: int, rng, tel) -> tuple[list, float]:
+        if self._device_loop is None:
+            return super()._run_epoch(epoch, rng, tel)
+        # steps and evaluation as ONE program (train/device_loop.py)
+        with phase("trainer.step", mode=self.mode, epoch=epoch,
+                   step=self.global_steps), tel.goodput.span("compute"):
+            self.state, em = self._device_loop.run_epoch(
+                self.state, jax.random.fold_in(rng, epoch + 1))
+        self.global_steps += self._device_loop.steps_per_epoch
+        self._train_acc = 100.0 * em["train_accuracy"]
+        return [em["train_loss"]], em["test_accuracy"]
+
+    def _epoch_line(self, epoch, loss, acc, seconds) -> str:
+        return (f"epoch {epoch + 1}/{self.config.num_epochs}: "
+                f"loss {loss:.4f} train {self._train_acc:.2f}% "
+                f"test {100.0 * acc:.2f}% ({seconds:.1f}s)")
+
+    def _epoch_visible(self, epoch: int, acc: float) -> None:
+        self.metrics.add_epoch(epoch + 1, self.epoch_losses[-1],
+                               self._train_acc, 100.0 * acc,
+                               self.epoch_times[-1])
+        super()._epoch_visible(epoch, acc)
+
+    def _final_metrics(self, total: float) -> dict:
+        cfg, m = self.config, self.metrics
+        return {
+            "role": "baseline",
+            "num_epochs": cfg.num_epochs,
+            "batch_size": cfg.batch_size,
+            "learning_rate": cfg.learning_rate,
+            "total_training_time_seconds": round(sum(m.epoch_times), 2),
+            "epoch_times_seconds": [round(t, 2) for t in m.epoch_times],
+            "final_test_accuracy": (m.test_accuracies[-1] if m.epochs
+                                    else None),
+            "all_test_accuracies": m.test_accuracies,
+            "final_train_loss": m.train_losses[-1] if m.epochs else None,
+            **device_fields(),
+        }
 
     def train(self, plot_path: str | None = None,
               emit_metrics: bool = False,
               checkpoint_dir: str | None = None,
               resume: bool = False) -> TrainingMetrics:
-        cfg = self.config
-        mgr = None
-        start_epoch = 1
-        if checkpoint_dir:
-            from ..checkpoint import CheckpointManager
-            mgr = CheckpointManager(checkpoint_dir)
-            if resume and mgr.latest_step() is not None:
-                self.state = mgr.restore(self.state)
-                steps_per_epoch = max(
-                    1, len(self.dataset.x_train) // cfg.batch_size)
-                start_epoch = int(self.state.step) // steps_per_epoch + 1
-                print(f"resumed from step {int(self.state.step)} "
-                      f"(epoch {start_epoch})")
-        for epoch in range(start_epoch, cfg.num_epochs + 1):
-            t0 = time.time()
-            if self._device_loop is not None:
-                self.state, em = self._device_loop.run_epoch(
-                    self.state,
-                    jax.random.fold_in(jax.random.PRNGKey(cfg.seed + 1),
-                                       epoch))
-                loss = em["train_loss"]
-                train_acc = 100.0 * em["train_accuracy"]
-                test_acc = 100.0 * em["test_accuracy"]
-            else:
-                loss, train_acc = self.train_epoch(epoch)
-                test_acc = self.test_epoch()
-            dt = time.time() - t0
-            self.metrics.add_epoch(epoch, loss, train_acc, test_acc, dt)
-            print(f"epoch {epoch}/{cfg.num_epochs}: loss {loss:.4f} "
-                  f"train {train_acc:.2f}% test {test_acc:.2f}% "
-                  f"({dt:.1f}s)")
-            if mgr is not None:
-                mgr.save(self.state)
-        if mgr is not None:
-            mgr.close()
+        super().train(emit_metrics, checkpoint_dir, resume)
         if plot_path:
             self.metrics.plot_results(plot_path)
-        if emit_metrics:
-            emit_metrics_json({
-                "role": "baseline",
-                "num_epochs": cfg.num_epochs,
-                "batch_size": cfg.batch_size,
-                "learning_rate": cfg.learning_rate,
-                "total_training_time_seconds": round(
-                    sum(self.metrics.epoch_times), 2),
-                "epoch_times_seconds": [round(t, 2)
-                                        for t in self.metrics.epoch_times],
-                "final_test_accuracy": self.metrics.test_accuracies[-1],
-                "all_test_accuracies": self.metrics.test_accuracies,
-                "final_train_loss": self.metrics.train_losses[-1],
-                **device_fields(),
-            })
         return self.metrics

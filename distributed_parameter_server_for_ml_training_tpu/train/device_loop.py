@@ -31,6 +31,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..data.cifar import Dataset
+from .steps import make_eval_step
 
 
 def prefetch_to_device(batches: Iterable, depth: int = 2,
@@ -101,6 +102,7 @@ class DeviceEpochLoop:
         self._n_test = n_te
 
         steps, bs = self.steps_per_epoch, batch_size
+        eval_step = make_eval_step()
 
         n_total = len(dataset.x_train)
         n_test = self._n_test
@@ -126,13 +128,7 @@ class DeviceEpochLoop:
             state, (losses, accs) = jax.lax.scan(train_body, state, perm)
 
             def eval_body(carry, batch):
-                xb, yb = batch
-                from .steps import _variables
-                from ..data.cifar import normalize
-                logits = state.apply_fn(
-                    _variables(state.params, state.batch_stats),
-                    normalize(xb), train=False)
-                return carry + jnp.sum(jnp.argmax(logits, -1) == yb), None
+                return carry + eval_step(state, *batch)[0], None
 
             correct, _ = jax.lax.scan(
                 eval_body, jnp.zeros((), jnp.int32), (x_te, y_te))

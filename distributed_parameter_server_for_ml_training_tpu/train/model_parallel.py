@@ -24,25 +24,23 @@ PipelineTrainer — GPipe over real ViT block groups:
 
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..data.cifar import (Dataset, augment_batch, make_batches, standardize,
-                          to_float)
+from ..data.cifar import Dataset
 from ..models.vit import EncoderStage, ViTEpilogue, ViTPrologue
 from ..ops.attention import dense_core
 from ..parallel.mesh import make_mesh
 from ..parallel.pipeline import make_pipeline_apply, stack_stage_params
 from ..parallel.tensor import shard_train_state
-from ..train.optimizers import server_sgd
-from ..train.steps import cross_entropy_loss, make_eval_step, make_train_step
-from ..train.train_state import create_train_state
-from ..utils.metrics import device_fields, emit_metrics_json
-from .train_state import TrainState
+from ..utils.metrics import device_fields
+from .loop import EpochLoop
+from .optimizers import server_sgd
+from .steps import make_eval_step, make_train_step
+from .train_state import TrainState, create_train_state
 
 # ViT shapes by registry name, CIFAR-resolution patch sizes.
 VIT_SHAPES = {
@@ -75,125 +73,49 @@ class ModelParallelConfig:
     seed: int = 0
 
 
-class _EpochTrainer:
-    """Shared epoch loop for the model-parallel trainers: batching, eval,
-    per-epoch Orbax checkpointing / --resume, METRICS_JSON fields. Subclasses
-    set ``mode``, implement ``_train_batch`` / ``evaluate`` /
-    ``_extra_metrics``, and may override ``_after_restore`` to re-place
-    restored params on the mesh."""
-
-    mode = "?"
+class _EpochTrainer(EpochLoop):
+    """What the model-parallel trainers hand the one epoch loop
+    (train/loop.py) alike: their line and their METRICS_JSON row.
+    Subclasses set ``mode`` and ``state``, give ``_label`` and
+    ``_extra_metrics``, and may override ``_shard``, ``_eval_batching`` and
+    ``_after_restore`` (to re-place restored params on the mesh)."""
 
     def __init__(self, dataset: Dataset, config: ModelParallelConfig):
-        self.config = config
+        self.config = cfg = config
         self.dataset = dataset
-        self.epoch_times: list[float] = []
-        self.test_accuracies: list[float] = []
-        self.global_steps = 0
+        if cfg.model not in VIT_SHAPES:
+            raise ValueError(
+                f"--mode {self.mode} supports transformer models "
+                f"{tuple(VIT_SHAPES)}; BatchNorm models need the shard_map "
+                f"sync path (--mode sync)")
+        self.shape = VIT_SHAPES[cfg.model]
+        self.dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
+        self._step = jax.jit(make_train_step(augment=cfg.augment),
+                             donate_argnums=0)
+        self._eval_step = jax.jit(make_eval_step())
+        self._init_loop()
 
-    def _train_batch(self, xb, yb, rng):
-        raise NotImplementedError
+    def _shard(self, batch):
+        return jax.device_put(batch, self._batch_sharding)
 
-    def evaluate(self) -> float:
-        raise NotImplementedError
+    def _epoch_line(self, epoch, loss, acc, seconds) -> str:
+        return (f"[{self._label()}] epoch {epoch + 1}: loss {loss:.4f} "
+                f"test {acc:.2%} ({seconds:.1f}s)")
 
-    def _extra_metrics(self) -> dict:
-        return {}
-
-    def _label(self) -> str:
-        return self.mode
-
-    def _after_restore(self) -> None:
-        """Re-place restored (host) params on the mesh."""
-
-    def _make_steps(self, forward):
-        """Build the jitted (train_step, eval_step) pair around a pure
-        ``forward(params, images_std) -> logits``: shared uint8->augment->
-        standardize preprocessing, CE loss, grad + SGD apply."""
-        augment = self.config.augment
-
-        def train_step(state, images_u8, labels, rng_key):
-            rng_key = jax.random.fold_in(rng_key, state.step)
-            # uint8-domain augment: same floats, 1/4 the gather bandwidth
-            # (train/steps.py).
-            images = images_u8
-            if augment:
-                images = augment_batch(rng_key, images)
-            images = standardize(to_float(images))
-
-            def loss_fn(p):
-                logits = forward(p, images)
-                return cross_entropy_loss(logits, labels), logits
-
-            (loss, logits), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(state.params)
-            state = state.apply_gradients(grads=grads)
-            acc = jnp.mean(jnp.argmax(logits, -1) == labels)
-            return state, {"loss": loss, "accuracy": acc}
-
-        def eval_step(params, images_u8, labels):
-            logits = forward(params, standardize(to_float(images_u8)))
-            return (jnp.sum(jnp.argmax(logits, -1) == labels),
-                    labels.shape[0])
-
-        return (jax.jit(train_step, donate_argnums=0), jax.jit(eval_step))
-
-    def train(self, emit_metrics: bool = False,
-              checkpoint_dir: str | None = None,
-              resume: bool = False) -> dict:
-        cfg = self.config
-        steps_per_epoch = max(1, len(self.dataset.x_train) // cfg.batch_size)
-        mgr = None
-        start_epoch = 0
-        if checkpoint_dir:
-            from ..checkpoint import CheckpointManager
-            mgr = CheckpointManager(checkpoint_dir)
-            if resume and mgr.latest_step() is not None:
-                self.state = mgr.restore(self.state)
-                self._after_restore()
-                self.global_steps = int(self.state.step)
-                start_epoch = self.global_steps // steps_per_epoch
-                print(f"resumed from step {self.global_steps} "
-                      f"(epoch {start_epoch + 1})")
-
-        rng = jax.random.PRNGKey(cfg.seed + 1)
-        t_start = time.time()
-        for epoch in range(start_epoch, cfg.num_epochs):
-            t0 = time.time()
-            losses = []
-            for xb, yb in make_batches(self.dataset.x_train,
-                                       self.dataset.y_train, cfg.batch_size,
-                                       seed=cfg.seed * 997 + epoch):
-                self.state, m = self._train_batch(xb, yb, rng)
-                losses.append(m["loss"])
-                self.global_steps += 1
-            acc = self.evaluate()
-            self.epoch_times.append(time.time() - t0)
-            self.test_accuracies.append(acc)
-            print(f"[{self._label()}] epoch {epoch + 1}: "
-                  f"loss {float(np.mean([float(l) for l in losses])):.4f} "
-                  f"test {acc:.2%} ({self.epoch_times[-1]:.1f}s)")
-            if mgr is not None:
-                mgr.save(self.state)
-        total = time.time() - t_start
-        if mgr is not None:
-            mgr.close()
-        metrics = {
+    def _final_metrics(self, total: float) -> dict:
+        return {
             "mode": self.mode,
-            "total_workers": cfg.num_workers,
+            "total_workers": self.config.num_workers,
             "total_training_time_seconds": round(total, 2),
             "global_steps_completed": self.global_steps,
             "total_parameter_updates": self.global_steps,
-            "learning_rate": cfg.learning_rate,
+            "learning_rate": self.config.learning_rate,
             "final_test_accuracy": (self.test_accuracies[-1]
                                     if self.test_accuracies else 0.0),
             "all_test_accuracies": self.test_accuracies,
             **self._extra_metrics(),
             **device_fields(),
         }
-        if emit_metrics:
-            emit_metrics_json(metrics)
-        return metrics
 
 
 class TPTrainer(_EpochTrainer):
@@ -206,10 +128,6 @@ class TPTrainer(_EpochTrainer):
 
         super().__init__(dataset, config or ModelParallelConfig())
         cfg = self.config
-        if cfg.model not in VIT_SHAPES:
-            raise ValueError(
-                f"--mode tp supports transformer models {tuple(VIT_SHAPES)}; "
-                f"BatchNorm models need the shard_map sync path (--mode sync)")
         dp, tp = cfg.num_workers, cfg.tp_degree
         devs = jax.devices()
         if dp * tp > len(devs):
@@ -218,13 +136,12 @@ class TPTrainer(_EpochTrainer):
                               devices=devs[:dp * tp])
 
         from ..models import get_model
-        dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
         h, w = dataset.x_train.shape[1:3]
         # GSPMD partitions the einsums of dense_core along 'model' (heads
         # follow the qkv split); a kernel call it cannot partition, so the
         # fused core attention_core would pick on a TPU is not offered here.
         self.model = get_model(cfg.model, num_classes=cfg.num_classes,
-                               dtype=dtype, image_size=h
+                               dtype=self.dtype, image_size=h
                                ).clone(attention_fn=dense_core)
         state = create_train_state(self.model, jax.random.PRNGKey(cfg.seed),
                                    server_sgd(cfg.learning_rate),
@@ -232,9 +149,6 @@ class TPTrainer(_EpochTrainer):
         # Megatron placement: qkv/fc1 column-split, out/fc2 row-split over
         # 'model'; everything else replicated (parallel/tensor.py rules).
         self.state = shard_train_state(state, self.mesh)
-        self._step = jax.jit(make_train_step(augment=cfg.augment),
-                             donate_argnums=0)
-        self._eval_step = jax.jit(make_eval_step())
         self._batch_sharding = NamedSharding(self.mesh, P("data"))
 
     def _label(self) -> str:
@@ -245,21 +159,6 @@ class TPTrainer(_EpochTrainer):
 
     def _after_restore(self) -> None:
         self.state = shard_train_state(self.state, self.mesh)
-
-    def _train_batch(self, xb, yb, rng):
-        return self._step(self.state,
-                          jax.device_put(xb, self._batch_sharding),
-                          jax.device_put(yb, self._batch_sharding), rng)
-
-    def evaluate(self) -> float:
-        correct = total = 0
-        for xb, yb in make_batches(self.dataset.x_test, self.dataset.y_test,
-                                   1000, shuffle=False,
-                                   drop_remainder=False):
-            c, t = self._eval_step(self.state, xb, yb)
-            correct += int(c)
-            total += int(t)
-        return correct / max(total, 1)
 
 
 class PipelineTrainer(_EpochTrainer):
@@ -277,11 +176,7 @@ class PipelineTrainer(_EpochTrainer):
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
         super().__init__(dataset, config or ModelParallelConfig())
-        cfg = self.config
-        shape = VIT_SHAPES.get(cfg.model)
-        if shape is None:
-            raise ValueError(
-                f"--mode pp supports ViT models {tuple(VIT_SHAPES)}")
+        cfg, shape, dtype = self.config, self.shape, self.dtype
         n_stages = cfg.num_workers
         dp, tp = cfg.dp_degree, cfg.pp_tp_degree
         if shape["depth"] % n_stages:
@@ -306,7 +201,6 @@ class PipelineTrainer(_EpochTrainer):
             np.array(devs[:dp * tp * n_stages]).reshape(dp, tp, n_stages),
             ("data", "model", "stage"))
 
-        dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
         h, w = dataset.x_train.shape[1:3]
         self.prologue = ViTPrologue(patch_size=shape["patch_size"],
                                     hidden_dim=shape["hidden_dim"],
@@ -337,10 +231,6 @@ class PipelineTrainer(_EpochTrainer):
         self._batch_sharding = NamedSharding(self.mesh, P("data"))
         params = self._place_params(params)
 
-        self.state = TrainState.create(
-            apply_fn=None, params=params, batch_stats={},
-            tx=server_sgd(cfg.learning_rate))
-
         pipe_apply = make_pipeline_apply(
             self.mesh,
             lambda p, x: self.stage.apply({"params": p}, x),
@@ -348,12 +238,18 @@ class PipelineTrainer(_EpochTrainer):
             data_axis="data")
         prologue, epilogue = self.prologue, self.epilogue
 
-        def forward(params, images):
+        def apply_fn(variables, images, train=False, mutable=False):
+            """The three parts as one model, with the call the shared
+            steps make of any (train/steps.py); no state but parameters."""
+            params = variables["params"]
             tokens = prologue.apply({"params": params["prologue"]}, images)
             tokens = pipe_apply(params["stages"], tokens)
-            return epilogue.apply({"params": params["epilogue"]}, tokens)
+            logits = epilogue.apply({"params": params["epilogue"]}, tokens)
+            return (logits, {}) if mutable else logits
 
-        self._step, self._eval_step = self._make_steps(forward)
+        self.state = TrainState.create(
+            apply_fn=apply_fn, params=params, batch_stats={},
+            tx=server_sgd(cfg.learning_rate))
 
     def _place_params(self, params: dict) -> dict:
         """Stage params one-per-slot on 'stage' — composed with the Megatron
@@ -395,26 +291,14 @@ class PipelineTrainer(_EpochTrainer):
         self.state = self.state.replace(
             params=self._place_params(self.state.params))
 
-    def _train_batch(self, xb, yb, rng):
-        return self._step(self.state,
-                          jax.device_put(xb, self._batch_sharding),
-                          jax.device_put(yb, self._batch_sharding), rng)
-
-    def evaluate(self) -> float:
-        cfg = self.config
-        correct = total = 0
+    def _eval_batching(self) -> tuple[int, bool]:
         # Eval batch must divide into the microbatch count, each microbatch
         # must divide across the 'data' axis, and it must fit the test set
         # (init validated test set >= one microbatch group).
+        cfg = self.config
         m = cfg.pp_microbatches * max(1, cfg.dp_degree)
         bs = min((1000 // m) * m, (len(self.dataset.x_test) // m) * m)
-        bs = max(bs, m)
-        for xb, yb in make_batches(self.dataset.x_test, self.dataset.y_test,
-                                   bs, shuffle=False, drop_remainder=True):
-            c, t = self._eval_step(self.state.params, xb, yb)
-            correct += int(c)
-            total += int(t)
-        return correct / max(total, 1)
+        return max(bs, m), True
 
 
 # ---------------------------------------------------------------------------
@@ -443,11 +327,7 @@ class SPTrainer(_EpochTrainer):
         from ..parallel.ring_attention import make_ring_attention
 
         super().__init__(dataset, config or ModelParallelConfig())
-        cfg = self.config
-        shape = VIT_SHAPES.get(cfg.model)
-        if shape is None:
-            raise ValueError(
-                f"--mode sp supports ViT models {tuple(VIT_SHAPES)}")
+        cfg, shape, dtype = self.config, self.shape, self.dtype
         devs = jax.devices()
         n_shards = cfg.num_workers
         if n_shards > len(devs):
@@ -461,22 +341,20 @@ class SPTrainer(_EpochTrainer):
         self.mesh = make_mesh(n_shards, axis_names=("seq",),
                               devices=devs[:n_shards])
         # Long-context configs run the fused ring x flash composition —
-        # flash kernels per hop, ppermute between — but ONLY when the
-        # per-hop block length clears BOTH the Pallas tile constraint
-        # (128-multiple, pick_block) and the MEASURED dense/flash
-        # crossover (flash_preferred): round 3 showed flash LOSING to
-        # the XLA-fused dense core below it (ViT-B/16 @224, 197 tokens:
-        # 28.4% vs 43.8% MFU), so divisibility alone is not a reason to
-        # select the fused kernel.
-        per_shard = self.tokens // n_shards
-        from ..ops.pallas.flash_attention import flash_preferred
-        if per_shard % 128 == 0 and flash_preferred(per_shard):
+        # flash kernels per hop, ppermute between — where the one rule
+        # (ops/attention.py:select_core) gives a hop's block length to the
+        # flash kernels; every other shard length runs the dense ring.
+        from ..ops.attention import _on_tpu, select_core
+        if select_core(on_tpu=_on_tpu(), causal=False, dtype=dtype,
+                       t=self.tokens // n_shards,
+                       num_heads=shape["num_heads"],
+                       head_dim=shape["hidden_dim"] // shape["num_heads"]
+                       ) == "flash":
             from ..parallel.ring_attention import make_ring_flash_attention
             ring = make_ring_flash_attention(self.mesh, axis="seq")
         else:
             ring = make_ring_attention(self.mesh, axis="seq", causal=False)
 
-        dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
         self.model = ViT(patch_size=patch, hidden_dim=shape["hidden_dim"],
                          depth=shape["depth"], num_heads=shape["num_heads"],
                          num_classes=cfg.num_classes, dtype=dtype,
@@ -487,9 +365,6 @@ class SPTrainer(_EpochTrainer):
         # Weights replicate; only activations shard (along T, inside the
         # ring shard_map).
         self.state = jax.device_put(state, NamedSharding(self.mesh, P()))
-        self._step = jax.jit(make_train_step(augment=cfg.augment),
-                             donate_argnums=0)
-        self._eval_step = jax.jit(make_eval_step())
 
     def _label(self) -> str:
         return (f"sp {self.config.model} {self.config.num_workers} "
@@ -499,18 +374,8 @@ class SPTrainer(_EpochTrainer):
         return {"seq_shards": self.config.num_workers,
                 "tokens": self.tokens}
 
-    def _train_batch(self, xb, yb, rng):
-        return self._step(self.state, xb, yb, rng)
-
-    def evaluate(self) -> float:
-        correct = total = 0
-        for xb, yb in make_batches(self.dataset.x_test, self.dataset.y_test,
-                                   1000, shuffle=False,
-                                   drop_remainder=False):
-            c, t = self._eval_step(self.state, xb, yb)
-            correct += int(c)
-            total += int(t)
-        return correct / max(total, 1)
+    def _shard(self, batch):
+        return batch    # weights replicate; the ring shards activations
 
 
 # ---------------------------------------------------------------------------
@@ -535,11 +400,7 @@ class MoETrainer(_EpochTrainer):
         from ..parallel.moe import make_moe_ffn
 
         super().__init__(dataset, config or ModelParallelConfig())
-        cfg = self.config
-        shape = VIT_SHAPES.get(cfg.model)
-        if shape is None:
-            raise ValueError(
-                f"--mode moe supports ViT models {tuple(VIT_SHAPES)}")
+        cfg, shape, dtype = self.config, self.shape, self.dtype
         devs = jax.devices()
         n_exp = cfg.num_workers
         dp = max(1, cfg.dp_degree)
@@ -580,7 +441,6 @@ class MoETrainer(_EpochTrainer):
         self.capacity = max(
             8, int(cfg.moe_capacity_factor * tokens_per_shard / n_exp))
 
-        dtype = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
         self.model = ViT(patch_size=patch, hidden_dim=d,
                          depth=shape["depth"], num_heads=shape["num_heads"],
                          num_classes=cfg.num_classes, dtype=dtype,
@@ -600,7 +460,6 @@ class MoETrainer(_EpochTrainer):
             make_train_step(augment=cfg.augment,
                             moe_aux_weight=cfg.moe_aux_weight),
             donate_argnums=0)
-        self._eval_step = jax.jit(make_eval_step())
         self._batch_sharding = NamedSharding(self.mesh, P(self._batch_spec))
         self._moe_step_metrics: list[dict] = []
 
@@ -652,25 +511,15 @@ class MoETrainer(_EpochTrainer):
         self.state = self.state.replace(
             params=self._place_params(self.state.params))
 
-    def _train_batch(self, xb, yb, rng):
-        state, m = self._step(self.state,
-                              jax.device_put(xb, self._batch_sharding),
-                              jax.device_put(yb, self._batch_sharding), rng)
+    def _train_step(self, placed, rng) -> dict:
+        m = super()._train_step(placed, rng)
         self._moe_step_metrics.append(
             {k: m[k] for k in ("moe_aux_loss", "moe_load_imbalance",
                                "moe_drop_frac") if k in m})
-        return state, m
+        return m
 
-    def evaluate(self) -> float:
-        cfg = self.config
-        correct = total = 0
+    def _eval_batching(self) -> tuple[int, bool]:
         # Eval at the TRAINING batch size: expert capacity was sized for
         # that token load — a bigger eval batch would silently drop the
         # overflow tokens and understate accuracy.
-        bs = cfg.batch_size
-        for xb, yb in make_batches(self.dataset.x_test, self.dataset.y_test,
-                                   bs, shuffle=False, drop_remainder=True):
-            c, t = self._eval_step(self.state, xb, yb)
-            correct += int(c)
-            total += int(t)
-        return correct / max(total, 1)
+        return self.config.batch_size, True

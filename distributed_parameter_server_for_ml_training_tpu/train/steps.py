@@ -34,13 +34,47 @@ def _variables(params, batch_stats):
     return v
 
 
-def _forward_loss(state: TrainState, params, images, labels):
-    outputs, mutated = state.apply_fn(
-        _variables(params, state.batch_stats),
-        images, train=True, mutable=["batch_stats"],
-    )
-    loss = cross_entropy_loss(outputs, labels)
-    return loss, (outputs, mutated.get("batch_stats", {}))
+def preprocess(images_u8: jax.Array, rng: jax.Array,
+               augment: bool) -> jax.Array:
+    """Raw uint8 pixels to what a model reads, in torchvision's order
+    (worker.py:145-154): RandomCrop/Flip on raw pixels (zero pad = black)
+    -> ToTensor -> Normalize. The crop/flip gathers run on the uint8
+    pixels — bit-identical floats to casting first (pure index
+    permutations, zero pad in either domain) at 1/4 the gather bandwidth;
+    the two batched gathers once cost ~45% of the ResNet-18 step."""
+    with jax.named_scope("augment"):
+        images = images_u8
+        if augment:
+            images = augment_batch(rng, images)
+        return standardize(to_float(images))
+
+
+def image_forward_backward(apply_fn: Callable, params, batch_stats,
+                           images_u8: jax.Array, labels: jax.Array,
+                           rng: jax.Array, augment: bool):
+    """THE image forward and backward pass, under every trainer: augment ->
+    standardize -> apply (train=True, batch statistics mutable) ->
+    cross-entropy -> ``value_and_grad``. Returns ``(loss, grads,
+    new_batch_stats, logits)``.
+
+    The named scopes (``augment``, ``forward_backward``; ``exchange`` and
+    ``update`` are the callers') tag each instruction's metadata with the
+    phase it belongs to, for a profile's readers; they cost nothing at run
+    time."""
+    images = preprocess(images_u8, rng, augment)
+
+    def loss_fn(p):
+        outputs, mutated = apply_fn(
+            _variables(p, batch_stats),
+            images, train=True, mutable=["batch_stats"],
+        )
+        loss = cross_entropy_loss(outputs, labels)
+        return loss, (outputs, mutated.get("batch_stats", {}))
+
+    with jax.named_scope("forward_backward"):
+        (loss, (logits, new_stats)), grads = jax.value_and_grad(
+            loss_fn, has_aux=True)(params)
+    return loss, grads, new_stats, logits
 
 
 def collect_moe_stats(intermediates: dict) -> list[dict]:
@@ -78,19 +112,10 @@ def make_train_step(augment: bool = True,
     def train_step(state: TrainState, images_u8: jax.Array,
                    labels: jax.Array, rng: jax.Array):
         rng = jax.random.fold_in(rng, state.step)
-        # torchvision order (worker.py:145-154): RandomCrop/Flip on raw
-        # pixels (zero pad = black) -> ToTensor -> Normalize. The crop/flip
-        # gathers run on the uint8 pixels — bit-identical floats to casting
-        # first (pure index permutations, zero pad in either domain) at 1/4
-        # the gather bandwidth; the two batched gathers once cost ~45% of
-        # the ResNet-18 step.
-        with jax.named_scope("augment"):
-            images = images_u8
-            if augment:
-                images = augment_batch(rng, images)
-            images = standardize(to_float(images))
-
+        moe = None
         if moe_aux_weight is not None:
+            images = preprocess(images_u8, rng, augment)
+
             def loss_fn(p):
                 outputs, mutated = state.apply_fn(
                     _variables(p, state.batch_stats), images, train=True,
@@ -110,12 +135,9 @@ def make_train_step(augment: bool = True,
                 (loss, (logits, new_stats, moe)), grads = grad_fn(
                     state.params)
         else:
-            grad_fn = jax.value_and_grad(
-                lambda p: _forward_loss(state, p, images, labels),
-                has_aux=True)
-            with jax.named_scope("forward_backward"):
-                (loss, (logits, new_stats)), grads = grad_fn(state.params)
-            moe = None
+            loss, grads, new_stats, logits = image_forward_backward(
+                state.apply_fn, state.params, state.batch_stats,
+                images_u8, labels, rng, augment)
 
         # "exchange", the third scope of parallel/sync_dp.py's step, has
         # nothing to hold on one chip
@@ -154,24 +176,9 @@ def make_grad_step(model, augment: bool = True) -> Callable:
 
     @jax.jit
     def grad_step(params, batch_stats, images_u8, labels, rng, step):
-        rng = jax.random.fold_in(rng, step)
-        # Augment on the raw uint8 pixels (see make_train_step): same
-        # floats, 1/4 the gather bandwidth.
-        images = images_u8
-        if augment:
-            images = augment_batch(rng, images)
-        images = standardize(to_float(images))
-
-        def loss_fn(p):
-            outputs, mutated = model.apply(
-                _variables(p, batch_stats),
-                images, train=True, mutable=["batch_stats"],
-            )
-            loss = cross_entropy_loss(outputs, labels)
-            return loss, (outputs, mutated.get("batch_stats", {}))
-
-        (loss, (logits, new_stats)), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params)
+        loss, grads, new_stats, logits = image_forward_backward(
+            model.apply, params, batch_stats, images_u8, labels,
+            jax.random.fold_in(rng, step), augment)
         accuracy = jnp.mean(jnp.argmax(logits, -1) == labels)
         return grads, new_stats, loss, accuracy
 
@@ -202,22 +209,9 @@ def make_fused_local_step(model, augment: bool = True) -> Callable:
     @partial(jax.jit, donate_argnums=(0, 1, 2))
     def fused_step(params, accum, batch_stats, images_u8, labels, rng,
                    step, lr):
-        rng = jax.random.fold_in(rng, step)
-        images = images_u8
-        if augment:
-            images = augment_batch(rng, images)
-        images = standardize(to_float(images))
-
-        def loss_fn(p):
-            outputs, mutated = model.apply(
-                _variables(p, batch_stats),
-                images, train=True, mutable=["batch_stats"],
-            )
-            loss = cross_entropy_loss(outputs, labels)
-            return loss, (outputs, mutated.get("batch_stats", {}))
-
-        (loss, (logits, new_stats)), grads = jax.value_and_grad(
-            loss_fn, has_aux=True)(params)
+        loss, grads, new_stats, logits = image_forward_backward(
+            model.apply, params, batch_stats, images_u8, labels,
+            jax.random.fold_in(rng, step), augment)
         new_params = jax.tree_util.tree_map(
             lambda p, g: p - lr * g, params, grads)
         new_accum = jax.tree_util.tree_map(

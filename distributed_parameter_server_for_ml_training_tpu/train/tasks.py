@@ -28,9 +28,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..data.cifar import augment_batch, make_batches, standardize, to_float
+from ..data.cifar import make_batches
 from .optimizers import adamw, server_sgd
-from .steps import _variables, cross_entropy_loss, make_eval_step
+from .steps import image_forward_backward, make_eval_step
 from .train_state import TrainState, create_train_state
 
 #: images one evaluation batch of the sync trainer holds
@@ -89,30 +89,9 @@ class ImageTask:
         (where the accuracy has always been computed); ``extra`` the
         task's own replicated metrics (``extra_metrics``)."""
         images_u8, labels = batch
-        # torchvision order (worker.py:145-154): crop/flip raw pixels
-        # (zero pad = black), then per-channel standardize. Gathers run
-        # on uint8 — bit-identical floats at 1/4 the bandwidth
-        # (train/steps.py).
-        # The four named scopes (also in train/steps.py:make_train_step)
-        # tag each instruction's metadata with the phase it belongs to,
-        # for a profile's readers; they cost nothing at run time.
-        with jax.named_scope("augment"):
-            images = images_u8
-            if self.augment:
-                images = augment_batch(rng, images)
-            images = standardize(to_float(images))
-
-        def loss_fn(params):
-            outputs, mutated = state.apply_fn(
-                _variables(params, state.batch_stats),
-                images, train=True, mutable=["batch_stats"],
-            )
-            loss = cross_entropy_loss(outputs, labels)
-            return loss, (outputs, mutated.get("batch_stats", {}))
-
-        with jax.named_scope("forward_backward"):
-            (loss, (logits, new_stats)), grads = jax.value_and_grad(
-                loss_fn, has_aux=True)(state.params)
+        loss, grads, new_stats, logits = image_forward_backward(
+            state.apply_fn, state.params, state.batch_stats, images_u8,
+            labels, rng, self.augment)
         return loss, grads, new_stats, (logits, labels), {}
 
     def accuracy(self, judged) -> jax.Array:

@@ -159,7 +159,7 @@ def main() -> int:
                            ViT(**vit_b16, attention_fn=flash_attention),
                            1024, 4, 4, args.trials, flops_rec=dense_lc)
         flash_lc["flops_from"] = "vit_b16_1024px_dense"
-        flash_lc["note"] = ("T=4097 >> measured crossover: the dispatch "
+        flash_lc["note"] = ("T=4097 >= FLASH_MIN_T: select_core "
                             "selects the Pallas kernel; same model, same "
                             "batch, same data as the dense row")
         flash_lc["end_to_end_speedup_vs_dense"] = round(
@@ -180,9 +180,9 @@ def main() -> int:
         # Long-sequence ViT-B/16 (224px -> 197 tokens): dense einsum
         # attention vs the Pallas flash kernel, same model otherwise.
         # "flash_auto" is what a user selecting flash_attention actually
-        # gets — the measured-crossover dispatch (dense below, Pallas
-        # above); "flash_forced" pins the Pallas path to document WHY
-        # dispatch picks dense at 197 tokens.
+        # gets — ops/attention.py:select_core's rule (dense under 1,024
+        # tokens, Pallas from there); "flash_forced" pins the Pallas path
+        # to document WHY the rule picks dense at 197 tokens.
         measure("vit_b16_224px_dense", ViT(**vit_b16), 224, 64, 10,
                 args.trials),
         measure("vit_b16_224px_flash_auto",
@@ -207,8 +207,8 @@ def main() -> int:
                          s2d_stem=True),
                 224, 512, 10, args.trials, num_classes=1000),
     ]
-    # The dense and flash_auto rows must be the SAME program below the
-    # crossover (the dispatch routes through the shared dense core);
+    # The dense and flash_auto rows must be the SAME program under
+    # FLASH_MIN_T (the dispatch routes through the shared dense core);
     # verify at the artifact level so the recorded img/s delta between
     # them is provably run-to-run variance, not a real regression.
     if not args.attn_only:
@@ -235,7 +235,7 @@ def main() -> int:
                     r["hlo_identical_to"] = "vit_b16_224px_dense"
                     r["note"] = (
                         "lowered StableHLO is byte-identical to the dense "
-                        "row (crossover dispatch routes through the shared "
+                        "row (select_core routes through the shared "
                         "dense core at 197 tokens); the img/s delta between "
                         "the two rows is run-to-run variance")
         print(f"dense-vs-auto HLO identical: "
@@ -244,7 +244,7 @@ def main() -> int:
     # Attention-core microbench: dense einsum vs the Pallas flash kernel,
     # fwd+bwd, across sequence lengths — the regime the fused kernel is
     # FOR (at CIFAR/224px token counts the whole attention is a rounding
-    # error and XLA's fused dense path wins; the crossover matters for the
+    # error and XLA's fused dense path wins; the kernels matter for the
     # long-context/SP configs).
     import time as _time
 
@@ -254,16 +254,14 @@ def main() -> int:
     # The dense arm must be the core the dispatch ACTUALLY falls back to
     # (input-dtype logits) — benchmarking against the fp32-upcast test
     # reference (parallel/ring_attention.dense_attention) overstated the
-    # flash speedups by the 7-10% upcast tax and biased the crossover.
+    # flash speedups by the 7-10% upcast tax.
     from distributed_parameter_server_for_ml_training_tpu.ops.attention import (
         dense_core)
-    from distributed_parameter_server_for_ml_training_tpu.ops.pallas.flash_attention import (
-        FLASH_TIE_THRESHOLD)
 
     # Host dispatch would swamp a single attention call, so each timing
     # chains REPS dependent iterations inside one lax.scan dispatch and
     # divides. MEDIAN of ATTN_TRIALS (not best-of-3): a single fast or slow
-    # outlier must not decide the computed crossover.
+    # outlier must not decide a row.
     REPS = 20
     ATTN_TRIALS = max(5, args.trials)
     attn_rows = []
@@ -314,46 +312,6 @@ def main() -> int:
     with open(out, "w") as f:
         json.dump({"train_step_mfu": rows,
                    "attention_core_bench": attn_rows}, f, indent=2)
-
-    # Encode the measured crossover where flash_attention's auto dispatch
-    # reads it (ops/pallas/attn_crossover.json): the smallest tabulated T
-    # from which flash fwd+bwd SUSTAINS >= 0.95x dense. The 0.95 margin
-    # treats statistical ties as flash wins — at a wall-clock tie the
-    # fused kernel is strictly better on memory (no [T, T] score
-    # materialization), and timing noise otherwise flips the boundary
-    # point between runs.
-    xover = None
-    for i, r in enumerate(attn_rows):
-        if all(rr["flash_fwd_bwd_speedup"] >= FLASH_TIE_THRESHOLD
-               for rr in attn_rows[i:]):
-            xover = r["seq_len"]
-            break
-    if xover is None:
-        # Flash never sustained a win: dispatch must NEVER auto-select it
-        # (not even beyond the tabulated range — extrapolating a win from
-        # an all-loss table would recreate the round-3 regression).
-        xover = 2 ** 31
-    from distributed_parameter_server_for_ml_training_tpu.ops.pallas import (
-        flash_attention as fa_mod)
-    try:
-        with open(fa_mod._CROSSOVER_FILE, "w") as f:
-            json.dump({
-                "crossover_t": xover,
-                "source": "experiments/measure_mfu.py attention_core_bench "
-                          "(regenerated by every measure_mfu.py run)",
-                "rule": "smallest tabulated T from which flash fwd+bwd "
-                        "sustains >= 0.95x dense (ties break to flash: "
-                        "O(T) memory); 2**31 = never wins",
-                "measured_speedups_fwd_bwd": {
-                    str(r["seq_len"]): r["flash_fwd_bwd_speedup"]
-                    for r in attn_rows},
-            }, f, indent=2)
-            f.write("\n")
-        print(f"crossover_t = {xover} -> {fa_mod._CROSSOVER_FILE}",
-              flush=True)
-    except OSError as e:    # read-only install: keep the results, warn
-        print(f"WARNING: could not write {fa_mod._CROSSOVER_FILE}: {e}",
-              file=sys.stderr, flush=True)
 
     print("\n| model / shape | batch | images/s/chip | ms/step | TF/s | MFU |")
     print("|---|---|---|---|---|---|")

@@ -107,7 +107,7 @@ def run_dense(epochs: int, ds) -> dict:
     t0 = time.time()
     accs, steps_done = [], 0
     for epoch in range(epochs):
-        # Same batch-order seed expression as _EpochTrainer's epoch loop.
+        # Same batch-order seed expression as the one epoch loop's (train/loop.py).
         for xb, yb in make_batches(ds.x_train, ds.y_train, 128,
                                    seed=cfg.seed * 997 + epoch):
             state, _ = step(state, xb, yb.astype(np.int32),

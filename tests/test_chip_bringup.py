@@ -86,12 +86,12 @@ def test_no_cache_path_built_from_tempfile_pid_or_time():
 # -- no fallback that hides the device ----------------------------------------
 
 def test_on_tpu_is_false_on_the_cpu_backend():
+    from distributed_parameter_server_for_ml_training_tpu.ops import attention
     from distributed_parameter_server_for_ml_training_tpu.ops.pallas import (
-        flash_attention, quantize)
+        quantize)
     assert jax.default_backend() == "cpu"
-    assert flash_attention._on_tpu() is False
+    assert attention._on_tpu() is False
     assert quantize._on_tpu() is False
-    assert flash_attention.flash_preferred(8192) is False
 
 
 # -- chip_smoke.py -------------------------------------------------------------

@@ -91,65 +91,6 @@ def test_explicit_block_override_validated():
         flash_attention(q, k, v, block_k=-128)
 
 
-def test_below_crossover_is_bitwise_default_core():
-    """Below the crossover, attention_fn=flash_attention must produce
-    BIT-IDENTICAL outputs to a ViT with no attention_fn — both route
-    through the one shared dense core (ops/attention.dense_core), so
-    dispatch costs nothing where dense wins."""
-    from distributed_parameter_server_for_ml_training_tpu.models.vit import ViT
-
-    kw = dict(patch_size=4, hidden_dim=64, depth=2, num_heads=2,
-              num_classes=10, dtype=jnp.bfloat16)
-    default_vit = ViT(**kw)
-    auto_vit = ViT(**kw, attention_fn=flash_attention)
-    x = jax.random.normal(jax.random.PRNGKey(0), (2, 32, 32, 3))
-    params = default_vit.init(jax.random.PRNGKey(1), x, train=False)
-    out_d = jax.jit(lambda p, x: default_vit.apply(p, x, train=False))(
-        params, x)
-    out_a = jax.jit(lambda p, x: auto_vit.apply(p, x, train=False))(
-        params, x)
-    np.testing.assert_array_equal(np.asarray(out_d), np.asarray(out_a))
-
-
-def test_crossover_dispatch(monkeypatch):
-    """use_pallas=None dispatches on the MEASURED crossover: dense below,
-    Pallas at/above (and never Pallas off-TPU)."""
-    import distributed_parameter_server_for_ml_training_tpu.ops.pallas.flash_attention as fa
-
-    xover = fa.flash_crossover()
-    assert xover >= 128  # sane measured value
-    monkeypatch.setattr(fa, "_on_tpu", lambda: False)
-    assert not fa.flash_preferred(xover)          # off TPU: never
-    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
-    assert not fa.flash_preferred(xover - 1)
-    assert fa.flash_preferred(xover)
-    assert fa.flash_preferred(4 * xover)
-
-
-def test_dispatch_padding_tax(monkeypatch):
-    """Non-128-multiple lengths pay (t/t_padded)^2 on the kernel's padded
-    FLOPs; the predicate must reject lengths whose taxed speedup falls
-    under the tie threshold even above the crossover (measured on-chip:
-    T=576 -> flash 0.89x dense)."""
-    import distributed_parameter_server_for_ml_training_tpu.ops.pallas.flash_attention as fa
-
-    monkeypatch.setattr(fa, "_on_tpu", lambda: True)
-    monkeypatch.setattr(fa, "_crossover_record", lambda: {
-        "crossover_t": 512,
-        "measured_speedups_fwd_bwd": {"512": 1.02, "1024": 1.04,
-                                      "2048": 1.30, "4096": 1.72}})
-    assert fa.flash_preferred(512)       # clean multiple at the crossover
-    assert fa.flash_preferred(1024)
-    # 576 pads to 640: ~1.02 * (576/640)^2 = 0.83 < 0.95 -> dense
-    assert not fa.flash_preferred(576)
-    # 1056 pads to 1152: ~1.07 * (1056/1152)^2 = 0.90 < 0.95 -> dense
-    assert not fa.flash_preferred(1056)
-    # 2040 pads to 2048: 1.30 * ~0.99 -> flash
-    assert fa.flash_preferred(2040)
-    # interpolation clamps beyond the table
-    assert fa.flash_preferred(8192)
-
-
 @pytest.mark.parametrize("t,causal", [(197, False), (197, True), (300, True)])
 def test_kernels_interpret_mode(t, causal, monkeypatch):
     """The ACTUAL Pallas kernels (loop bounds, SMEM scalars, padding
@@ -264,6 +205,47 @@ def test_select_core_sends_long_bf16_sequences_to_flash(on_tpu, dtype, t,
     assert at.select_core(on_tpu=on_tpu, causal=causal, dtype=dtype, t=t,
                           num_heads=32, head_dim=64 if dv == 32 else 192,
                           v_head_dim=dv) == want
+
+
+@pytest.mark.parametrize("on_tpu,t,want", [
+    (False, 1024, "dense"),     # every CPU run, whatever the length
+    (True, 576, "dense"),       # under FLASH_MIN_T
+    (True, 1024, "flash"),
+    (True, 2040, "dense"),      # not whole tiles
+])
+def test_the_bthd_door_asks_the_same_rule(on_tpu, t, want, monkeypatch):
+    """``flash_attention(use_pallas=None)`` takes what ``select_core`` says
+    for its shapes (``fused_short`` cannot be held under ``[B, T, H, D]``
+    and reads ``dense``): bitwise ``dense_core``, or the kernels' path."""
+    from distributed_parameter_server_for_ml_training_tpu.ops import (
+        attention as at)
+    from distributed_parameter_server_for_ml_training_tpu.ops.pallas import (
+        flash_attention as fa)
+    from distributed_parameter_server_for_ml_training_tpu.telemetry import (
+        get_registry)
+
+    q, k, v = _qkv(1, t, 2, 64, jnp.bfloat16)
+    assert at.select_core(on_tpu=on_tpu, causal=False, dtype=q.dtype, t=t,
+                          num_heads=2, head_dim=64) == want
+    kernels = []
+
+    def heads_first(q3, k3, v3, block_q, block_k, use_pallas, causal):
+        kernels.append(use_pallas)
+        return jnp.zeros_like(q3)
+
+    monkeypatch.setattr(at, "_on_tpu", lambda: on_tpu)
+    monkeypatch.setattr(fa, "_flash_heads_first", heads_first)
+    counted = get_registry().counter("dps_attention_core_total", impl=want)
+    before = counted.value
+    out = flash_attention(q, k, v)
+    assert counted.value == before + 1
+    if want == "flash":
+        assert kernels == [True]
+    else:
+        assert kernels == []
+        np.testing.assert_array_equal(
+            np.asarray(out, np.float32),
+            np.asarray(at.dense_core(q, k, v), np.float32))
 
 
 def _heads_major(q, k, v):

@@ -664,6 +664,51 @@ class TestFaultInjection:
         with pytest.raises(SessionLostError):
             client.fetch(0)
 
+    @pytest.mark.parametrize("then", ["reply", "session_lost"])
+    def test_cancelled_in_flight_is_transient(self, then):
+        """A server stopped without grace answers the call in flight
+        CANCELLED. The client cancels nothing itself, so that is the server
+        going away: retried like UNAVAILABLE, and past the budget it is
+        ``SessionLostError`` (what the reconnect machine acts on), never a
+        bare ``RpcError``."""
+        import grpc
+
+        from distributed_parameter_server_for_ml_training_tpu.comms.faults \
+            import InjectedRpcError
+        store = ParameterStore(
+            {"w": np.ones(8, np.float32)},
+            StoreConfig(mode="async", total_workers=1, push_codec="none"))
+        server, port = serve(store, port=0)
+        try:
+            client = RemoteStore(f"localhost:{port}", rpc_retries=1,
+                                 rpc_backoff=0.01)
+            wid, _ = client.register_worker("cancelled")
+            real, calls = client._call["FetchParameters"], []
+
+            def fetch_call(request, timeout=None):
+                calls.append(len(calls))
+                if len(calls) == 1:
+                    raise InjectedRpcError(grpc.StatusCode.CANCELLED,
+                                           "the server stopped")
+                if then == "session_lost":
+                    raise InjectedRpcError(grpc.StatusCode.UNAVAILABLE,
+                                           "and stayed away")
+                return real(request, timeout=timeout)
+
+            client._call["FetchParameters"] = fetch_call
+            if then == "reply":
+                params, step = client.fetch(wid)
+                assert step == 0
+                np.testing.assert_array_equal(params["w"],
+                                              np.ones(8, np.float32))
+            else:
+                with pytest.raises(SessionLostError):
+                    client.fetch(wid)
+            assert len(calls) == 2      # one retry: the whole budget
+            client.close()
+        finally:
+            server.stop(grace=None)
+
 
 class TestTenantJournalIsolation:
     """Per-job checkpoint lineage across a restart (docs/TENANCY.md):
