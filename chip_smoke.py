@@ -551,8 +551,69 @@ def _child_kernels(only: tuple = ()) -> int:
                 "tol": tol, "max_err_over_max_bthd": {
                     k: round(v, 6) for k, v in errs.items()}}
 
+    def flash_kernel_ms(b, t, h, d, dv, calls=5):
+        """Device ms a call of each flash kernel at the decoder LM's shape
+        (q, k ``[B*H, T, D]``; v, dO ``[B, T, H*Dv]``, causal), each in a
+        program of its own (the backward's other kernel is dropped with its
+        unused results: one Mosaic call a program, asserted): the median
+        duration of the kernel's events in a device trace of ``calls``
+        runs. For a builder judging a change to a kernel body, parent
+        beside change; no check reads it."""
+        import glob
+        import statistics
+        import tempfile
+        from jax.profiler import ProfileData
+
+        ks = jax.random.split(jax.random.PRNGKey(t), 4)
+        q, k = (jax.random.normal(kk, (b * h, t, d), jnp.bfloat16)
+                for kk in ks[:2])
+        v, do = (jax.random.normal(kk, (b, t, h * dv), jnp.bfloat16)
+                 for kk in ks[2:])
+        blk = fa.pick_block(t)
+
+        def fwd(q, k, v):
+            return fa._flash_fwd_impl(q, k, v, t, blk, blk, True,
+                                      causal=True, v_heads=h)
+
+        def bwd(*args):
+            return fa._flash_bwd_impl(*args, t, blk, blk, True, causal=True,
+                                      q_len=t, v_heads=h)
+
+        o, lse = jax.jit(fwd)(q, k, v)
+        grads = (q, k, v, do, lse, fa._delta_of_heads(do, o, h, blk, True))
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = options.host_tracer_level = 0
+        out = {}
+        for name, fn, args in (
+                ("flash_attention_fwd", fwd, (q, k, v)),
+                ("flash_attention_bwd_dq", lambda *a: bwd(*a)[0], grads),
+                ("flash_attention_bwd_dkv", lambda *a: bwd(*a)[1:], grads)):
+            exe, _s, kernels = compiled(fn, *args)
+            assert kernels == 1, (name, kernels)
+            jax.block_until_ready(exe(*args))
+            os.makedirs(LOG_DIR, exist_ok=True)
+            with tempfile.TemporaryDirectory(dir=LOG_DIR) as trace_dir:
+                jax.profiler.start_trace(trace_dir, profiler_options=options)
+                for _ in range(calls):
+                    result = exe(*args)
+                jax.block_until_ready(result)
+                jax.profiler.stop_trace()
+                (xplane,) = glob.glob(trace_dir + "/**/*.xplane.pb",
+                                      recursive=True)
+                ms = [e.duration_ns / 1e6
+                      for plane in ProfileData.from_file(xplane).planes
+                      if plane.name.startswith("/device:TPU:")
+                      for line in plane.lines if line.name == "XLA Ops"
+                      for e in line.events
+                      if e.name.split(" = ")[0].lstrip("%").split(".")[0]
+                      == name]
+            assert len(ms) == calls, (name, len(ms))
+            out[name] = round(statistics.median(ms), 3)
+        return out
+
     check("flash_heads_major_bf16_T4096_causal")(
-        lambda: heads_major_case(1, 4096, 4, 192, 128, 1e-2))
+        lambda: {**heads_major_case(1, 4096, 4, 192, 128, 1e-2),
+                 "kernel_ms_b4_h32": flash_kernel_ms(4, 4096, 32, 192, 128)})
 
     def short_case(b, t, h, d, tol):
         """The fused short-sequence kernel as ``attention_core`` calls it

@@ -12,12 +12,24 @@ Design (standard flash attention, TPU-shaped):
 
 - forward: grid over (batch*heads, T/BLOCK_Q); each program streams K/V
   through VMEM in BLOCK_K tiles, keeping the online-softmax running
-  (max, sum, acc) in registers — the [T, T] score matrix never
+  (max, sum, acc) in VMEM scratch — the [T, T] score matrix never
   materializes. Saves the per-row logsumexp for the backward.
 - backward: two kernels re-using the saved LSE (no softmax recompute
   ambiguity): dQ tiles over query blocks, dK/dV tiles over key blocks,
   each streaming the opposite operand. delta = rowsum(dO * O) is a cheap
   elementwise precompute.
+- what a program sums over its tiles (the forward's max, sum and acc, dQ,
+  dK and dV) is VMEM scratch that a tile reads and writes where it uses it;
+  the loops carry nothing. As loop-carried values the same arrays, up to
+  three times the register file, were copied between spill slots at both
+  ends of every iteration while the MXU stood still. The forward's max and
+  sum are ``[BLOCK_Q, 128]`` (a row's max in every lane, its sum as 128
+  partial sums that meet after the loop).
+- a program visits only the tiles its loop bounds leave (``_k_ranges``,
+  ``_q_ranges``: not the padding, not the causal future; ``tile_plan``
+  counts them) and masks every tile it visits: on a v5e the kernels wait
+  for the MXU, and a loop of their own for the tiles the mask cannot touch
+  made them slower (PERF.md section 6, PR 33).
 - ``v`` (and so ``o``) may have a head width of its own: latent attention
   (models/joyai.py) has q/k heads of 192 and v heads of 128. The softmax
   scale is 1/sqrt of the q/k width.
@@ -51,6 +63,8 @@ import jax.numpy as jnp
 import numpy as np
 
 _NEG_INF = -1e30
+#: lanes of a vector register: the width of the forward's running max and sum
+_LANES = 128
 # Measured on-chip (experiments/measure_mfu.py block sweep): 512-wide tiles
 # nearly halve the backward at T>=2048 vs 128 (bigger serial-loop bodies
 # keep the MXU fed); short sequences clamp down so padding stays small.
@@ -64,78 +78,173 @@ VMEM_LIMIT_BYTES = 64 << 20
 INTERPRET = False
 
 
-# -- forward ------------------------------------------------------------------
+# -- which tiles a program visits ---------------------------------------------
+#
+# A tile is one (query block, K block) pair. Both helpers below compute from
+# the same facts: the static shapes, ``kv_len``, and the (q, k) offsets.
+# ``xp`` is ``jnp`` in the kernels, where the offsets are SMEM scalars and
+# the grid position is traced (a ring hop has non-zero offsets and block_q
+# may differ from block_k), and ``np`` in ``tile_plan``. Not causal, every
+# bound is a Python int.
 
-def _k_loop_hi(pos_ref, n_k: int, block_q: int, block_k: int, kv_len: int,
-               causal: bool):
-    """Upper bound (exclusive) of the K-block loop for the current query
-    block: fully-padded K blocks (beyond ``kv_len``, static) are skipped
-    outright, and under causal masking so are blocks entirely in the
-    future of this query block's last GLOBAL row (dynamic — depends on
-    the SMEM (q_offset, k_offset) scalars and the grid position)."""
-    import jax.experimental.pallas as pl
-
+def _k_ranges(xp, pid_q, shift, n_k: int, block_q: int, block_k: int,
+              kv_len: int, causal: bool):
+    """``(n_full, hi)`` for query block ``pid_q``: the K-block loop runs
+    ``[0, hi)``; blocks ``[hi, n_k)`` lie in the padding (static) or, under
+    causal masking, wholly in the future of the block's last GLOBAL row
+    (dynamic) and are skipped. Of the blocks visited, ``[0, n_full)`` are
+    wholly visible and wholly inside ``kv_len``: the mask changes nothing
+    there (``tile_plan`` counts them; the kernels mask every tile they
+    visit, PERF.md section 6, PR 33). ``shift`` is ``q_offset -
+    k_offset``."""
     hi = min(n_k, -(-kv_len // block_k))           # static: skip padding
-    if not causal:
-        return hi
-    row_max = pos_ref[0, 0] + (pl.program_id(1) + 1) * block_q - 1
-    dyn = jnp.floor_divide(row_max - pos_ref[0, 1], block_k) + 1
-    return jnp.clip(dyn, 0, hi)
+    n_full = kv_len // block_k                     # static
+    if causal:
+        row0 = shift + pid_q * block_q             # first row, from k's 0
+        hi = xp.clip((row0 + block_q - 1) // block_k + 1, 0, hi)
+        n_full = xp.clip((row0 + 1) // block_k, 0, n_full)
+    return n_full, hi
 
 
-def _fwd_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, lse_ref, *,
+def _q_ranges(xp, pid_k, shift, n_q: int, block_q: int, block_k: int,
+              kv_len: int, q_len: int, t_k: int, causal: bool):
+    """``(lo, hi)`` for K block ``pid_k``: the query-block loop runs
+    ``[lo, hi)``. Blocks from ``hi`` on are padded query rows (zero dO and
+    delta; static); under causal masking blocks before ``lo`` lie wholly
+    before the K block's first GLOBAL column (dynamic); a K block wholly in
+    the padding visits none. ``shift`` is ``k_offset - q_offset``."""
+    hi = min(n_q, -(-q_len // block_q))            # static
+    lo = 0
+    if causal:
+        col0 = shift + pid_k * block_k             # first column, from q's 0
+        lo = xp.clip(col0 // block_q, 0, hi)
+    if kv_len < t_k:                               # static: padded keys
+        lo = xp.where(pid_k * block_k >= kv_len, hi, lo)
+    return lo, hi
+
+
+def tile_plan(t_q: int, t_k: int, kv_len: int, block_q: int, block_k: int,
+              causal: bool) -> dict:
+    """Tiles of one (batch, head) of one kernel call, offsets zero, from the
+    loop bounds the kernels themselves use: ``skipped`` (never computed),
+    ``masked`` (computed; the diagonal or the end of the keys crosses them)
+    and ``unmasked`` (computed, wholly visible). At the decoder LM's shape
+    (4,096 causal tokens, 512-wide blocks): 28 unmasked, 8 masked, 28
+    skipped."""
+    n_q, n_k = t_q // block_q, t_k // block_k
+    n_full, hi = (np.broadcast_to(x, (n_q,)) for x in _k_ranges(
+        np, np.arange(n_q), 0, n_k, block_q, block_k, kv_len, causal))
+    unmasked, visited = int(n_full.sum()), int(hi.sum())
+    return {"unmasked": unmasked, "masked": visited - unmasked,
+            "skipped": n_q * n_k - visited}
+
+
+def _count_tiles(programs: int, plan: dict) -> None:
+    """``dps_flash_tiles_total{kind}``, at trace time: ``plan`` for each of
+    one kernel call's ``programs`` (batch, head)s."""
+    from ...telemetry import get_registry
+    for kind, n in plan.items():
+        get_registry().counter("dps_flash_tiles_total", kind=kind).inc(
+            programs * n)
+
+
+def _keep(shape, pos_ref, row0, col0, kv_len: int, pad_k: bool,
+          causal: bool):
+    """A tile's ``[BQ, BK]`` keep-mask (None where nothing can be masked):
+    local columns under ``kv_len``, compared only where the keys are padded
+    at all (``pad_k``, static), and under causal masking GLOBAL column <=
+    GLOBAL row (``pos_ref`` holds (q_offset, k_offset), non-zero when the
+    call is one hop of a sharded ring). ``row0`` / ``col0``: the tile's
+    first local row / column."""
+    keep = None
+    if pad_k or causal:
+        col = col0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    if pad_k:
+        keep = col < kv_len
+    if causal:
+        row_g = pos_ref[0, 0] + row0 \
+            + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        visible = pos_ref[0, 1] + col <= row_g
+        keep = visible if keep is None else keep & visible
+    return keep
+
+
+def _lanes(x, width: int):
+    """``x`` ``[BQ, 128]`` with every lane of a row equal -> ``[BQ, width]``
+    of the same: whole vregs side by side, no cross-lane move."""
+    if width % _LANES == 0:
+        return jnp.tile(x, (1, width // _LANES))
+    if width < _LANES:
+        return x[:, :width]
+    return jnp.broadcast_to(x[:, :1], (x.shape[0], width))
+
+
+# -- the kernels --------------------------------------------------------------
+#
+# What a program sums over its tiles lives in VMEM scratch, read and written
+# where a tile uses it, and the loop carries nothing. Carried as loop values
+# the same arrays (192 vregs in the forward and in dK/dV, twice the register
+# file) were copied from spill slot to spill slot at every iteration's two
+# ends, 370-520 bundles of a 1,850-3,300 bundle tile in which the MXU stood
+# still (PERF.md section 6, PR 33).
+
+def _fwd_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
+                m_ref, l_ref, acc_ref, *,
                 scale: float, block_q: int, block_k: int, kv_len: int,
                 causal: bool):
     import jax.experimental.pallas as pl  # noqa: F401 (pl.ds below)
 
     q = q_ref[0]                                   # [BQ, D]
-    bq = q.shape[0]
     n_k = k_ref.shape[1] // block_k
+    pad_k = kv_len < k_ref.shape[1]
     # program_id is read OUTSIDE the loop body: the interpret-mode lowering
     # can't substitute it inside fori_loop sub-jaxprs (and hoisting is free
     # on the TPU path).
     pid_q = pl.program_id(1)
+    # Running max and sum a row as [BQ, 128]: the max with every lane of a
+    # row equal, the sum as 128 partial sums a row (column c of a tile goes
+    # to lane c % 128) that meet in one cross-lane sum after the loop. A
+    # [BQ, 1] array costs the same 64 vregs and a masked store a vreg.
+    m_ref[...] = jnp.full(m_ref.shape, _NEG_INF, jnp.float32)
+    l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)   # v's own width
 
-    m0 = jnp.full((bq, 1), _NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq, 1), jnp.float32)
-    acc0 = jnp.zeros((bq, v_ref.shape[2]), jnp.float32)  # v's own width
-
-    def body(i, carry):
-        m, l, acc = carry
+    def tile(i, carry):
         kb = k_ref[0, pl.ds(i * block_k, block_k), :]      # [BK, D]
         vb = v_ref[0, pl.ds(i * block_k, block_k), :]
         s = jax.lax.dot_general(
             q, kb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale    # [BQ, BK]
-        col = i * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(col < kv_len, s, _NEG_INF)
-        if causal:
-            # Global positions: pos_ref holds (q_offset, k_offset) —
-            # nonzero when this call is one hop of a sharded ring.
-            row_g = pos_ref[0, 0] + pid_q * block_q \
-                + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            s = jnp.where(pos_ref[0, 1] + col <= row_g, s, _NEG_INF)
+        keep = _keep(s.shape, pos_ref, pid_q * block_q, i * block_k,
+                     kv_len, pad_k, causal)
+        if keep is not None:
+            s = jnp.where(keep, s, _NEG_INF)
+        m = m_ref[...]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)                             # [BQ, BK]
+        p = jnp.exp(s - _lanes(m_new, block_k))            # [BQ, BK]
         alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        m_ref[...] = m_new
+        p_lanes = p[:, :_LANES]
+        for c in range(_LANES, block_k, _LANES):
+            p_lanes = p_lanes + p[:, c:c + _LANES]
+        l_ref[...] = l_ref[...] * alpha + p_lanes
         pv = jax.lax.dot_general(
             p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        return m_new, l_new, acc * alpha + pv
+        acc_ref[...] = acc_ref[...] * _lanes(alpha, pv.shape[1]) + pv
+        return carry
 
-    m, l, acc = jax.lax.fori_loop(
-        0, _k_loop_hi(pos_ref, n_k, block_q, block_k, kv_len, causal),
-        body, (m0, l0, acc0))
-    l = jnp.maximum(l, 1e-30)
-    o_ref[0] = (acc / l).astype(o_ref.dtype)
-    lse_ref[0] = m + jnp.log(l)
+    _n_full, hi = _k_ranges(jnp, pid_q, pos_ref[0, 0] - pos_ref[0, 1], n_k,
+                            block_q, block_k, kv_len, causal)
+    jax.lax.fori_loop(0, hi, tile, 0)
+    l = jnp.maximum(jnp.sum(l_ref[...], axis=-1, keepdims=True), 1e-30)
+    o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
+    lse_ref[0] = m_ref[:, :1] + jnp.log(l)
 
 
 def _bwd_dq_kernel(pos_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                   delta_ref, dq_ref,
-                   *, scale: float, block_q: int, block_k: int, kv_len: int,
+                   delta_ref, dq_ref, dq_acc, *,
+                   scale: float, block_q: int, block_k: int, kv_len: int,
                    causal: bool):
     import jax.experimental.pallas as pl  # noqa: F401
 
@@ -144,61 +253,51 @@ def _bwd_dq_kernel(pos_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     lse = lse_ref[0]                                       # [BQ, 1]
     delta = delta_ref[0]
     n_k = k_ref.shape[1] // block_k
+    pad_k = kv_len < k_ref.shape[1]
     pid_q = pl.program_id(1)       # hoisted: see _fwd_kernel
+    dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
 
-    def body(i, dq):
+    def tile(i, carry):
         kb = k_ref[0, pl.ds(i * block_k, block_k), :]
         vb = v_ref[0, pl.ds(i * block_k, block_k), :]
         s = jax.lax.dot_general(
             q, kb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
-        col = i * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        keep = col < kv_len
-        if causal:
-            row_g = pos_ref[0, 0] + pid_q * block_q \
-                + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            keep = keep & (pos_ref[0, 1] + col <= row_g)
-        p = jnp.where(keep, jnp.exp(s - lse), 0.0)          # [BQ, BK]
+        p = jnp.exp(s - lse)                               # [BQ, BK]
+        keep = _keep(s.shape, pos_ref, pid_q * block_q, i * block_k,
+                     kv_len, pad_k, causal)
+        if keep is not None:
+            p = jnp.where(keep, p, 0.0)
         dp = jax.lax.dot_general(
             do.astype(vb.dtype), vb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
         ds = p * (dp - delta)
-        return dq + jax.lax.dot_general(
+        dq_acc[...] += jax.lax.dot_general(
             ds.astype(kb.dtype), kb, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
+        return carry
 
-    dq = jax.lax.fori_loop(
-        0, _k_loop_hi(pos_ref, n_k, block_q, block_k, kv_len, causal),
-        body, jnp.zeros(q.shape[:1] + (q.shape[1],), jnp.float32))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
+    _n_full, hi = _k_ranges(jnp, pid_q, pos_ref[0, 0] - pos_ref[0, 1], n_k,
+                            block_q, block_k, kv_len, causal)
+    jax.lax.fori_loop(0, hi, tile, 0)
+    dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(pos_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                    delta_ref, dk_ref, dv_ref, *,
+                    delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
                     scale: float, block_q: int, kv_len: int, q_len: int,
-                    causal: bool):
+                    t_k: int, causal: bool):
     import jax.experimental.pallas as pl
 
     kb = k_ref[0]                                          # [BK, D]
     vb = v_ref[0]
     bk = kb.shape[0]
-    col = pl.program_id(1) * bk + jax.lax.broadcasted_iota(
-        jnp.int32, (1, bk), 1)                             # [1, BK] global
-    n_q = q_ref.shape[1] // block_q
-    # Padded QUERY blocks (beyond q_len) have zero dO/delta — skip them
-    # (static); under causal masking also skip query blocks entirely
-    # BEFORE this K block's first global column (dynamic).
-    hi_q = min(n_q, -(-q_len // block_q))
-    if causal:
-        col0 = pos_ref[0, 1] + pl.program_id(1) * bk
-        lo_q = jnp.clip(jnp.floor_divide(col0 - pos_ref[0, 0], block_q),
-                        0, hi_q)
-    else:
-        lo_q = 0
+    pid_k = pl.program_id(1)       # hoisted: see _fwd_kernel
+    pad_k = kv_len < t_k
+    dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
+    dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
 
-    def body(j, carry):
-        dk, dv = carry
+    def tile(j, carry):
         qb = q_ref[0, pl.ds(j * block_q, block_q), :]      # [BQ, D]
         dob = do_ref[0, pl.ds(j * block_q, block_q), :].astype(jnp.float32)
         lse = lse_ref[0, pl.ds(j * block_q, block_q), :]   # [BQ, 1]
@@ -206,29 +305,29 @@ def _bwd_dkv_kernel(pos_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         s = jax.lax.dot_general(
             qb, kb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale    # [BQ, BK]
-        keep = col < kv_len
-        if causal:
-            row_g = pos_ref[0, 0] + j * block_q \
-                + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            keep = keep & (pos_ref[0, 1] + col <= row_g)
-        p = jnp.where(keep, jnp.exp(s - lse), 0.0)
-        dv = dv + jax.lax.dot_general(
+        p = jnp.exp(s - lse)
+        keep = _keep(s.shape, pos_ref, j * block_q, pid_k * bk, kv_len,
+                     pad_k, causal)
+        if keep is not None:
+            p = jnp.where(keep, p, 0.0)
+        dv_acc[...] += jax.lax.dot_general(
             p.astype(dob.dtype), dob, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [BK, D]
+            preferred_element_type=jnp.float32)            # [BK, Dv]
         dp = jax.lax.dot_general(
             dob.astype(vb.dtype), vb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)            # [BQ, BK]
         ds = p * (dp - delta)
-        dk = dk + jax.lax.dot_general(
+        dk_acc[...] += jax.lax.dot_general(
             ds.astype(qb.dtype), qb, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale    # [BK, D]
-        return dk, dv
+        return carry
 
-    dk, dv = jax.lax.fori_loop(
-        lo_q, hi_q, body, (jnp.zeros(kb.shape, jnp.float32),
-                           jnp.zeros(vb.shape, jnp.float32)))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    lo, hi = _q_ranges(
+        jnp, pid_k, pos_ref[0, 1] - pos_ref[0, 0], q_ref.shape[1] // block_q,
+        block_q, bk, kv_len, q_len, t_k, causal)
+    jax.lax.fori_loop(lo, hi, tile, 0)
+    dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+    dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 # -- jnp fallback (identical masked math, dense) ------------------------------
@@ -284,6 +383,12 @@ def _compiler_params():
 
 
 # -- core op on [BH, T_pad, D] with custom VJP --------------------------------
+
+def _static_zeros(*offsets) -> bool:
+    """Whether the call's offsets are known to be zero as it is traced (a
+    ring hop's are traced values)."""
+    return all(isinstance(x, (int, np.integer)) and x == 0 for x in offsets)
+
 
 def _pos_scalars(q_offset, k_offset):
     """(1, 2) int32 SMEM payload carrying the global (q, k) offsets."""
@@ -355,6 +460,9 @@ def _flash_fwd_impl(q, k, v, kv_len, block_q, block_k, use_pallas,
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if _static_zeros(q_offset, k_offset):
+        _count_tiles(bh, tile_plan(tp, k.shape[1], kv_len, block_q, block_k,
+                                   causal))
     n_q = tp // block_q
     blk_pos = pl.BlockSpec(memory_space=pltpu.SMEM)
     blk_q = pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0),
@@ -376,6 +484,9 @@ def _flash_fwd_impl(q, k, v, kv_len, block_q, block_k, use_pallas,
         out_shape=(jax.ShapeDtypeStruct((v.shape[0], tp, v.shape[2]),
                                         out_dtype or q.dtype),
                    jax.ShapeDtypeStruct((bh, tp, 1), jnp.float32)),
+        scratch_shapes=[pltpu.VMEM((block_q, _LANES), jnp.float32),
+                        pltpu.VMEM((block_q, _LANES), jnp.float32),
+                        pltpu.VMEM((block_q, dv), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=INTERPRET, name="flash_attention_fwd",
     )(_pos_scalars(q_offset, k_offset), q, k, v)
@@ -430,6 +541,11 @@ def _flash_bwd_impl(q, k, v, do, lse, delta, kv_len, block_q, block_k,
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if _static_zeros(q_offset, k_offset):
+        # dQ visits every query block, dK/dV none that is all padded rows
+        for rows in (tq, min(tq, -(-q_len // block_q) * block_q)):
+            _count_tiles(bh, tile_plan(rows, tk, kv_len, block_q, block_k,
+                                       causal))
     blk_q = pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0),
                          memory_space=pltpu.VMEM)
     blk_do = _head_block(block_q, dv, v_heads, whole=False)
@@ -458,19 +574,22 @@ def _flash_bwd_impl(q, k, v, do, lse, delta, kv_len, block_q, block_k,
                   blk_row_q],
         out_specs=blk_q,
         out_shape=jax.ShapeDtypeStruct(q.shape, dts[0]),
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=INTERPRET, name="flash_attention_bwd_dq",
     )(pos, q, k, v, do, lse, delta)
 
     dk, dv = pl.pallas_call(
         partial(_bwd_dkv_kernel, scale=scale, block_q=block_q,
-                kv_len=kv_len, q_len=q_len, causal=causal),
+                kv_len=kv_len, q_len=q_len, t_k=tk, causal=causal),
         grid=(bh, tk // block_k),
         in_specs=[blk_pos, blk_qfull, blk_k, blk_v, blk_dofull,
                   blk_row_qfull, blk_row_qfull],
         out_specs=(blk_k, blk_v),
         out_shape=(jax.ShapeDtypeStruct(k.shape, dts[1]),
                    jax.ShapeDtypeStruct(v.shape, dts[2])),
+        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
+                        pltpu.VMEM((block_k, dv), jnp.float32)],
         compiler_params=_compiler_params(),
         interpret=INTERPRET, name="flash_attention_bwd_dkv",
     )(pos, q, k, v, do, lse, delta)

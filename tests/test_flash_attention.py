@@ -118,6 +118,147 @@ def test_kernels_interpret_mode(t, causal, monkeypatch):
                                    err_msg=f"d{name} mismatch")
 
 
+def _value_and_grads(fn, q, k, v, cot):
+    return jax.value_and_grad(
+        lambda a, b, c: jnp.sum(fn(a, b, c).astype(jnp.float32)
+                                * cot.astype(jnp.float32)),
+        argnums=(0, 1, 2))(q, k, v)
+
+
+@pytest.mark.parametrize("door,dtype,t,causal,block_q,block_k", [
+    ("bthd", jnp.float32, 512, True, 128, 128),
+    ("bthd", jnp.float32, 512, False, 128, 128),
+    ("bthd", jnp.float32, 600, True, 128, 128),     # padded to 640: the
+    ("bthd", jnp.float32, 600, False, 128, 128),    # last K block is masked
+    ("bthd", jnp.float32, 512, True, 256, 128),
+    ("bthd", jnp.float32, 512, True, 128, 256),
+    ("bthd", jnp.float32, 600, True, 256, 128),     # 768: a K block all pad
+    ("bthd", jnp.float32, 600, False, 128, 256),
+    ("bthd", jnp.bfloat16, 512, True, 128, 128),
+    ("bthd", jnp.bfloat16, 600, False, 128, 128),
+    ("heads_major", jnp.float32, 512, True, 128, 128),
+    ("heads_major", jnp.bfloat16, 600, True, 128, 128),
+    ("heads_major", jnp.bfloat16, 512, True, 256, 128),
+])
+def test_kernels_sum_over_several_tiles(door, dtype, t, causal, block_q,
+                                        block_k, monkeypatch):
+    """Several tiles a program (interpret mode; the cases above are one
+    tile a program): every kernel sums into its VMEM scratch over wholly
+    visible tiles, tiles the diagonal crosses and, at 600 tokens, a last K
+    block that is partly padding; forward and all three gradients against
+    plain float32 attention. ``heads_major`` is the decoder LM's door at
+    its widths, q/k heads of 192 and v heads of 128."""
+    import distributed_parameter_server_for_ml_training_tpu.ops.pallas.flash_attention as fa
+
+    monkeypatch.setattr(fa, "INTERPRET", True)
+    plan = fa.tile_plan(-(-t // block_q) * block_q, -(-t // block_k) * block_k,
+                        t, block_q, block_k, causal)
+    assert plan["unmasked"] and (plan["masked"] or not (causal or t % 128))
+    d, dv = (192, 128) if door == "heads_major" else (64, 64)
+    ks = jax.random.split(jax.random.PRNGKey(t + block_q), 4)
+    q, k = (jax.random.normal(x, (1, t, 2, d), dtype) for x in ks[:2])
+    v, cot = (jax.random.normal(x, (1, t, 2, dv), dtype) for x in ks[2:])
+
+    def kernels(q, k, v):
+        if door == "bthd":
+            return flash_attention(q, k, v, causal=causal, use_pallas=True,
+                                   block_q=block_q, block_k=block_k)
+        return fa.flash_attention_heads_major(
+            *_heads_major(q, k, v), causal=causal, block_q=block_q,
+            block_k=block_k).reshape(v.shape)
+
+    def plain(q, k, v):
+        return _dense_f32(*(x.astype(jnp.float32) for x in (q, k, v)),
+                          causal)
+
+    out = kernels(q, k, v)
+    assert out.dtype == dtype and out.shape == v.shape
+    fwd_tol, bwd_tol = (2e-3, 5e-3) if dtype == jnp.float32 else (3e-2, 3e-2)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(plain(q, k, v)),
+                               atol=fwd_tol, rtol=fwd_tol)
+    (_l, got), (_l2, want) = (_value_and_grads(fn, q, k, v, cot)
+                              for fn in (kernels, plain))
+    for g, w, name in zip(got, want, "qkv"):
+        w = np.asarray(w, np.float32)
+        # bf16: of the gradient's largest value, as chip_smoke.py measures
+        tol = bwd_tol * (1.0 if dtype == jnp.float32 else np.abs(w).max())
+        np.testing.assert_allclose(np.asarray(g, np.float32), w, atol=tol,
+                                   rtol=bwd_tol, err_msg=f"d{name}")
+
+
+# -- which tiles a program visits (tile_plan, dps_flash_tiles_total) ----------
+
+def test_tile_plan_at_the_decoder_lms_shape():
+    import distributed_parameter_server_for_ml_training_tpu.ops.pallas.flash_attention as fa
+
+    assert fa.tile_plan(4096, 4096, 4096, 512, 512, True) == {
+        "unmasked": 28, "masked": 8, "skipped": 28}
+    assert fa.tile_plan(4096, 4096, 4096, 512, 512, False) == {
+        "unmasked": 64, "masked": 0, "skipped": 0}
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("t_pad,kv_len,block_q,block_k", [
+    (512, 512, 128, 128), (640, 600, 128, 128), (768, 600, 256, 128),
+    (768, 600, 128, 256), (1024, 1024, 256, 128), (1024, 1000, 128, 256),
+    (384, 300, 128, 384), (1024, 520, 512, 256), (768, 512, 384, 256)])
+def test_loop_bounds_agree_with_the_mask(t_pad, kv_len, block_q, block_k,
+                                         causal):
+    """Both kernels' loop bounds (the K-block loop of the forward and dQ,
+    the query-block loop of dK/dV) against a count over the dense mask,
+    offsets zero: a tile is skipped exactly when the mask keeps none of it,
+    and counted unmasked exactly when it keeps all of it."""
+    import distributed_parameter_server_for_ml_training_tpu.ops.pallas.flash_attention as fa
+
+    n_q, n_k = t_pad // block_q, t_pad // block_k
+    mask = np.broadcast_to(np.asarray(
+        fa._position_mask(t_pad, t_pad, kv_len, causal, 0, 0)),
+        (t_pad, t_pad)).reshape(n_q, block_q, n_k, block_k)
+    every, some = mask.all((1, 3)), mask.any((1, 3))
+    want = {"unmasked": int(every.sum()), "masked": int((some & ~every).sum()),
+            "skipped": int((~some).sum())}
+    assert fa.tile_plan(t_pad, t_pad, kv_len, block_q, block_k,
+                        causal) == want
+    # tile by tile
+    n_full, hi = (np.broadcast_to(x, (n_q,)) for x in fa._k_ranges(
+        np, np.arange(n_q), 0, n_k, block_q, block_k, kv_len, causal))
+    lo, hi_q = (np.broadcast_to(x, (n_k,)) for x in fa._q_ranges(
+        np, np.arange(n_k), 0, n_q, block_q, block_k, kv_len, t_pad, t_pad,
+        causal))
+    for i in range(n_q):
+        for j in range(n_k):
+            assert (j < hi[i]) == some[i, j] == (lo[j] <= i < hi_q[j])
+            assert (j < n_full[i]) == every[i, j]
+
+
+def test_tracing_latent_attention_counts_its_tiles(monkeypatch):
+    """``dps_flash_tiles_total{kind}`` at trace time: the decoder LM's tiny
+    latent attention at 1,024 bf16 tokens, told it is on a TPU, traces the
+    three kernels over 4 (batch, head)s of 2 x 2 tiles each (1 unmasked, 2
+    masked, 1 skipped), forward and backward."""
+    from distributed_parameter_server_for_ml_training_tpu.models import joyai
+    from distributed_parameter_server_for_ml_training_tpu.ops import (
+        attention as at)
+    from distributed_parameter_server_for_ml_training_tpu.telemetry import (
+        get_registry)
+
+    monkeypatch.setattr(at, "_on_tpu", lambda: True)
+    cfg = joyai.PRESETS["tiny"]
+    mla = joyai.MLA(cfg, jnp.bfloat16)
+    u = jax.ShapeDtypeStruct((1, 1024, cfg.hidden_size), jnp.bfloat16)
+    params = jax.eval_shape(mla.init, jax.random.PRNGKey(0), u)
+    kinds = {kind: get_registry().counter("dps_flash_tiles_total", kind=kind)
+             for kind in ("unmasked", "masked", "skipped")}
+    before = {kind: c.value for kind, c in kinds.items()}
+    jax.eval_shape(jax.grad(lambda p, u: jnp.sum(
+        mla.apply(p, u).astype(jnp.float32))), params, u)
+    heads = cfg.num_attention_heads
+    assert {kind: c.value - before[kind] for kind, c in kinds.items()} == {
+        "unmasked": 3 * heads * 1, "masked": 3 * heads * 2,
+        "skipped": 3 * heads * 1}
+
+
 @pytest.mark.parametrize("t", [64, 100, 257])
 def test_causal_forward_matches_dense(t):
     q, k, v = _qkv(2, t, 3, 64, seed=5)

@@ -104,6 +104,39 @@ def test_ring_flash_interpreted_kernels(devices, causal, monkeypatch):
                                    err_msg=f"d{name}")
 
 
+def test_ring_flash_interpreted_kernels_two_blocks_a_hop(devices,
+                                                         monkeypatch):
+    """As above, causal, with two blocks a hop (1,024 tokens over 4 devices
+    in 128-row blocks; ``pick_block`` would give a hop one block): a hop on
+    the diagonal sums in one program over a wholly visible tile and a tile
+    the diagonal crosses, and the loop bounds are computed from non-zero
+    (q_offset, k_offset)."""
+    import distributed_parameter_server_for_ml_training_tpu.ops.pallas.flash_attention as fa
+    from distributed_parameter_server_for_ml_training_tpu.parallel.ring_attention import (
+        make_ring_flash_attention)
+
+    monkeypatch.setattr(fa, "INTERPRET", True)
+    monkeypatch.setattr(fa, "MAX_BLOCK", 128)
+    assert fa.pick_block(1024 // 4) == 128
+    ring = make_ring_flash_attention(make_mesh(4), axis="data", causal=True,
+                                     use_pallas=True)
+    ks = jax.random.split(jax.random.PRNGKey(4), 4)
+    q, k, v, cot = (jax.random.normal(kk, (1, 1024, 2, 64), jnp.float32)
+                    for kk in ks)
+    np.testing.assert_allclose(
+        np.asarray(ring(q, k, v)),
+        np.asarray(dense_attention(q, k, v, causal=True)),
+        atol=2e-3, rtol=2e-3)
+    gr = jax.grad(lambda a, b, c: jnp.sum(ring(a, b, c) * cot),
+                  argnums=(0, 1, 2))(q, k, v)
+    gd = jax.grad(lambda a, b, c: jnp.sum(
+        dense_attention(a, b, c, causal=True) * cot),
+        argnums=(0, 1, 2))(q, k, v)
+    for g1, g2, name in zip(gr, gd, "qkv"):
+        np.testing.assert_allclose(np.asarray(g1), np.asarray(g2),
+                                   atol=5e-3, rtol=5e-3, err_msg=f"d{name}")
+
+
 class TestRingFlash:
     """Ring x flash composition: flash kernels as the per-hop block core
     (CPU runs the identical-math jnp hop fallback; the Pallas hop path is
