@@ -1,0 +1,21 @@
+"""The expert layers' routing (the router on the block's input, top-6 and its
+softmax, the sort, each pass's gather and the scatter back): device milliseconds a step, forward, recomputation and
+backward, of the instructions traced under the ``moe_route`` scope
+(``harness/smallthinker_scopes.py``: the compiled step's ``op_name``s joined
+to the traced slice's ``XLA Ops`` events, in this cell's own order of
+scopes). ``None`` without a trace, or from a program whose driver keeps no
+HLO text."""
+
+from harness import smallthinker_scopes
+
+LAYER = "expert layer"
+UNIT = "ms"
+BETTER = "lower"
+SOURCE = "device_trace"
+MOVES = "images_per_s_per_chip"
+DRIVERS = ("sync_mesh_lm",)
+CHIPS = None
+
+
+def read(run):
+    return smallthinker_scopes.scope_ms(run, "moe_route")

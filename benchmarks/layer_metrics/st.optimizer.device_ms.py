@@ -1,0 +1,20 @@
+"""AdamW's pass over the 656.5M parameters and their moments: device milliseconds a step, forward, recomputation and
+backward, of the instructions traced under the ``update`` scope
+(``harness/smallthinker_scopes.py``: the compiled step's ``op_name``s joined
+to the traced slice's ``XLA Ops`` events, in this cell's own order of
+scopes). ``None`` without a trace, or from a program whose driver keeps no
+HLO text."""
+
+from harness import smallthinker_scopes
+
+LAYER = "optimizer"
+UNIT = "ms"
+BETTER = "lower"
+SOURCE = "device_trace"
+MOVES = "images_per_s_per_chip"
+DRIVERS = ("sync_mesh_lm",)
+CHIPS = None
+
+
+def read(run):
+    return smallthinker_scopes.scope_ms(run, "update")

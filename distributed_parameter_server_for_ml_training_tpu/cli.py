@@ -118,17 +118,21 @@ def build_parser() -> argparse.ArgumentParser:
                        default="bfloat16")
         q.add_argument("--model",
                        choices=["resnet18", "resnet50", "vit_b16",
-                                "vit_tiny", "joyai_llm_flash"],
+                                "vit_tiny", "joyai_llm_flash",
+                                "smallthinker"],
                        default="resnet18",
-                       help="joyai_llm_flash (train --mode sync only) is "
-                            "the decoder LM: it trains on seeded synthetic "
-                            "documents with AdamW; give it --lr 3e-3 (tiny) or 3e-6 "
-                            "(ep16), --batch-size in sequences")
+                       help="joyai_llm_flash and smallthinker (train --mode "
+                            "sync only) are the decoder LMs: they train on "
+                            "seeded synthetic documents with AdamW; give "
+                            "them --lr 3e-3 (tiny) or 3e-6 (ep16, ep4), "
+                            "--batch-size in sequences")
         q.add_argument("--model-preset", default=None,
-                       help="joyai_llm_flash: 'tiny' (default; CPU runs, "
-                            "sequences of 64) or 'ep16' (the published "
-                            "widths, one of 16 chips' share, sequences of "
-                            "4,096: a TPU's memory)")
+                       help="a decoder LM's: 'tiny' (default; CPU runs, "
+                            "sequences of 64) or the published widths as "
+                            "one chip's share, a TPU's memory: "
+                            "joyai_llm_flash 'ep16' (one of 16 chips, "
+                            "sequences of 4,096), smallthinker 'ep4' (one "
+                            "of 4, sequences of 16,384)")
         q.add_argument("--dataset", choices=["cifar100", "imagenet-synth"],
                        default="cifar100",
                        help="imagenet-synth = ImageNet-shaped synthetic "
@@ -1210,8 +1214,11 @@ def _load_dataset(args):
         # the decoder LM's task: packed token rows (data/tokens.py)
         from .data.tokens import synthetic_documents
         config = lm_config(args.model, getattr(args, "model_preset", None))
-        # packed rows of 4,096 tokens at the published widths, 64 for tiny
-        seq_len = 4096 if config.hidden_size >= 1024 else 64
+        # packed rows at the published widths: the model's own context
+        # where its configuration states one it trains at, else 4,096; 64
+        # for tiny
+        seq_len = (getattr(config, "max_position_embeddings", 4096)
+                   if config.hidden_size >= 1024 else 64)
         return synthetic_documents(
             vocab_size=config.vocab_size, seq_len=seq_len,
             n_train=getattr(args, "num_train", None) or 64,

@@ -9,6 +9,14 @@ Configs covered (BASELINE.json ``configs``):
     a top-k expert layer told which experts it holds, multi-token
     prediction; built from a ``JoyAIConfig`` (``config=``, or one of its
     ``PRESETS`` by name)
+  - smallthinker — the decoder LM of models/smallthinker.py: sliding-window
+    and full attention mixed, grouped queries, a softmax router that reads
+    the block's input, ReLU-gated experts; from a ``SmallThinkerConfig``
+
+A decoder LM's configuration object comes from :func:`lm_config`: itself, a
+preset's name, or (``lm_config_from_file``) a dict of the source's published
+keys with what the caller overrides, which is how a benchmark driver builds
+any of them without importing a model's module by name.
 
 A model's *family* (``family_of``) says which task trains it
 (train/tasks.py): ``image`` (uint8 images and labels) or ``lm`` (packed
@@ -21,6 +29,8 @@ import jax.numpy as jnp
 
 from .joyai import PRESETS as JOYAI_PRESETS, JoyAIConfig, JoyAILM
 from .resnet import ResNet18, ResNet50
+from .smallthinker import (PRESETS as SMALLTHINKER_PRESETS,
+                           SmallThinkerConfig, SmallThinkerLM)
 from .vit import ViT_B16, ViT_Tiny
 
 _REGISTRY = {
@@ -40,7 +50,11 @@ _REGISTRY = {
 }
 
 #: decoder LMs: name -> (module, configuration class, presets)
-_LM_REGISTRY = {"joyai_llm_flash": (JoyAILM, JoyAIConfig, JOYAI_PRESETS)}
+_LM_REGISTRY = {
+    "joyai_llm_flash": (JoyAILM, JoyAIConfig, JOYAI_PRESETS),
+    "smallthinker": (SmallThinkerLM, SmallThinkerConfig,
+                     SMALLTHINKER_PRESETS),
+}
 
 MODEL_NAMES = tuple(_REGISTRY) + tuple(_LM_REGISTRY)
 
@@ -64,6 +78,14 @@ def lm_config(name: str, config=None):
         raise ValueError(f"{name} has presets {tuple(presets)}, not "
                          f"{config!r}")
     return presets[config or "tiny"]
+
+
+def lm_config_from_file(name: str, published: dict, **overrides):
+    """The configuration of decoder LM ``name`` from a dict that holds the
+    source's published keys (its ``config.json``, or a benchmark's
+    configuration file), with ``overrides`` for the fields that are no
+    published key: the share held here, what the source leaves open."""
+    return _LM_REGISTRY[name][1].from_hf(published, **overrides)
 
 
 def get_model(name: str, num_classes: int = 100, dtype=jnp.bfloat16,

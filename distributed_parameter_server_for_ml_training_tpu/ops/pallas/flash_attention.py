@@ -42,16 +42,30 @@ Design (standard flash attention, TPU-shaped):
 - sequence lengths that aren't block multiples are zero-padded; padded KEY
   positions are masked to -inf in every kernel, padded QUERY rows fall out
   of the backward because their dO/delta are zero.
+- ``window`` (static; causal calls): row ``i`` sees columns ``i - window <
+  j <= i``. Tiles wholly below the band are skipped like tiles above the
+  diagonal, and where the band is shorter than the sequence a program is
+  handed the band alone: its query block's ``window + block`` keys (the
+  dK/dV kernel: its K block's queries) through an element-indexed block
+  spec, not the sequence (models/smallthinker.py: 4,608 of 16,384 rows).
+- grouped queries: ``k`` and ``v`` may hold fewer heads than ``q``
+  (``[B*G, T, D]`` beside ``[B*H, T, D]``; query head ``h`` reads key/value
+  head ``h // (H/G)``). The grid then has the group as its innermost axis:
+  a group's query heads run one after the other on one K/V block, which is
+  fetched once, and the dK/dV kernel sums over them in its scratch. With
+  ``H == G`` and no window every call is what it was before either existed.
 
 Off TPU the same math runs as a jnp fallback (exact dense formulation with
 identical masking), which is what the CPU test suite exercises; kernel-vs-
 fallback parity on real hardware is asserted by tests/test_flash_attention.py
 when a TPU is attached (and by experiments/ on-chip runs).
 
-VMEM sizing: each program holds full K and V for one (batch, head) — at
-D=64 fp32 that bounds T at ~8k per chip; beyond that, shard the sequence
-with ring attention (parallel/ring_attention.py), which calls this kernel
-per hop on T/N-sized blocks.
+VMEM sizing: without a window each program holds full K and V for one
+(batch, head), double-buffered (the dK/dV kernel: full Q, dO, LSE and
+delta, the ``[T, 1]`` rows padded to 128 lanes): at D=128 bf16 and 16,384
+tokens 16 MiB and 48 MiB of ``VMEM_LIMIT_BYTES``; beyond that, shard the
+sequence with ring attention (parallel/ring_attention.py), which calls this
+kernel per hop on T/N-sized blocks.
 """
 
 from __future__ import annotations
@@ -80,23 +94,23 @@ INTERPRET = False
 
 # -- which tiles a program visits ---------------------------------------------
 #
-# A tile is one (query block, K block) pair. Both helpers below compute from
-# the same facts: the static shapes, ``kv_len``, and the (q, k) offsets.
-# ``xp`` is ``jnp`` in the kernels, where the offsets are SMEM scalars and
-# the grid position is traced (a ring hop has non-zero offsets and block_q
-# may differ from block_k), and ``np`` in ``tile_plan``. Not causal, every
-# bound is a Python int.
+# A tile is one (query block, K block) pair. The helpers below compute from
+# the same facts: the static shapes, ``kv_len``, the window, and the (q, k)
+# offsets. ``xp`` is ``jnp`` in the kernels, where the offsets are SMEM
+# scalars and the grid position is traced (a ring hop has non-zero offsets
+# and block_q may differ from block_k), and ``np`` in ``tile_plan``. Not
+# causal, every bound is a Python int.
 
 def _k_ranges(xp, pid_q, shift, n_k: int, block_q: int, block_k: int,
               kv_len: int, causal: bool):
-    """``(n_full, hi)`` for query block ``pid_q``: the K-block loop runs
-    ``[0, hi)``; blocks ``[hi, n_k)`` lie in the padding (static) or, under
+    """``(n_full, hi)`` for query block ``pid_q``: the K-block loop ends at
+    ``hi``; blocks ``[hi, n_k)`` lie in the padding (static) or, under
     causal masking, wholly in the future of the block's last GLOBAL row
-    (dynamic) and are skipped. Of the blocks visited, ``[0, n_full)`` are
-    wholly visible and wholly inside ``kv_len``: the mask changes nothing
-    there (``tile_plan`` counts them; the kernels mask every tile they
-    visit, PERF.md section 6, PR 33). ``shift`` is ``q_offset -
-    k_offset``."""
+    (dynamic) and are skipped. Blocks ``[0, n_full)`` are wholly at or
+    before the diagonal and wholly inside ``kv_len`` (``tile_plan`` counts
+    them; the kernels mask every tile they visit, PERF.md section 6, PR
+    33). ``shift`` is ``q_offset - k_offset``. Where the loop *starts*
+    under a window: :func:`_k_band`."""
     hi = min(n_k, -(-kv_len // block_k))           # static: skip padding
     n_full = kv_len // block_k                     # static
     if causal:
@@ -106,13 +120,26 @@ def _k_ranges(xp, pid_q, shift, n_k: int, block_q: int, block_k: int,
     return n_full, hi
 
 
+def _k_band(xp, pid_q, block_q: int, block_k: int, window: int):
+    """``(lo, full_lo)`` for query block ``pid_q`` under a window, offsets
+    zero: K blocks before ``lo`` lie wholly below the band of the block's
+    FIRST row (row ``i`` sees columns above ``i - window``) and are
+    skipped; blocks from ``full_lo`` on lie wholly inside the band of its
+    LAST row, so only ``[lo, full_lo)`` cross the band's lower edge."""
+    row0 = pid_q * block_q
+    lo = xp.maximum(row0 - window + 1, 0) // block_k
+    full_lo = -(-xp.maximum(row0 + block_q - window, 0) // block_k)
+    return lo, full_lo
+
+
 def _q_ranges(xp, pid_k, shift, n_q: int, block_q: int, block_k: int,
               kv_len: int, q_len: int, t_k: int, causal: bool):
     """``(lo, hi)`` for K block ``pid_k``: the query-block loop runs
     ``[lo, hi)``. Blocks from ``hi`` on are padded query rows (zero dO and
     delta; static); under causal masking blocks before ``lo`` lie wholly
     before the K block's first GLOBAL column (dynamic); a K block wholly in
-    the padding visits none. ``shift`` is ``k_offset - q_offset``."""
+    the padding visits none. ``shift`` is ``k_offset - q_offset``. Where
+    the loop *ends* under a window: :func:`_q_band`."""
     hi = min(n_q, -(-q_len // block_q))            # static
     lo = 0
     if causal:
@@ -123,20 +150,74 @@ def _q_ranges(xp, pid_k, shift, n_q: int, block_q: int, block_k: int,
     return lo, hi
 
 
+def _q_band(xp, pid_k, block_q: int, block_k: int, window: int):
+    """For K block ``pid_k`` under a window, offsets zero: query blocks
+    from this one on lie wholly below the band of the block's LAST column
+    (column ``j`` is seen by rows below ``j + window``) and are skipped."""
+    return (pid_k * block_k + block_k + window - 2) // block_q + 1
+
+
+def _band_blocks(n_own: int, n_other: int, block_own: int, block_other: int,
+                 window: int | None, k_side: bool) -> int:
+    """How many blocks of the *other* operand a program's loop can visit
+    under the window (the forward and dQ: K blocks a query block; dK/dV:
+    query blocks a K block), offsets zero. A program is handed that many
+    blocks, not the sequence (``_held_k``, ``_held_q``); 0 where that is
+    no fewer than the sequence's, or there is no window: the sequence it
+    is."""
+    if window is None:
+        return 0
+    own = np.arange(n_own)
+    if k_side:      # K blocks [lo, hi) of query block ``own``
+        lo, _ = _k_band(np, own, block_own, block_other, window)
+        hi = (own * block_own + block_own - 1) // block_other + 1
+    else:           # query blocks [lo, hi) of K block ``own``
+        lo = own * block_own // block_other
+        hi = _q_band(np, own, block_other, block_own, window)
+    band = int((np.minimum(hi, n_other) - lo).max())
+    return band if band < n_other else 0
+
+
+def _held_k(pid_q, block_q: int, block_k: int, n_k: int, band: int):
+    """The first of the ``band`` K blocks query block ``pid_q`` holds: they
+    end with the block its last row lies in, moved where they would pass
+    either end of K. The block specs' index maps and the kernels compute it
+    alike."""
+    last = jnp.minimum((pid_q * block_q + block_q - 1) // block_k + 1, n_k)
+    return jnp.clip(last - band, 0, n_k - band)
+
+
+def _held_q(pid_k, block_q: int, block_k: int, n_q: int, band: int):
+    """The first of the ``band`` query blocks K block ``pid_k`` holds: they
+    start with the block its first column lies in."""
+    return jnp.clip(pid_k * block_k // block_q, 0, n_q - band)
+
+
 def tile_plan(t_q: int, t_k: int, kv_len: int, block_q: int, block_k: int,
-              causal: bool) -> dict:
+              causal: bool, window: int | None = None) -> dict:
     """Tiles of one (batch, head) of one kernel call, offsets zero, from the
-    loop bounds the kernels themselves use: ``skipped`` (never computed),
-    ``masked`` (computed; the diagonal or the end of the keys crosses them)
-    and ``unmasked`` (computed, wholly visible). At the decoder LM's shape
-    (4,096 causal tokens, 512-wide blocks): 28 unmasked, 8 masked, 28
-    skipped."""
+    loop bounds the kernels themselves use: ``skipped`` (never computed:
+    above the diagonal or in the padding), ``masked`` (computed; the
+    diagonal, the end of the keys or the band's lower edge crosses them)
+    and ``unmasked`` (computed, wholly visible); with a ``window`` also
+    ``below_band`` (never computed: wholly below the band). At the decoder
+    LM's shape (4,096 causal tokens, 512-wide blocks): 28 unmasked, 8
+    masked, 28 skipped. At 16,384 causal tokens, 512-wide blocks: 496 / 32 /
+    496 of 1,024, and under a window of 4,096: 196 / 56 / 496 with 276
+    below the band, 252 of the triangle's 528."""
     n_q, n_k = t_q // block_q, t_k // block_k
     n_full, hi = (np.broadcast_to(x, (n_q,)) for x in _k_ranges(
         np, np.arange(n_q), 0, n_k, block_q, block_k, kv_len, causal))
-    unmasked, visited = int(n_full.sum()), int(hi.sum())
+    plan = {}
+    lo = full_lo = np.zeros((n_q,), np.int64)
+    if window is not None:
+        lo, full_lo = _k_band(np, np.arange(n_q), block_q, block_k, window)
+        lo = np.minimum(lo, hi)
+        plan["below_band"] = int(lo.sum())
+    unmasked = int(np.maximum(n_full - np.maximum(full_lo, lo), 0).sum())
+    visited = int((hi - lo).sum())
     return {"unmasked": unmasked, "masked": visited - unmasked,
-            "skipped": n_q * n_k - visited}
+            "skipped": n_q * n_k - int(hi.sum()), **plan}
 
 
 def _count_tiles(programs: int, plan: dict) -> None:
@@ -149,13 +230,14 @@ def _count_tiles(programs: int, plan: dict) -> None:
 
 
 def _keep(shape, pos_ref, row0, col0, kv_len: int, pad_k: bool,
-          causal: bool):
+          causal: bool, window: int | None = None):
     """A tile's ``[BQ, BK]`` keep-mask (None where nothing can be masked):
     local columns under ``kv_len``, compared only where the keys are padded
     at all (``pad_k``, static), and under causal masking GLOBAL column <=
     GLOBAL row (``pos_ref`` holds (q_offset, k_offset), non-zero when the
-    call is one hop of a sharded ring). ``row0`` / ``col0``: the tile's
-    first local row / column."""
+    call is one hop of a sharded ring), under a ``window`` also GLOBAL row
+    - GLOBAL column < ``window``. ``row0`` / ``col0``: the tile's first
+    local row / column."""
     keep = None
     if pad_k or causal:
         col = col0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
@@ -164,7 +246,10 @@ def _keep(shape, pos_ref, row0, col0, kv_len: int, pad_k: bool,
     if causal:
         row_g = pos_ref[0, 0] + row0 \
             + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-        visible = pos_ref[0, 1] + col <= row_g
+        col_g = pos_ref[0, 1] + col
+        visible = col_g <= row_g
+        if window is not None:
+            visible &= row_g - col_g < window
         keep = visible if keep is None else keep & visible
     return keep
 
@@ -187,20 +272,42 @@ def _lanes(x, width: int):
 # file) were copied from spill slot to spill slot at every iteration's two
 # ends, 370-520 bundles of a 1,850-3,300 bundle tile in which the MXU stood
 # still (PERF.md section 6, PR 33).
+#
+# ``band`` (static; 0 without a window, or where the band is the sequence):
+# the blocks of the streamed operand the program holds. Its loop then runs
+# over GLOBAL block numbers as before and reads block ``i`` at row ``(i -
+# first resident block) * block`` of what it holds.
+
+def _k_loop(pos_ref, pid_q, n_k: int, block_q: int, block_k: int,
+            kv_len: int, causal: bool, window, band: int):
+    """``(lo, hi, row)`` of the forward's and dQ's K-block loop: it runs
+    ``[lo, hi)`` and ``row(i)`` is block ``i``'s first row in the K and V
+    the program holds."""
+    _n_full, hi = _k_ranges(jnp, pid_q, pos_ref[0, 0] - pos_ref[0, 1], n_k,
+                            block_q, block_k, kv_len, causal)
+    if window is None:
+        return 0, hi, lambda i: i * block_k
+    lo, _full_lo = _k_band(jnp, pid_q, block_q, block_k, window)
+    if not band:
+        return jnp.minimum(lo, hi), hi, lambda i: i * block_k
+    held = _held_k(pid_q, block_q, block_k, n_k, band)
+    return jnp.minimum(lo, hi), hi, lambda i: (i - held) * block_k
+
 
 def _fwd_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                 m_ref, l_ref, acc_ref, *,
-                scale: float, block_q: int, block_k: int, kv_len: int,
-                causal: bool):
+                scale: float, block_q: int, block_k: int, n_k: int,
+                kv_len: int, causal: bool, window=None, band: int = 0):
     import jax.experimental.pallas as pl  # noqa: F401 (pl.ds below)
 
     q = q_ref[0]                                   # [BQ, D]
-    n_k = k_ref.shape[1] // block_k
-    pad_k = kv_len < k_ref.shape[1]
+    pad_k = kv_len < n_k * block_k
     # program_id is read OUTSIDE the loop body: the interpret-mode lowering
     # can't substitute it inside fori_loop sub-jaxprs (and hoisting is free
     # on the TPU path).
     pid_q = pl.program_id(1)
+    lo, hi, row = _k_loop(pos_ref, pid_q, n_k, block_q, block_k, kv_len,
+                          causal, window, band)
     # Running max and sum a row as [BQ, 128]: the max with every lane of a
     # row equal, the sum as 128 partial sums a row (column c of a tile goes
     # to lane c % 128) that meet in one cross-lane sum after the loop. A
@@ -210,13 +317,14 @@ def _fwd_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)   # v's own width
 
     def tile(i, carry):
-        kb = k_ref[0, pl.ds(i * block_k, block_k), :]      # [BK, D]
-        vb = v_ref[0, pl.ds(i * block_k, block_k), :]
+        at = row(i)
+        kb = k_ref[0, pl.ds(at, block_k), :]               # [BK, D]
+        vb = v_ref[0, pl.ds(at, block_k), :]
         s = jax.lax.dot_general(
             q, kb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale    # [BQ, BK]
         keep = _keep(s.shape, pos_ref, pid_q * block_q, i * block_k,
-                     kv_len, pad_k, causal)
+                     kv_len, pad_k, causal, window)
         if keep is not None:
             s = jnp.where(keep, s, _NEG_INF)
         m = m_ref[...]
@@ -234,9 +342,7 @@ def _fwd_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         acc_ref[...] = acc_ref[...] * _lanes(alpha, pv.shape[1]) + pv
         return carry
 
-    _n_full, hi = _k_ranges(jnp, pid_q, pos_ref[0, 0] - pos_ref[0, 1], n_k,
-                            block_q, block_k, kv_len, causal)
-    jax.lax.fori_loop(0, hi, tile, 0)
+    jax.lax.fori_loop(lo, hi, tile, 0)
     l = jnp.maximum(jnp.sum(l_ref[...], axis=-1, keepdims=True), 1e-30)
     o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
     lse_ref[0] = m_ref[:, :1] + jnp.log(l)
@@ -244,28 +350,30 @@ def _fwd_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 def _bwd_dq_kernel(pos_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                    delta_ref, dq_ref, dq_acc, *,
-                   scale: float, block_q: int, block_k: int, kv_len: int,
-                   causal: bool):
+                   scale: float, block_q: int, block_k: int, n_k: int,
+                   kv_len: int, causal: bool, window=None, band: int = 0):
     import jax.experimental.pallas as pl  # noqa: F401
 
     q = q_ref[0]
     do = do_ref[0].astype(jnp.float32)
     lse = lse_ref[0]                                       # [BQ, 1]
     delta = delta_ref[0]
-    n_k = k_ref.shape[1] // block_k
-    pad_k = kv_len < k_ref.shape[1]
+    pad_k = kv_len < n_k * block_k
     pid_q = pl.program_id(1)       # hoisted: see _fwd_kernel
+    lo, hi, row = _k_loop(pos_ref, pid_q, n_k, block_q, block_k, kv_len,
+                          causal, window, band)
     dq_acc[...] = jnp.zeros(dq_acc.shape, jnp.float32)
 
     def tile(i, carry):
-        kb = k_ref[0, pl.ds(i * block_k, block_k), :]
-        vb = v_ref[0, pl.ds(i * block_k, block_k), :]
+        at = row(i)
+        kb = k_ref[0, pl.ds(at, block_k), :]
+        vb = v_ref[0, pl.ds(at, block_k), :]
         s = jax.lax.dot_general(
             q, kb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
         p = jnp.exp(s - lse)                               # [BQ, BK]
         keep = _keep(s.shape, pos_ref, pid_q * block_q, i * block_k,
-                     kv_len, pad_k, causal)
+                     kv_len, pad_k, causal, window)
         if keep is not None:
             p = jnp.where(keep, p, 0.0)
         dp = jax.lax.dot_general(
@@ -277,16 +385,15 @@ def _bwd_dq_kernel(pos_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             preferred_element_type=jnp.float32) * scale
         return carry
 
-    _n_full, hi = _k_ranges(jnp, pid_q, pos_ref[0, 0] - pos_ref[0, 1], n_k,
-                            block_q, block_k, kv_len, causal)
-    jax.lax.fori_loop(0, hi, tile, 0)
+    jax.lax.fori_loop(lo, hi, tile, 0)
     dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(pos_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
                     delta_ref, dk_ref, dv_ref, dk_acc, dv_acc, *,
-                    scale: float, block_q: int, kv_len: int, q_len: int,
-                    t_k: int, causal: bool):
+                    scale: float, block_q: int, n_q: int, kv_len: int,
+                    q_len: int, t_k: int, causal: bool, window=None,
+                    band: int = 0, group: int = 1):
     import jax.experimental.pallas as pl
 
     kb = k_ref[0]                                          # [BK, D]
@@ -294,20 +401,41 @@ def _bwd_dkv_kernel(pos_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
     bk = kb.shape[0]
     pid_k = pl.program_id(1)       # hoisted: see _fwd_kernel
     pad_k = kv_len < t_k
-    dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
-    dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
+    lo, hi = _q_ranges(
+        jnp, pid_k, pos_ref[0, 1] - pos_ref[0, 0], n_q, block_q, bk, kv_len,
+        q_len, t_k, causal)
+    if window is not None:
+        hi = jnp.clip(_q_band(jnp, pid_k, block_q, bk, window), lo, hi)
+    held = _held_q(pid_k, block_q, bk, n_q, band) if band else 0
+
+    def start():
+        dk_acc[...] = jnp.zeros(dk_acc.shape, jnp.float32)
+        dv_acc[...] = jnp.zeros(dv_acc.shape, jnp.float32)
+
+    def finish():
+        dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+
+    # a group's query heads are the grid's innermost axis: dK and dV sum
+    # over them in the scratch, which the first zeroes and the last writes
+    if group == 1:
+        start()
+    else:
+        member = pl.program_id(2)
+        pl.when(member == 0)(start)
 
     def tile(j, carry):
-        qb = q_ref[0, pl.ds(j * block_q, block_q), :]      # [BQ, D]
-        dob = do_ref[0, pl.ds(j * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(j * block_q, block_q), :]   # [BQ, 1]
-        delta = delta_ref[0, pl.ds(j * block_q, block_q), :]
+        at = (j - held) * block_q if band else j * block_q
+        qb = q_ref[0, pl.ds(at, block_q), :]               # [BQ, D]
+        dob = do_ref[0, pl.ds(at, block_q), :].astype(jnp.float32)
+        lse = lse_ref[0, pl.ds(at, block_q), :]            # [BQ, 1]
+        delta = delta_ref[0, pl.ds(at, block_q), :]
         s = jax.lax.dot_general(
             qb, kb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale    # [BQ, BK]
         p = jnp.exp(s - lse)
         keep = _keep(s.shape, pos_ref, j * block_q, pid_k * bk, kv_len,
-                     pad_k, causal)
+                     pad_k, causal, window)
         if keep is not None:
             p = jnp.where(keep, p, 0.0)
         dv_acc[...] += jax.lax.dot_general(
@@ -322,34 +450,35 @@ def _bwd_dkv_kernel(pos_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
             preferred_element_type=jnp.float32) * scale    # [BK, D]
         return carry
 
-    lo, hi = _q_ranges(
-        jnp, pid_k, pos_ref[0, 1] - pos_ref[0, 0], q_ref.shape[1] // block_q,
-        block_q, bk, kv_len, q_len, t_k, causal)
     jax.lax.fori_loop(lo, hi, tile, 0)
-    dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
-    dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
+    if group == 1:
+        finish()
+    else:
+        pl.when(member == group - 1)(finish)
 
 
 # -- jnp fallback (identical masked math, dense) ------------------------------
 
-def _position_mask(tq, tk, kv_len, causal, q_offset, k_offset):
+def _position_mask(tq, tk, kv_len, causal, q_offset, k_offset, window=None):
     """[Tq, Tk] keep-mask combining the kv_len bound with (optionally) the
     causal constraint in GLOBAL positions (offsets are nonzero when the
-    call is one hop of a sharded ring)."""
+    call is one hop of a sharded ring) and the window's."""
     keep = (jnp.arange(tk) < kv_len)[None, :]
     if causal:
         rows = q_offset + jnp.arange(tq)
         cols = k_offset + jnp.arange(tk)
         keep = keep & (cols[None, :] <= rows[:, None])
+        if window is not None:
+            keep = keep & (rows[:, None] - cols[None, :] < window)
     return keep
 
 
 def _dense_fwd(q, k, v, kv_len, scale, out_dtype=None,
-               causal=False, q_offset=0, k_offset=0):
+               causal=False, q_offset=0, k_offset=0, window=None):
     s = jnp.einsum("bqd,bkd->bqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     mask = _position_mask(q.shape[1], k.shape[1], kv_len, causal,
-                          q_offset, k_offset)
+                          q_offset, k_offset, window)
     s = jnp.where(mask[None], s, _NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.exp(s - m)
@@ -411,78 +540,160 @@ def _merge_heads(x, heads: int):
         0, 2, 1, 3).reshape(bh // heads, t, heads * w)
 
 
-def _head_block(rows, width: int, heads: int, whole: bool):
-    """The BlockSpec of one (batch, head)'s ``[rows, width]`` block for
-    grid ``(b*H + h, i)``: of a ``[B*H, T, width]`` array when ``heads`` is
-    0, else of a ``[B, T, H*width]`` array, the ``h``-th ``width`` lanes of
-    batch ``b`` (``width`` is whole 128-lane tiles there, so the block is
-    lane-aligned). ``whole``: all of T whatever ``i``."""
-    import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+class _Grid:
+    """One kernel call's grid and the block specs on it.
 
-    def index(g, i):
-        row = 0 if whole else i
-        return (g // heads, row, g % heads) if heads else (g, row, 0)
+    The grid is ``(key/value heads, blocks)`` and, where a key/value head
+    serves ``group`` > 1 query heads, ``(key/value heads, blocks, group)``:
+    the group innermost, so that consecutive programs share their K/V
+    blocks (fetched once) and the dK/dV kernel's sums. An index map is
+    written once, as a function of ``(kv, block, member)``; ``head`` says
+    whose blocks an operand's are (:meth:`query` head ``kv * group +
+    member``, or the key/value head itself)."""
 
-    return pl.BlockSpec((1, rows, width), index, memory_space=pltpu.VMEM)
+    def __init__(self, kv_programs: int, blocks: int, group: int):
+        self.group = group
+        self.grid = ((kv_programs, blocks) if group == 1
+                     else (kv_programs, blocks, group))
+
+    def query(self, kv, member):
+        return kv if self.group == 1 else kv * self.group + member
+
+    def block(self, rows: int, width: int, head, row, *, lanes_of: int = 0,
+              elements: bool = False):
+        """A ``[rows, width]`` block of program ``head(kv, member)`` at
+        ``row(block)``: of a ``[programs, T, width]`` array, or with
+        ``lanes_of`` = the heads a batch has there, of a ``[B, T, heads *
+        width]`` array that head's ``width`` lanes (whole 128-lane tiles,
+        so the block is lane-aligned). ``row`` gives a block number, or
+        with ``elements`` the first row itself (a band that starts where
+        its program needs it, not on a multiple of its length)."""
+        import jax.experimental.pallas as pl
+        from jax.experimental.pallas import tpu as pltpu
+
+        def index(kv, i, member=0):
+            p, at = head(kv, member), row(i)
+            if lanes_of:
+                lane = p % lanes_of
+                return (p // lanes_of, at, lane * width if elements else lane)
+            return (p, at, 0)
+
+        shape = (1, rows, width)
+        if elements:
+            shape = tuple(pl.Element(n) for n in shape)
+        return pl.BlockSpec(shape, index, memory_space=pltpu.VMEM)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _whole(_i):
+    return 0
+
+
+def _own(i):
+    return i
+
+
+def _kv(kv, _member):
+    return kv
+
+
+def _held_kv(g: _Grid, n_q: int, n_k: int, block_q: int, block_k: int,
+             d: int, dv: int, kv_heads: int, window):
+    """``(band, K's block spec, V's)`` of the forward and dQ: what a query
+    block's program holds of its key/value head, the whole of K and V
+    (``band`` 0) or under a window the ``band`` blocks that end with its
+    diagonal block (``_held_k``), wherever they start."""
+    band = _band_blocks(n_q, n_k, block_q, block_k, window, k_side=True)
+    if band:
+        rows = band * block_k
+
+        def row(i):
+            return _held_k(i, block_q, block_k, n_k, band) * block_k
+    else:
+        rows, row = n_k * block_k, _whole
+    return (band, g.block(rows, d, _kv, row, elements=bool(band)),
+            g.block(rows, dv, _kv, row, lanes_of=kv_heads,
+                    elements=bool(band)))
+
+
+def _repeat_group(x, group: int):
+    """``[B*G, T, D]`` -> ``[B*H, T, D]``: each key/value head once for
+    each query head of its group (the jnp fallback's view)."""
+    return jnp.repeat(x, group, axis=0) if group > 1 else x
+
+
+def _sum_group(x, group: int):
+    """The gradient's way back through ``_repeat_group``."""
+    if group == 1:
+        return x
+    return x.reshape(x.shape[0] // group, group, *x.shape[1:]).sum(axis=1)
+
+
+def _check_window(window, causal, *offsets) -> None:
+    if window is not None and not (causal and _static_zeros(*offsets)):
+        raise ValueError("a window needs causal attention and offsets that "
+                         "are zero as the call is traced")
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash_core(q, k, v, kv_len, block_q, block_k, use_pallas, causal,
-                v_heads=0):
+                v_heads=0, window=None):
     o, _ = _flash_fwd_impl(q, k, v, kv_len, block_q, block_k, use_pallas,
-                           causal=causal, v_heads=v_heads)
+                           causal=causal, v_heads=v_heads, window=window)
     return o
 
 
 def _flash_fwd_impl(q, k, v, kv_len, block_q, block_k, use_pallas,
                     out_dtype=None, causal=False, q_offset=0, k_offset=0,
-                    v_heads=0):
-    """``q``, ``k`` ``[B*H, T, D]``. ``v`` (and the ``o`` returned)
-    ``[B*H, T, Dv]`` or, with ``v_heads`` = H, ``[B, T, H*Dv]`` as a Dense
-    writes and reads them: the kernel bodies are the same, the block specs
-    pick head ``h``'s lanes of batch ``b``."""
+                    v_heads=0, window=None):
+    """``q`` ``[B*H, T, D]``, ``k`` ``[B*G, T, D]`` (G key/value heads a
+    batch, G dividing H). ``v`` ``[B*G, T, Dv]`` and the ``o`` returned
+    ``[B*H, T, Dv]`` or, with ``v_heads`` = H, ``[B, T, G*Dv]`` and ``[B,
+    T, H*Dv]`` as a Dense writes and reads them: the kernel bodies are the
+    same, the block specs pick a head's lanes of its batch."""
     bh, tp, d = q.shape
+    group = bh // k.shape[0]
+    kv_heads = v_heads // group
     # v (and o) may be narrower than q and k (MLA)
-    dv = v.shape[2] // v_heads if v_heads else v.shape[2]
+    dv = v.shape[2] // kv_heads if v_heads else v.shape[2]
     scale = 1.0 / np.sqrt(d)
+    _check_window(window, causal, q_offset, k_offset)
     if not use_pallas:
         # out_dtype reaches the FINAL cast — an intermediate round-trip
         # through q.dtype would quantize the fp32 partials the ring merge
         # depends on.
         if v_heads:
-            o, lse = _dense_fwd(q, k, _split_heads(v, v_heads), kv_len,
-                                scale, out_dtype, causal, q_offset, k_offset)
-            return _merge_heads(o, v_heads), lse
-        return _dense_fwd(q, k, v, kv_len, scale, out_dtype,
-                          causal, q_offset, k_offset)
+            v = _split_heads(v, kv_heads)
+        o, lse = _dense_fwd(q, _repeat_group(k, group),
+                            _repeat_group(v, group), kv_len, scale,
+                            out_dtype, causal, q_offset, k_offset, window)
+        return (_merge_heads(o, v_heads) if v_heads else o), lse
 
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     if _static_zeros(q_offset, k_offset):
         _count_tiles(bh, tile_plan(tp, k.shape[1], kv_len, block_q, block_k,
-                                   causal))
-    n_q = tp // block_q
+                                   causal, window))
+    n_q, n_k = tp // block_q, k.shape[1] // block_k
+    g = _Grid(k.shape[0], n_q, group)
+    band, blk_k, blk_v = _held_kv(g, n_q, n_k, block_q, block_k, d, dv,
+                                  kv_heads, window)
     blk_pos = pl.BlockSpec(memory_space=pltpu.SMEM)
-    blk_q = pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0),
-                         memory_space=pltpu.VMEM)
-    blk_o = _head_block(block_q, dv, v_heads, whole=False)
-    blk_kfull = pl.BlockSpec((1, tp, d), lambda b, i: (b, 0, 0),
-                             memory_space=pltpu.VMEM)
-    blk_vfull = _head_block(tp, dv, v_heads, whole=True)
+    blk_q = g.block(block_q, d, g.query, _own)
+    blk_o = g.block(block_q, dv, g.query, _own, lanes_of=v_heads)
     # LSE rides as [BH, T, 1]: a (1, BLOCK_Q, 1) block keeps the last
     # two dims tileable ((BLOCK_Q, 1): sublanes % 8 == 0, lane dim == array).
-    blk_lse = pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0),
-                           memory_space=pltpu.VMEM)
+    blk_lse = g.block(block_q, 1, g.query, _own)
     o, lse = pl.pallas_call(
         partial(_fwd_kernel, scale=scale, block_q=block_q, block_k=block_k,
-                kv_len=kv_len, causal=causal),
-        grid=(bh, n_q),
-        in_specs=[blk_pos, blk_q, blk_kfull, blk_vfull],
+                n_k=n_k, kv_len=kv_len, causal=causal, window=window,
+                band=band),
+        grid=g.grid,
+        in_specs=[blk_pos, blk_q, blk_k, blk_v],
         out_specs=(blk_o, blk_lse),
-        out_shape=(jax.ShapeDtypeStruct((v.shape[0], tp, v.shape[2]),
-                                        out_dtype or q.dtype),
+        out_shape=(jax.ShapeDtypeStruct(
+            (v.shape[0], tp, group * v.shape[2]) if v_heads
+            else (bh, tp, dv), out_dtype or q.dtype),
                    jax.ShapeDtypeStruct((bh, tp, 1), jnp.float32)),
         scratch_shapes=[pltpu.VMEM((block_q, _LANES), jnp.float32),
                         pltpu.VMEM((block_q, _LANES), jnp.float32),
@@ -494,16 +705,16 @@ def _flash_fwd_impl(q, k, v, kv_len, block_q, block_k, use_pallas,
 
 
 def _flash_core_fwd(q, k, v, kv_len, block_q, block_k, use_pallas, causal,
-                    v_heads=0):
+                    v_heads=0, window=None):
     o, lse = _flash_fwd_impl(q, k, v, kv_len, block_q, block_k, use_pallas,
-                             causal=causal, v_heads=v_heads)
+                             causal=causal, v_heads=v_heads, window=window)
     return o, (q, k, v, o, lse)
 
 
 def _flash_bwd_impl(q, k, v, do, lse, delta, kv_len, block_q, block_k,
                     use_pallas, out_dtype=None,
                     causal=False, q_offset=0, k_offset=0, q_len=None,
-                    v_heads=0):
+                    v_heads=0, window=None):
     """Flash backward given EXTERNAL (lse, delta) — shared by the custom
     VJP below and by ring attention's per-hop backward
     (parallel/ring_attention.py), where lse/delta come from the MERGED
@@ -511,32 +722,39 @@ def _flash_bwd_impl(q, k, v, do, lse, delta, kv_len, block_q, block_k,
     dtype (the ring accumulates partials in fp32). ``q_len`` is the
     UNPADDED query length (padded query rows carry zero dO/delta, so the
     dK/dV kernel skips those blocks); defaults to the padded length,
-    i.e. no skipping. ``v_heads`` = H: ``v``, ``do`` and the ``dv``
-    returned are ``[B, T, H*Dv]`` (``_flash_fwd_impl``)."""
+    i.e. no skipping. Shapes as ``_flash_fwd_impl``'s: ``do`` is ``o``'s,
+    the ``dk`` and ``dv`` returned are ``k``'s and ``v``'s, summed over
+    each key/value head's group of query heads."""
     bh, tq, d = q.shape
     tk = k.shape[1]
-    dv = v.shape[2] // v_heads if v_heads else v.shape[2]
+    group = bh // k.shape[0]
+    kv_heads = v_heads // group
+    dv = v.shape[2] // kv_heads if v_heads else v.shape[2]
     q_len = tq if q_len is None else q_len
     scale = 1.0 / np.sqrt(d)
     dts = [out_dtype or x.dtype for x in (q, k, v)]
+    _check_window(window, causal, q_offset, k_offset)
     if not use_pallas:
         if v_heads:
             dq, dk, dv = _flash_bwd_impl(
-                q, k, _split_heads(v, v_heads), _split_heads(do, v_heads),
+                q, k, _split_heads(v, kv_heads), _split_heads(do, v_heads),
                 lse, delta, kv_len, block_q, block_k, False, out_dtype,
-                causal, q_offset, k_offset, q_len)
-            return dq, dk, _merge_heads(dv, v_heads)
-        qf, kf, vf = (x.astype(jnp.float32) for x in (q, k, v))
+                causal, q_offset, k_offset, q_len, window=window)
+            return dq, dk, _merge_heads(dv, kv_heads)
+        qf, kf, vf = (x.astype(jnp.float32) for x in (
+            q, _repeat_group(k, group), _repeat_group(v, group)))
         dof = do.astype(jnp.float32)
         s = jnp.einsum("bqd,bkd->bqk", qf, kf) * scale
-        mask = _position_mask(tq, tk, kv_len, causal, q_offset, k_offset)
+        mask = _position_mask(tq, tk, kv_len, causal, q_offset, k_offset,
+                              window)
         p = jnp.where(mask[None], jnp.exp(s - lse), 0.0)
         dv = jnp.einsum("bqk,bqd->bkd", p, dof)
         dp = jnp.einsum("bqd,bkd->bqk", dof, vf)
         ds = p * (dp - delta)
         dq = jnp.einsum("bqk,bkd->bqd", ds, kf) * scale
         dk = jnp.einsum("bqk,bqd->bkd", ds, qf) * scale
-        return (dq.astype(dts[0]), dk.astype(dts[1]), dv.astype(dts[2]))
+        return (dq.astype(dts[0]), _sum_group(dk, group).astype(dts[1]),
+                _sum_group(dv, group).astype(dts[2]))
 
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -545,33 +763,25 @@ def _flash_bwd_impl(q, k, v, do, lse, delta, kv_len, block_q, block_k,
         # dQ visits every query block, dK/dV none that is all padded rows
         for rows in (tq, min(tq, -(-q_len // block_q) * block_q)):
             _count_tiles(bh, tile_plan(rows, tk, kv_len, block_q, block_k,
-                                       causal))
-    blk_q = pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0),
-                         memory_space=pltpu.VMEM)
-    blk_do = _head_block(block_q, dv, v_heads, whole=False)
-    blk_k = pl.BlockSpec((1, block_k, d), lambda b, i: (b, i, 0),
-                         memory_space=pltpu.VMEM)
-    blk_v = _head_block(block_k, dv, v_heads, whole=False)
-    blk_qfull = pl.BlockSpec((1, tq, d), lambda b, i: (b, 0, 0),
-                             memory_space=pltpu.VMEM)
-    blk_dofull = _head_block(tq, dv, v_heads, whole=True)
-    blk_kfull = pl.BlockSpec((1, tk, d), lambda b, i: (b, 0, 0),
-                             memory_space=pltpu.VMEM)
-    blk_vfull = _head_block(tk, dv, v_heads, whole=True)
-    blk_row_q = pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0),
-                             memory_space=pltpu.VMEM)
-    blk_row_qfull = pl.BlockSpec((1, tq, 1), lambda b, i: (b, 0, 0),
-                                 memory_space=pltpu.VMEM)
-
+                                       causal, window))
+    n_q, n_k = tq // block_q, tk // block_k
     blk_pos = pl.BlockSpec(memory_space=pltpu.SMEM)
     pos = _pos_scalars(q_offset, k_offset)
 
+    # -- dQ: a query block a program, K and V (or their band) held
+    g = _Grid(k.shape[0], n_q, group)
+    band, blk_kheld, blk_vheld = _held_kv(g, n_q, n_k, block_q, block_k, d,
+                                          dv, kv_heads, window)
+    blk_q = g.block(block_q, d, g.query, _own)
+    blk_row = g.block(block_q, 1, g.query, _own)
     dq = pl.pallas_call(
         partial(_bwd_dq_kernel, scale=scale, block_q=block_q,
-                block_k=block_k, kv_len=kv_len, causal=causal),
-        grid=(bh, tq // block_q),
-        in_specs=[blk_pos, blk_q, blk_kfull, blk_vfull, blk_do, blk_row_q,
-                  blk_row_q],
+                block_k=block_k, n_k=n_k, kv_len=kv_len, causal=causal,
+                window=window, band=band),
+        grid=g.grid,
+        in_specs=[blk_pos, blk_q, blk_kheld, blk_vheld,
+                  g.block(block_q, dv, g.query, _own, lanes_of=v_heads),
+                  blk_row, blk_row],
         out_specs=blk_q,
         out_shape=jax.ShapeDtypeStruct(q.shape, dts[0]),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
@@ -579,12 +789,29 @@ def _flash_bwd_impl(q, k, v, do, lse, delta, kv_len, block_q, block_k,
         interpret=INTERPRET, name="flash_attention_bwd_dq",
     )(pos, q, k, v, do, lse, delta)
 
+    # -- dK/dV: a K block a program, Q, dO, LSE and delta (or their band)
+    # held, one query head of the group after the other
+    g = _Grid(k.shape[0], n_k, group)
+    band = _band_blocks(n_k, n_q, block_k, block_q, window, k_side=False)
+
+    def held_q(j):
+        return _held_q(j, block_q, block_k, n_q, band) * block_q
+
+    rows, row = (band * block_q, held_q) if band else (tq, _whole)
+    blk_k = g.block(block_k, d, _kv, _own)
+    blk_v = g.block(block_k, dv, _kv, _own, lanes_of=kv_heads)
+    blk_rows = g.block(rows, 1, g.query, row, elements=bool(band))
     dk, dv = pl.pallas_call(
-        partial(_bwd_dkv_kernel, scale=scale, block_q=block_q,
-                kv_len=kv_len, q_len=q_len, t_k=tk, causal=causal),
-        grid=(bh, tk // block_k),
-        in_specs=[blk_pos, blk_qfull, blk_k, blk_v, blk_dofull,
-                  blk_row_qfull, blk_row_qfull],
+        partial(_bwd_dkv_kernel, scale=scale, block_q=block_q, n_q=n_q,
+                kv_len=kv_len, q_len=q_len, t_k=tk, causal=causal,
+                window=window, band=band, group=group),
+        grid=g.grid,
+        in_specs=[blk_pos,
+                  g.block(rows, d, g.query, row, elements=bool(band)),
+                  blk_k, blk_v,
+                  g.block(rows, dv, g.query, row, lanes_of=v_heads,
+                          elements=bool(band)),
+                  blk_rows, blk_rows],
         out_specs=(blk_k, blk_v),
         out_shape=(jax.ShapeDtypeStruct(k.shape, dts[1]),
                    jax.ShapeDtypeStruct(v.shape, dts[2])),
@@ -618,20 +845,19 @@ def _delta_of_heads(do, o, heads: int, block_q: int, use_pallas: bool):
             0, 2, 1).reshape(b * heads, t, 1)
 
     import jax.experimental.pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
-    blk = _head_block(block_q, dv, heads, whole=False)
+    g = _Grid(b * heads, t // block_q, 1)
+    blk = g.block(block_q, dv, _kv, _own, lanes_of=heads)
     return pl.pallas_call(
-        _delta_kernel, grid=(b * heads, t // block_q), in_specs=[blk, blk],
-        out_specs=pl.BlockSpec((1, block_q, 1), lambda g, i: (g, i, 0),
-                               memory_space=pltpu.VMEM),
+        _delta_kernel, grid=g.grid, in_specs=[blk, blk],
+        out_specs=g.block(block_q, 1, _kv, _own),
         out_shape=jax.ShapeDtypeStruct((b * heads, t, 1), jnp.float32),
         interpret=INTERPRET, name="flash_attention_bwd_delta",
     )(do, o)
 
 
 def _flash_core_bwd(kv_len, block_q, block_k, use_pallas, causal, v_heads,
-                    res, do):
+                    window, res, do):
     q, k, v, o, lse = res
     if v_heads:
         delta = _delta_of_heads(do, o, v_heads, block_q, use_pallas)
@@ -641,7 +867,7 @@ def _flash_core_bwd(kv_len, block_q, block_k, use_pallas, causal, v_heads,
     # Self-attention: q and k share the unpadded length, so q_len=kv_len.
     return _flash_bwd_impl(q, k, v, do, lse, delta, kv_len, block_q,
                            block_k, use_pallas, causal=causal,
-                           q_len=kv_len, v_heads=v_heads)
+                           q_len=kv_len, v_heads=v_heads, window=window)
 
 
 _flash_core.defvjp(_flash_core_fwd, _flash_core_bwd)
@@ -658,11 +884,11 @@ def _check_blocks(block_q, block_k) -> None:
 
 
 def _flash_heads_first(q3, k3, v3, block_q, block_k, use_pallas, causal,
-                       v_heads=0):
-    """``[B*H, T, D]`` x2 and ``[B*H, T, Dv]`` -> ``[B*H, T, Dv]`` (with
-    ``v_heads`` = H: ``[B, T, H*Dv]`` -> ``[B, T, H*Dv]``): the block
-    sizes, the padding of T and the core op, shared by both public entries
-    below."""
+                       v_heads=0, window=None):
+    """``[B*H, T, D]``, ``[B*G, T, D]`` and ``[B*G, T, Dv]`` -> ``[B*H, T,
+    Dv]`` (with ``v_heads`` = H: ``[B, T, G*Dv]`` -> ``[B, T, H*Dv]``): the
+    block sizes, the padding of T and the core op, shared by both public
+    entries below."""
     t = q3.shape[1]
     # Default blocks: the largest 128-multiple <= MAX_BLOCK that DIVIDES the
     # 128-rounded sequence length — a bare min() would pad e.g. T=768 up to
@@ -682,7 +908,7 @@ def _flash_heads_first(q3, k3, v3, block_q, block_k, use_pallas, causal,
         return jnp.pad(x, ((0, 0), (0, tp - t), (0, 0))) if tp != t else x
 
     o3 = _flash_core(pad(q3), pad(k3), pad(v3), t, block_q, block_k,
-                     bool(use_pallas), bool(causal), v_heads)
+                     bool(use_pallas), bool(causal), v_heads, window)
     return o3[:, :t] if tp != t else o3
 
 
@@ -690,11 +916,14 @@ def flash_attention_heads_major(q: jax.Array, k: jax.Array, v: jax.Array, *,
                                 causal: bool = False,
                                 block_q: int | None = None,
                                 block_k: int | None = None,
-                                use_pallas: bool = True) -> jax.Array:
+                                use_pallas: bool = True,
+                                window: int | None = None) -> jax.Array:
     """Fused attention for a caller that projects per head: q and k
-    heads-major ``[B, H, T, D]``, ``v`` as its Dense writes it, ``[B, T,
-    H*Dv]``, and ``o`` returned as the output Dense reads it, ``[B, T,
-    H*Dv]``.
+    heads-major ``[B, H, T, D]`` and ``[B, G, T, D]`` (G key/value heads, G
+    dividing H: query head ``h`` reads head ``h // (H/G)``), ``v`` as its
+    Dense writes it, ``[B, T, G*Dv]``, and ``o`` returned as the output
+    Dense reads it, ``[B, T, H*Dv]``. ``window``: a causal row sees itself
+    and the ``window - 1`` positions before it.
 
     The kernels read ``[B*H, T, D]``, which is heads-major q/k with the two
     leading axes taken as one, and (``Dv`` whole 128-lane tiles) head
@@ -708,27 +937,31 @@ def flash_attention_heads_major(q: jax.Array, k: jax.Array, v: jax.Array, *,
     ``use_pallas=False`` is the kernel-identical jnp fallback the CPU tests
     compare with."""
     b, h, t, d = q.shape
-    dv = v.shape[-1] // h
+    kv_heads = k.shape[1]
+    dv = v.shape[-1] // kv_heads
     _check_blocks(block_q, block_k)
-    q3, k3 = q.reshape(b * h, t, d), k.reshape(b * h, t, d)
+    q3, k3 = q.reshape(b * h, t, d), k.reshape(b * kv_heads, t, d)
     if dv % 128:
-        o3 = _flash_heads_first(q3, k3, _split_heads(v, h), block_q, block_k,
-                                use_pallas, causal)
+        o3 = _flash_heads_first(q3, k3, _split_heads(v, kv_heads), block_q,
+                                block_k, use_pallas, causal, window=window)
         return _merge_heads(o3, h).astype(q.dtype)
     return _flash_heads_first(q3, k3, v, block_q, block_k, use_pallas,
-                              causal, v_heads=h).astype(q.dtype)
+                              causal, v_heads=h,
+                              window=window).astype(q.dtype)
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     causal: bool = False,
                     block_q: int | None = None,
                     block_k: int | None = None,
-                    use_pallas: bool | None = None) -> jax.Array:
-    """Fused attention over ``[B, T, H, D]`` q/k/v (causal optional): the
-    layout a Dense writes. The kernels read heads-major ``[B*H, T, D]``, so
-    this entry transposes q, k and v on the way in and ``o`` on the way
-    out; a caller that can hold ``[B, H, T, D]`` uses
-    ``flash_attention_heads_major`` and moves nothing.
+                    use_pallas: bool | None = None,
+                    window: int | None = None) -> jax.Array:
+    """Fused attention over ``[B, T, H, D]`` q/k/v (causal optional; k and
+    v may hold fewer heads, ``[B, T, G, D]``; ``window`` as
+    ``flash_attention_heads_major``'s): the layout a Dense writes. The
+    kernels read heads-major ``[B*H, T, D]``, so this entry transposes q, k
+    and v on the way in and ``o`` on the way out; a caller that can hold
+    ``[B, H, T, D]`` uses ``flash_attention_heads_major`` and moves nothing.
 
     Same contract as parallel/ring_attention.dense_attention — plug into
     models/vit.py:SelfAttention via ``attention_fn=flash_attention`` (or
@@ -751,15 +984,17 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     _check_blocks(block_q, block_k)
     if use_pallas is None:
         from .. import attention
-        if attention.core_for_separate_qkv(causal, q.dtype, t, h, d,
-                                           v.shape[-1]) == "dense":
-            return attention.dense_core(q, k, v, causal=causal)
+        if attention.core_for_separate_qkv(
+                causal, q.dtype, t, h, d, v.shape[-1], window=window,
+                num_kv_heads=k.shape[2]) == "dense":
+            return attention.dense_core(q, k, v, causal=causal,
+                                        window=window)
         use_pallas = True
 
     def to3(x):
-        return jnp.transpose(x, (0, 2, 1, 3)).reshape(b * h, t, x.shape[-1])
+        return jnp.transpose(x, (0, 2, 1, 3)).reshape(-1, t, x.shape[-1])
 
     o3 = _flash_heads_first(to3(q), to3(k), to3(v), block_q, block_k,
-                            use_pallas, causal)
+                            use_pallas, causal, window=window)
     o = o3.reshape(b, h, t, v.shape[-1])
     return jnp.transpose(o, (0, 2, 1, 3)).astype(q.dtype)

@@ -9,7 +9,9 @@ down): top-k over the router's whole published width, many experts a chip,
 the layer *told which experts it holds*, no token dropped whatever the
 imbalance. It returns the partial sum the held experts give; on one chip it
 runs without its exchange (the other shares' partial sums live on the chips
-that hold them). The decoder LM (models/joyai.py) runs it.
+that hold them). The decoder LMs run it: models/joyai.py (sigmoid scores
+and a balancing bias, SwiGLU experts) and models/smallthinker.py (a softmax
+over the chosen logits, :func:`route_top_k_softmax`, ReLU-gated experts).
 
 The Switch layer: net-new capability (the reference has no MoE — SURVEY.md §2 checklist, EP
 row). One expert per mesh slot; each device routes its resident tokens,
@@ -200,6 +202,16 @@ def route_top_k(scores: jax.Array, bias: jax.Array, k: int, *,
     return idx.astype(jnp.int32), weights * scaling
 
 
+def route_top_k_softmax(logits: jax.Array, k: int):
+    """Choose the ``k`` largest of a token's router ``logits`` ``[N, E]``
+    (float32, the router's whole published width) and weigh them by a
+    softmax over those ``k`` logits alone: ``(idx [N, k] int32, weights [N,
+    k] float32)``, the weights of a token summing to 1. No bias steers the
+    choice."""
+    top, idx = jax.lax.top_k(logits, k)
+    return idx.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
+
+
 def expert_loads(idx: jax.Array, n_experts: int) -> jax.Array:
     """``[E]`` int32: assignments each of the ``E`` experts was given."""
     return jnp.zeros((n_experts,), jnp.int32).at[idx.reshape(-1)].add(1)
@@ -244,14 +256,19 @@ def _pass_rows(p, order, starts, ends, total, rows: int):
     return at, valid, group.at[-1].add(rows - jnp.sum(group))
 
 
-def _pass_out(rows_x, rows_w, experts, group, valid):
-    """The pass's rows through their experts' SwiGLU, weighted: three
+#: the gate's activation, by the name :func:`held_expert_ffn` takes
+GATE_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def _pass_out(rows_x, rows_w, experts, group, valid, activation: str):
+    """The pass's rows through their experts' gated unit, weighted: three
     grouped matmuls (``jax.lax.ragged_dot``; on the TPU XLA's own grouped
     kernel, which visits only the tiles a group fills)."""
     with jax.named_scope("moe_experts"):
         wg, wu, wd = (experts[name].astype(rows_x.dtype)
                       for name in ("gate", "up", "down"))
-        hidden = (jax.nn.silu(jax.lax.ragged_dot(rows_x, wg, group))
+        gate = GATE_ACTIVATIONS[activation]
+        hidden = (gate(jax.lax.ragged_dot(rows_x, wg, group))
                   * jax.lax.ragged_dot(rows_x, wu, group))
         out = jax.lax.ragged_dot(hidden, wd, group)
     # rows past the last group's end hold whatever the kernel left
@@ -263,18 +280,77 @@ def _passes(total, rows: int, min_passes: int):
     return jnp.maximum(-(-total // rows), min_passes)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9))
+#: how a token's rows are summed (``held_expert_ffn(combine=...)``)
+COMBINES = ("scatter", "gather")
+
+
+class _TokenSums:
+    """Where the passes' rows go to be summed a token: ``[N, D]`` float32
+    sums of the rows' ``[rows, D]`` results, row ``i`` of pass ``p`` being
+    assignment ``order[p * rows + i]`` of token ``order[...] // k``.
+
+    ``scatter``: each pass adds its rows into the sums (a read-modify-write
+    of a float32 row a row: only the sums and one pass's rows are live).
+    ``gather``: each pass writes its rows where they stand in the sorted
+    list, a ``[N * k, D]`` buffer in the rows' dtype, and at the end every
+    token gathers its ``k`` rows from where the sort put them and sums
+    them. On a TPU a scatter-add costs by the distinct rows it touches
+    (7.9 ms for 12,288 distinct rows of 2,560, 3.1 ms where four in a row
+    are one token's, as a pass's slack rows are; PERF.md, PR 34), so its
+    time follows the routing, and a gather does not (1.0 ms either way);
+    the price is the buffer, 0.5 GB at 16,384 tokens x 6."""
+
+    def __init__(self, combine: str, n: int, k: int, rows: int,
+                 passes: int, order):
+        if combine not in COMBINES:
+            raise ValueError(f"combine={combine!r}: one of {COMBINES}")
+        self.gather, self.n, self.k, self.rows = (combine == "gather", n, k,
+                                                  rows)
+        self.slots = max(n * k, passes * rows)
+        self.order = order
+
+    def zeros(self, d: int, dtype):
+        return (jnp.zeros((self.slots, d), dtype) if self.gather
+                else jnp.zeros((self.n, d), jnp.float32))
+
+    def add(self, sums, p, tokens, part):
+        if self.gather:
+            return jax.lax.dynamic_update_slice(
+                sums, part.astype(sums.dtype), (p * self.rows, 0))
+        return sums.at[tokens].add(part.astype(jnp.float32))
+
+    def done(self, sums):
+        if not self.gather:
+            return sums
+        n_k = self.n * self.k
+        # where the sort put assignment a = token * k + j
+        at = jnp.zeros((n_k,), jnp.int32).at[self.order[:n_k]].set(
+            jnp.arange(n_k, dtype=jnp.int32))
+        return jnp.sum(sums[at].reshape(self.n, self.k, -1).astype(
+            jnp.float32), axis=1)
+
+
+def _most_passes(n: int, k: int, held: int, rows: int, min_passes: int):
+    """The most passes the loop can run (``_passes``' largest value)."""
+    return max(min_passes, -(-n * min(k, held) // rows))
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11))
 def _work_off(x, flat_weights, experts, order, starts, ends, total,
-              k: int, rows: int, min_passes: int):
+              k: int, rows: int, min_passes: int, activation: str,
+              combine: str):
     """``(y [N, D] float32, processed)``: the sorted list worked off
     ``rows`` at a time, ``min_passes`` passes or as many as the list is long
     (a loop with a dynamic trip count, hence the hand-written backward pass
     below: the same loop, each pass's forward computed again and
     differentiated by ``jax.vjp``), and the assignments the passes computed,
     counted pass by pass: a trip count that stops short shows as fewer than
-    ``total``. Only one pass's rows and the ``[N, D]`` sums are live,
-    whatever the imbalance, and a pass beyond both costs nothing."""
+    ``total``. Under ``combine="scatter"`` only one pass's rows and the
+    ``[N, D]`` sums are live, whatever the imbalance (``_TokenSums``), and a
+    pass beyond both costs nothing."""
     n, d = x.shape
+    sums = _TokenSums(combine, n, k, rows, _most_passes(
+        n, k, starts.shape[0], rows, min_passes), order)
 
     def one_pass(p, carry):
         y, processed = carry
@@ -283,28 +359,34 @@ def _work_off(x, flat_weights, experts, order, starts, ends, total,
             tokens = at // k
             rows_x = jnp.where(valid[:, None], x[tokens], 0)
             rows_w = flat_weights[at]
-        part = _pass_out(rows_x, rows_w, experts, group, valid)
+        part = _pass_out(rows_x, rows_w, experts, group, valid, activation)
         with jax.named_scope("moe_route"):
-            return (y.at[tokens].add(part.astype(jnp.float32)),
+            return (sums.add(y, p, tokens, part),
                     processed + jnp.sum(valid, dtype=jnp.int32))
 
-    return jax.lax.fori_loop(0, _passes(total, rows, min_passes), one_pass,
-                             (jnp.zeros((n, d), jnp.float32), jnp.int32(0)))
+    y, processed = jax.lax.fori_loop(
+        0, _passes(total, rows, min_passes), one_pass,
+        (sums.zeros(d, x.dtype), jnp.int32(0)))
+    with jax.named_scope("moe_route"):
+        return sums.done(y), processed
 
 
 def _work_off_fwd(x, flat_weights, experts, order, starts, ends, total,
-                  k, rows, min_passes):
+                  k, rows, min_passes, activation, combine):
     out = _work_off(x, flat_weights, experts, order, starts, ends, total,
-                    k, rows, min_passes)
+                    k, rows, min_passes, activation, combine)
     return out, (x, flat_weights, experts, order, starts, ends, total)
 
 
-def _work_off_bwd(k, rows, min_passes, residuals, cotangents):
+def _work_off_bwd(k, rows, min_passes, activation, combine, residuals,
+                  cotangents):
     x, flat_weights, experts, order, starts, ends, total = residuals
     dy, _ = cotangents          # the count takes none
+    sums = _TokenSums(combine, x.shape[0], k, rows, _most_passes(
+        x.shape[0], k, starts.shape[0], rows, min_passes), order)
 
-    def one_pass(p, sums):
-        dx, dw, de = sums
+    def one_pass(p, carry):
+        dx, dw, de = carry
         at, valid, group = _pass_rows(p, order, starts, ends, total, rows)
         with jax.named_scope("moe_route"):
             tokens = at // k
@@ -312,19 +394,22 @@ def _work_off_bwd(k, rows, min_passes, residuals, cotangents):
             rows_w = flat_weights[at]
             d_part = dy[tokens].astype(x.dtype)
         _out, vjp = jax.vjp(
-            lambda rx, rw, ex: _pass_out(rx, rw, ex, group, valid),
+            lambda rx, rw, ex: _pass_out(rx, rw, ex, group, valid,
+                                         activation),
             rows_x, rows_w, experts)
         d_rows_x, d_rows_w, d_experts = vjp(d_part)
         with jax.named_scope("moe_route"):
-            dx = dx.at[tokens].add(jnp.where(
-                valid[:, None], d_rows_x, 0).astype(jnp.float32))
+            dx = sums.add(dx, p, tokens,
+                          jnp.where(valid[:, None], d_rows_x, 0))
             dw = dw.at[at].add(jnp.where(valid, d_rows_w, 0))
         return dx, dw, jax.tree_util.tree_map(jnp.add, de, d_experts)
 
     dx, dw, de = jax.lax.fori_loop(
         0, _passes(total, rows, min_passes), one_pass,
-        (jnp.zeros(x.shape, jnp.float32), jnp.zeros_like(flat_weights),
+        (sums.zeros(x.shape[1], x.dtype), jnp.zeros_like(flat_weights),
          jax.tree_util.tree_map(jnp.zeros_like, experts)))
+    with jax.named_scope("moe_route"):
+        dx = sums.done(dx)
     return dx.astype(x.dtype), dw, de, None, None, None, None
 
 
@@ -333,13 +418,16 @@ _work_off.defvjp(_work_off_fwd, _work_off_bwd)
 
 def held_expert_ffn(x: jax.Array, idx: jax.Array, weights: jax.Array,
                     experts: dict, first: int, *, rows: int,
-                    min_passes: int = 1):
+                    min_passes: int = 1, activation: str = "silu",
+                    combine: str = "scatter"):
     """The held experts' part of a top-k expert layer's output.
 
     ``x`` ``[N, D]`` tokens, ``idx`` / ``weights`` ``[N, k]`` from
-    :func:`route_top_k`, ``experts`` the stacked SwiGLU weights of the
-    experts held here (``gate`` / ``up`` ``[C, D, F]``, ``down``
-    ``[C, F, D]``), which are the experts ``first .. first + C - 1`` of the
+    :func:`route_top_k` (or :func:`route_top_k_softmax`), ``experts`` the
+    stacked weights of the gated experts held here (``gate`` / ``up`` ``[C,
+    D, F]``, ``down`` ``[C, F, D]``; an expert is ``down(activation(gate u)
+    * (up u))``, ``activation`` a key of ``GATE_ACTIVATIONS``: SwiGLU by
+    default), which are the experts ``first .. first + C - 1`` of the
     router's numbering. Returns ``(y [N, D], processed)``: for each token the
     weighted sum over the held experts among its ``k``, zero for a token that
     chose none of them, and the number of assignments the passes computed,
@@ -353,6 +441,10 @@ def held_expert_ffn(x: jax.Array, idx: jax.Array, weights: jax.Array,
     tokens' sums. As many passes run as the list needs, found at run time,
     so only the worst case pays for the worst case; ``min_passes`` is the
     floor a stated capacity sets (:func:`pass_plan`), 1 without one.
+    ``combine`` (``COMBINES``) says how the rows' results reach the tokens'
+    sums: added into them pass by pass (``scatter``, the default: least
+    memory) or written where the sort put them and gathered at the end
+    (``gather``: a time that does not follow the routing; ``_TokenSums``).
     """
     k, c = idx.shape[1], experts["gate"].shape[0]
     with jax.named_scope("moe_route"):
@@ -366,5 +458,6 @@ def held_expert_ffn(x: jax.Array, idx: jax.Array, weights: jax.Array,
         # the last pass, or a capacity's floor, may reach past the end
         order = jnp.pad(order, (0, rows * min_passes))
     y, processed = _work_off(x, weights.reshape(-1), experts, order, starts,
-                             ends, total, k, rows, min_passes)
+                             ends, total, k, rows, min_passes, activation,
+                             combine)
     return y.astype(x.dtype), processed

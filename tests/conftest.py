@@ -53,3 +53,25 @@ def small_batch():
     images = r.integers(0, 255, (16, 32, 32, 3), dtype=np.uint8)
     labels = (np.arange(16) % 10).astype(np.int32)
     return images, labels
+
+
+#: a test of an accepted benchmark file that a later PR's cell makes false
+#: and that PR may not edit (files under BENCHMARK.json's ``paths`` change
+#: in ``benchmark`` PRs alone) -> why, and which test holds what is still
+#: true of it
+OVERTAKEN = {
+    "tests/benchmark/test_bench_lm_cell.py::"
+    "test_the_five_accepted_entries_list_the_image_cells_and_no_other":
+        "its last line pins BENCHMARK.json to four cells ([1, 1, 4, 1] "
+        "chips); PR 34 added the fifth. Everything else it asserts is held "
+        "by tests/benchmark/test_bench_smallthinker_cell.py::"
+        "test_the_accepted_entries_list_the_cells_they_listed; a benchmark "
+        "PR should drop the line",
+}
+
+
+def pytest_collection_modifyitems(config, items):
+    for item in items:
+        reason = OVERTAKEN.get(item.nodeid)
+        if reason:
+            item.add_marker(pytest.mark.xfail(reason=reason, strict=True))

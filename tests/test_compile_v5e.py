@@ -252,3 +252,76 @@ def test_lm_evaluation_fits_beside_the_state(lm_programs):
     # five blocks: the accuracy is the main head's, so the MTP module's
     # block is dead code in this program
     assert len(re.findall(r"%flash_attention_fwd(?:\.\d+)? = ", text)) == 5
+
+
+# -- the window model's step: 16,384 tokens, a band, grouped queries ------------
+
+@pytest.fixture(scope="module")
+def smallthinker_step(topo):
+    """The SmallThinker cell's step program (``worker_step`` with the LM
+    task, the ``ep4`` preset, 1 sequence of 16,384 tokens, bf16, AdamW)
+    compiled for one described v5e; under a minute."""
+    from distributed_parameter_server_for_ml_training_tpu.parallel.sync_dp \
+        import make_sync_dp_step
+    from distributed_parameter_server_for_ml_training_tpu.train.distributed \
+        import DistributedConfig
+    from distributed_parameter_server_for_ml_training_tpu.train.tasks import (
+        LMTask)
+
+    class Data:
+        vocab_size, seq_len = 37984, 16384
+
+    was_tpu, at._on_tpu = at._on_tpu, lambda: True
+    was_cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+        replicated, split = (NamedSharding(mesh, P()),
+                             NamedSharding(mesh, P("data")))
+        task = LMTask("ep4")
+        cfg = DistributedConfig(model="smallthinker", learning_rate=3e-6)
+        model = task.make_model(cfg, Data, jnp.bfloat16, "data")
+        state = jax.eval_shape(lambda: task.init_state(
+            model, jax.random.PRNGKey(0), task.make_optimizer(cfg), Data))
+        state = jax.tree_util.tree_map(
+            lambda x: _shaped(x.shape, x.dtype, replicated), state)
+        step = make_sync_dp_step(mesh, compression="none", task=task).lower(
+            state, _shaped((1, 16386), jnp.int32, split),
+            _shaped((2,), jnp.uint32, replicated)).compile()
+    finally:
+        at._on_tpu = was_tpu
+        jax.config.update("jax_enable_compilation_cache", was_cache)
+    return state, step
+
+
+def test_smallthinker_step_fits_one_chip_with_its_band_in_vmem(
+        smallthinker_step):
+    """What interpret mode cannot show: the three kernels with a window's
+    element-indexed band blocks, a group's 7 query heads an inner grid axis
+    and, on the global layer, 16,384 rows of K and V (dK/dV: of Q, dO and
+    the two row statistics) in VMEM compile for the chip, and the step fits
+    its memory."""
+    state, step = smallthinker_step
+    n = sum(int(np.prod(x.shape))
+            for x in jax.tree_util.tree_leaves(state.params))
+    assert n == 656_529_920
+    memory = step.memory_analysis()
+    assert memory.argument_size_in_bytes >= 12 * n
+    assert memory.alias_size_in_bytes >= 12 * n
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < LM_MEMORY_LIMIT), memory
+    text = step.as_text()
+    # no score matrix, of a whole layer or of a band
+    assert not re.search(r"\[[\d,]*16384,16384\]", text)
+    assert not re.search(r"\[[\d,]*16384,4608\]", text)
+    # four blocks: 8 forward runs with the recomputation, 4 and 4 backward
+    for kernel, calls in (("flash_attention_fwd", 8),
+                          ("flash_attention_bwd_dq", 4),
+                          ("flash_attention_bwd_dkv", 4),
+                          ("flash_attention_bwd_delta", 4)):
+        assert len(re.findall(rf"%{kernel}(?:\.\d+)? = ", text)) == calls
+    assert re.search(r"%ragged-dot", text)
+    # the scopes the cell's readers go by
+    for scope in ("attn_window", "attn_full", "moe_route", "moe_experts",
+                  "head_loss", "update"):
+        assert f"/{scope}/" in text, scope

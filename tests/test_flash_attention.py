@@ -125,23 +125,40 @@ def _value_and_grads(fn, q, k, v, cot):
         argnums=(0, 1, 2))(q, k, v)
 
 
-@pytest.mark.parametrize("door,dtype,t,causal,block_q,block_k", [
-    ("bthd", jnp.float32, 512, True, 128, 128),
-    ("bthd", jnp.float32, 512, False, 128, 128),
-    ("bthd", jnp.float32, 600, True, 128, 128),     # padded to 640: the
-    ("bthd", jnp.float32, 600, False, 128, 128),    # last K block is masked
-    ("bthd", jnp.float32, 512, True, 256, 128),
-    ("bthd", jnp.float32, 512, True, 128, 256),
-    ("bthd", jnp.float32, 600, True, 256, 128),     # 768: a K block all pad
-    ("bthd", jnp.float32, 600, False, 128, 256),
-    ("bthd", jnp.bfloat16, 512, True, 128, 128),
-    ("bthd", jnp.bfloat16, 600, False, 128, 128),
-    ("heads_major", jnp.float32, 512, True, 128, 128),
-    ("heads_major", jnp.bfloat16, 600, True, 128, 128),
-    ("heads_major", jnp.bfloat16, 512, True, 256, 128),
+#: (window, query heads, key/value heads) of the cases before there were any
+PLAIN = (None, 2, 2)
+
+
+@pytest.mark.parametrize("door,dtype,t,causal,block_q,block_k,grouping", [
+    ("bthd", jnp.float32, 512, True, 128, 128, PLAIN),
+    ("bthd", jnp.float32, 512, False, 128, 128, PLAIN),
+    ("bthd", jnp.float32, 600, True, 128, 128, PLAIN),    # padded to 640: the
+    ("bthd", jnp.float32, 600, False, 128, 128, PLAIN),   # last K block is masked
+    ("bthd", jnp.float32, 512, True, 256, 128, PLAIN),
+    ("bthd", jnp.float32, 512, True, 128, 256, PLAIN),
+    ("bthd", jnp.float32, 600, True, 256, 128, PLAIN),    # 768: a K block all pad
+    ("bthd", jnp.float32, 600, False, 128, 256, PLAIN),
+    ("bthd", jnp.bfloat16, 512, True, 128, 128, PLAIN),
+    ("bthd", jnp.bfloat16, 600, False, 128, 128, PLAIN),
+    ("heads_major", jnp.float32, 512, True, 128, 128, PLAIN),
+    ("heads_major", jnp.bfloat16, 600, True, 128, 128, PLAIN),
+    ("heads_major", jnp.bfloat16, 512, True, 256, 128, PLAIN),
+    # a window x grouped queries. 256: whole blocks; 200 and 130: the band's
+    # lower edge inside a block (at 130 no tile is wholly visible); each
+    # program holds a band of its operand, not the sequence
+    # (``_band_blocks``: 3 of 4 blocks at 512 tokens and a window of 200)
+    ("bthd", jnp.float32, 512, True, 128, 128, (256, 4, 2)),
+    ("bthd", jnp.float32, 512, True, 128, 128, (200, 4, 2)),
+    ("heads_major", jnp.float32, 512, True, 128, 128, (200, 4, 1)),
+    ("heads_major", jnp.bfloat16, 512, True, 128, 128, (200, 6, 2)),
+    ("bthd", jnp.float32, 600, True, 128, 256, (200, 6, 2)),   # and padding
+    ("bthd", jnp.float32, 768, True, 256, 128, (130, 2, 2)),   # window alone
+    ("heads_major", jnp.float32, 640, True, 128, 128, (130, 4, 2)),
+    ("heads_major", jnp.float32, 512, True, 128, 128, (None, 4, 2)),  # groups
+    ("bthd", jnp.float32, 512, False, 128, 128, (None, 6, 3)),        # alone
 ])
 def test_kernels_sum_over_several_tiles(door, dtype, t, causal, block_q,
-                                        block_k, monkeypatch):
+                                        block_k, grouping, monkeypatch):
     """Several tiles a program (interpret mode; the cases above are one
     tile a program): every kernel sums into its VMEM scratch over wholly
     visible tiles, tiles the diagonal crosses and, at 600 tokens, a last K
@@ -151,28 +168,39 @@ def test_kernels_sum_over_several_tiles(door, dtype, t, causal, block_q,
     import distributed_parameter_server_for_ml_training_tpu.ops.pallas.flash_attention as fa
 
     monkeypatch.setattr(fa, "INTERPRET", True)
+    from distributed_parameter_server_for_ml_training_tpu.ops.attention import (
+        dense_core)
+    window, heads, kv_heads = grouping
     plan = fa.tile_plan(-(-t // block_q) * block_q, -(-t // block_k) * block_k,
-                        t, block_q, block_k, causal)
-    assert plan["unmasked"] and (plan["masked"] or not (causal or t % 128))
+                        t, block_q, block_k, causal, window)
+    assert plan["unmasked"] or window is not None
+    assert plan["masked"] or not (causal or t % 128)
+    assert window is None or plan["below_band"]
     d, dv = (192, 128) if door == "heads_major" else (64, 64)
     ks = jax.random.split(jax.random.PRNGKey(t + block_q), 4)
-    q, k = (jax.random.normal(x, (1, t, 2, d), dtype) for x in ks[:2])
-    v, cot = (jax.random.normal(x, (1, t, 2, dv), dtype) for x in ks[2:])
+    q = jax.random.normal(ks[0], (1, t, heads, d), dtype)
+    k = jax.random.normal(ks[1], (1, t, kv_heads, d), dtype)
+    v = jax.random.normal(ks[2], (1, t, kv_heads, dv), dtype)
+    cot = jax.random.normal(ks[3], (1, t, heads, dv), dtype)
+    extra = {} if window is None else {"window": window}
 
     def kernels(q, k, v):
         if door == "bthd":
             return flash_attention(q, k, v, causal=causal, use_pallas=True,
-                                   block_q=block_q, block_k=block_k)
+                                   block_q=block_q, block_k=block_k, **extra)
         return fa.flash_attention_heads_major(
             *_heads_major(q, k, v), causal=causal, block_q=block_q,
-            block_k=block_k).reshape(v.shape)
+            block_k=block_k, **extra).reshape(cot.shape)
 
     def plain(q, k, v):
-        return _dense_f32(*(x.astype(jnp.float32) for x in (q, k, v)),
-                          causal)
+        q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+        if grouping == PLAIN:
+            return _dense_f32(q, k, v, causal)
+        # the CPU path's core with the same mask, in float32
+        return dense_core(q, k, v, causal=causal, window=window)
 
     out = kernels(q, k, v)
-    assert out.dtype == dtype and out.shape == v.shape
+    assert out.dtype == dtype and out.shape == cot.shape
     fwd_tol, bwd_tol = (2e-3, 5e-3) if dtype == jnp.float32 else (3e-2, 3e-2)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(plain(q, k, v)),
@@ -196,6 +224,31 @@ def test_tile_plan_at_the_decoder_lms_shape():
         "unmasked": 28, "masked": 8, "skipped": 28}
     assert fa.tile_plan(4096, 4096, 4096, 512, 512, False) == {
         "unmasked": 64, "masked": 0, "skipped": 0}
+    # a window as long as the sequence skips nothing more
+    assert fa.tile_plan(4096, 4096, 4096, 512, 512, True, 4096) == {
+        "unmasked": 28, "masked": 8, "skipped": 28, "below_band": 0}
+
+
+def test_tile_plan_for_a_band_at_the_window_models_shape():
+    """16,384 causal tokens in 512-wide blocks: 528 tiles in the triangle;
+    under a window of 4,096 a query block visits its own block, the 7 before
+    it and the one the band's lower edge crosses: 36 + 24 x 9 = 252, with 24
+    + 32 of them masked, and holds 9 of the 32 K blocks (dK/dV: 9 of the 32
+    query blocks)."""
+    import distributed_parameter_server_for_ml_training_tpu.ops.pallas.flash_attention as fa
+
+    assert fa.tile_plan(16384, 16384, 16384, 512, 512, True) == {
+        "unmasked": 496, "masked": 32, "skipped": 496}
+    assert fa.tile_plan(16384, 16384, 16384, 512, 512, True, 4096) == {
+        "unmasked": 196, "masked": 56, "skipped": 496, "below_band": 276}
+    assert fa._band_blocks(32, 32, 512, 512, 4096, k_side=True) == 9
+    assert fa._band_blocks(32, 32, 512, 512, 4096, k_side=False) == 9
+    assert fa._band_blocks(32, 32, 512, 512, None, k_side=True) == 0
+    # two positions more and a query block's first row sees into a tenth
+    assert fa._band_blocks(32, 32, 512, 512, 4097, k_side=True) == 9
+    assert fa._band_blocks(32, 32, 512, 512, 4098, k_side=True) == 10
+    # at 4,096 tokens a window of 4,096 is the sequence: nothing is cut
+    assert fa._band_blocks(8, 8, 512, 512, 4096, k_side=True) == 0
 
 
 @pytest.mark.parametrize("causal", [True, False])
@@ -230,6 +283,56 @@ def test_loop_bounds_agree_with_the_mask(t_pad, kv_len, block_q, block_k,
         for j in range(n_k):
             assert (j < hi[i]) == some[i, j] == (lo[j] <= i < hi_q[j])
             assert (j < n_full[i]) == every[i, j]
+
+
+@pytest.mark.parametrize("window", [130, 200, 256, 300, 512])
+@pytest.mark.parametrize("t_pad,kv_len,block_q,block_k", [
+    (512, 512, 128, 128), (640, 600, 128, 128), (768, 600, 256, 128),
+    (768, 600, 128, 256), (1024, 1024, 256, 128), (1024, 1000, 128, 256),
+    (1024, 520, 512, 256), (768, 512, 384, 256)])
+def test_loop_bounds_under_a_window_agree_with_the_mask(t_pad, kv_len,
+                                                        block_q, block_k,
+                                                        window):
+    """As ``test_loop_bounds_agree_with_the_mask``, causal with a window:
+    below the band a tile is skipped exactly when the mask keeps none of
+    it, the four kinds of ``tile_plan`` are a count over the dense mask,
+    and the blocks a program holds (``_held_k``, ``_held_q`` and
+    ``_band_blocks``) cover every tile its loop visits."""
+    import distributed_parameter_server_for_ml_training_tpu.ops.pallas.flash_attention as fa
+
+    n_q, n_k = t_pad // block_q, t_pad // block_k
+    mask = np.asarray(fa._position_mask(
+        t_pad, t_pad, kv_len, True, 0, 0, window)).reshape(
+            n_q, block_q, n_k, block_k)
+    causal = np.asarray(fa._position_mask(
+        t_pad, t_pad, kv_len, True, 0, 0)).reshape(n_q, block_q, n_k, block_k)
+    every, some = mask.all((1, 3)), mask.any((1, 3))
+    in_triangle = causal.any((1, 3))
+    want = {"unmasked": int(every.sum()),
+            "masked": int((some & ~every).sum()),
+            "skipped": int((~in_triangle).sum()),
+            "below_band": int((in_triangle & ~some).sum())}
+    assert fa.tile_plan(t_pad, t_pad, kv_len, block_q, block_k, True,
+                        window) == want
+    _n_full, hi = (np.broadcast_to(x, (n_q,)) for x in fa._k_ranges(
+        np, np.arange(n_q), 0, n_k, block_q, block_k, kv_len, True))
+    lo, _full_lo = fa._k_band(np, np.arange(n_q), block_q, block_k, window)
+    lo_q, hi_q = (np.broadcast_to(x, (n_k,)) for x in fa._q_ranges(
+        np, np.arange(n_k), 0, n_q, block_q, block_k, kv_len, t_pad, t_pad,
+        True))
+    hi_q = np.clip(fa._q_band(np, np.arange(n_k), block_q, block_k, window),
+                   lo_q, hi_q)
+    band_k = fa._band_blocks(n_q, n_k, block_q, block_k, window, True) or n_k
+    band_q = fa._band_blocks(n_k, n_q, block_k, block_q, window, False) or n_q
+    for i in range(n_q):
+        held = int(fa._held_k(i, block_q, block_k, n_k, band_k))
+        for j in range(n_k):
+            visited = min(lo[i], hi[i]) <= j < hi[i]
+            assert visited == some[i, j] == (lo_q[j] <= i < hi_q[j]), (i, j)
+            if visited:
+                assert held <= j < held + band_k
+                held_q = int(fa._held_q(j, block_q, block_k, n_q, band_q))
+                assert held_q <= i < held_q + band_q
 
 
 def test_tracing_latent_attention_counts_its_tiles(monkeypatch):
@@ -370,7 +473,8 @@ def test_the_bthd_door_asks_the_same_rule(on_tpu, t, want, monkeypatch):
                           num_heads=2, head_dim=64) == want
     kernels = []
 
-    def heads_first(q3, k3, v3, block_q, block_k, use_pallas, causal):
+    def heads_first(q3, k3, v3, block_q, block_k, use_pallas, causal,
+                    window=None):
         kernels.append(use_pallas)
         return jnp.zeros_like(q3)
 
@@ -463,3 +567,118 @@ def test_heads_attention_core_counts_and_computes(monkeypatch):
         np.asarray(out),
         np.asarray(_dense_f32(q, k, v, True)).reshape(1, 40, 2 * 16),
         atol=1e-5)
+
+
+# -- a window, grouped queries: the rule, the CPU core, the counters -----------
+
+@pytest.mark.parametrize("t,causal,window,kv_heads,want", [
+    (16384, True, 4096, 4, "flash"),      # the window model's window layers
+    (16384, True, None, 4, "flash"),      # and its global layer
+    (4096, True, 4096, 28, "flash"),
+    (197, False, None, 12, "fused_short"),
+    (197, False, None, 4, "dense"),       # the short kernel has no groups
+    (1000, True, 512, 4, "dense"),        # not whole tiles
+])
+def test_select_core_takes_the_window_and_the_key_value_heads(t, causal,
+                                                              window,
+                                                              kv_heads, want):
+    from distributed_parameter_server_for_ml_training_tpu.ops import (
+        attention as at)
+    heads, width = (12, 64) if t == 197 else (28, 128)
+    assert at.select_core(on_tpu=True, causal=causal, dtype=jnp.bfloat16,
+                          t=t, num_heads=heads, head_dim=width,
+                          window=window, num_kv_heads=kv_heads) == want
+    assert at.select_core(on_tpu=False, causal=causal, dtype=jnp.bfloat16,
+                          t=t, num_heads=heads, head_dim=width,
+                          window=window, num_kv_heads=kv_heads) == "dense"
+
+
+@pytest.mark.parametrize("heads_major", [False, True])
+@pytest.mark.parametrize("window,kv_heads", [(None, 2), (5, 4), (5, 2),
+                                             (1, 1), (40, 2)])
+def test_dense_core_masks_the_band_and_repeats_the_key_value_heads(
+        window, kv_heads, heads_major):
+    """``dense_core`` against a loop over rows: row ``i`` of query head
+    ``h`` is a softmax over columns ``i - window < j <= i`` of key/value
+    head ``h // (H/G)``."""
+    from distributed_parameter_server_for_ml_training_tpu.ops import (
+        attention as at)
+    t, heads, d = 12, 4, 8
+    ks = jax.random.split(jax.random.PRNGKey(window or 0), 3)
+    q = jax.random.normal(ks[0], (1, t, heads, d))
+    k, v = (jax.random.normal(x, (1, t, kv_heads, d)) for x in ks[1:])
+    args = [x.transpose(0, 2, 1, 3) for x in (q, k, v)] if heads_major \
+        else [q, k, v]
+    out = at.dense_core(*args, causal=True, window=window,
+                        heads_major=heads_major)
+    out = out.transpose(0, 2, 1, 3) if heads_major else out
+    for h in range(heads):
+        g = h // (heads // kv_heads)
+        for i in range(t):
+            first = 0 if window is None else max(0, i - window + 1)
+            s = (k[0, first:i + 1, g] @ q[0, i, h]) / np.sqrt(d)
+            want = jax.nn.softmax(s) @ v[0, first:i + 1, g]
+            np.testing.assert_allclose(np.asarray(out[0, i, h]),
+                                       np.asarray(want), atol=1e-5)
+    with pytest.raises(ValueError, match="causal"):
+        at.dense_core(*args, causal=False, window=4, heads_major=heads_major)
+
+
+def test_a_window_needs_causal_attention_and_static_offsets():
+    import distributed_parameter_server_for_ml_training_tpu.ops.pallas.flash_attention as fa
+    q, k, v = _qkv(1, 128, 1, 64)
+    with pytest.raises(ValueError, match="window"):
+        flash_attention(q, k, v, causal=False, window=64, use_pallas=False)
+    q3 = q.reshape(1, 128, 64)
+    with pytest.raises(ValueError, match="window"):
+        fa._flash_fwd_impl(q3, q3, q3, 128, 128, 128, False, causal=True,
+                           q_offset=jnp.int32(0), window=64)
+    out = flash_attention(q, k, v, causal=True, window=64, use_pallas=False)
+    from distributed_parameter_server_for_ml_training_tpu.ops.attention import (
+        dense_core)
+    np.testing.assert_allclose(
+        np.asarray(out), np.asarray(dense_core(q, k, v, causal=True,
+                                               window=64)), atol=1e-5)
+
+
+def test_tracing_window_attention_counts_its_core_and_its_tiles(monkeypatch):
+    """The window model's tiny attention at 1,024 bf16 tokens, told it is
+    on a TPU: a window layer (window 300) and the global layer each trace
+    the three kernels over 4 query heads of 2 x 2 tiles (no tile lies
+    wholly below a band of 300, but its lower edge crosses the one tile
+    under the diagonal, which is then masked), counted under ``impl=flash``
+    with the layer's ``window`` and ``group``."""
+    from distributed_parameter_server_for_ml_training_tpu.models import (
+        smallthinker)
+    from distributed_parameter_server_for_ml_training_tpu.ops import (
+        attention as at)
+    from distributed_parameter_server_for_ml_training_tpu.telemetry import (
+        get_registry)
+    from dataclasses import replace
+
+    monkeypatch.setattr(at, "_on_tpu", lambda: True)
+    cfg = replace(smallthinker.PRESETS["tiny"], sliding_window_size=300,
+                  head_dim=128)
+    reg = get_registry()
+    kinds = {kind: reg.counter("dps_flash_tiles_total", kind=kind)
+             for kind in ("unmasked", "masked", "skipped", "below_band")}
+    cores = {layer: reg.counter("dps_attention_core_total", impl="flash",
+                                group="2", **extra)
+             for layer, extra in ((0, {}), (1, {"window": "300"}))}
+    u = jax.ShapeDtypeStruct((1, 1024, cfg.hidden_size), jnp.bfloat16)
+    for layer in (0, 1):
+        attn = smallthinker.GroupedAttention(
+            cfg, jnp.bfloat16, rope=bool(cfg.rope_layout[layer]),
+            window=cfg.window(layer))
+        params = jax.eval_shape(attn.init, jax.random.PRNGKey(0), u)
+        before = {kind: c.value for kind, c in kinds.items()}
+        traced = cores[layer].value
+        jax.eval_shape(jax.grad(lambda p, u: jnp.sum(
+            attn.apply(p, u).astype(jnp.float32))), params, u)
+        assert cores[layer].value == traced + 1
+        heads = cfg.num_attention_heads
+        got = {kind: c.value - before[kind] for kind, c in kinds.items()}
+        under = 0 if layer else 1       # the tile under the diagonal
+        assert got == {"unmasked": 3 * heads * under,
+                       "masked": 3 * heads * (3 - under),
+                       "skipped": 3 * heads * 1, "below_band": 0}

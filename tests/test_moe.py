@@ -199,3 +199,132 @@ def test_dp_ep_trainer_smoke(devices):
     assert metrics["moe_dp_degree"] == 2
     assert metrics["n_experts"] == 4
     assert "moe_load_imbalance" in metrics
+
+
+# -- the held-experts layer's gate and the softmax router ----------------------
+
+def _held_layer_inputs(n=96, d=16, f=8, e=8, seed=0):
+    r = np.random.default_rng(seed)
+    x = jnp.asarray(r.normal(size=(n, d)), jnp.float32)
+    logits = jnp.asarray(r.normal(size=(n, e)), jnp.float32)
+    experts = {name: jnp.asarray(r.normal(size=shape) * 0.3, jnp.float32)
+               for name, shape in (("gate", (e, d, f)), ("up", (e, d, f)),
+                                   ("down", (e, f, d)))}
+    return x, logits, experts
+
+
+@pytest.mark.parametrize("k", [1, 2, 6])
+def test_softmax_of_the_top_k_against_a_loop(k):
+    """``route_top_k_softmax``: a token's ``k`` largest logits, weighted by
+    a softmax over those ``k`` alone."""
+    from distributed_parameter_server_for_ml_training_tpu.parallel import moe
+    _x, logits, _experts = _held_layer_inputs()
+    idx, weights = moe.route_top_k_softmax(logits, k)
+    assert idx.shape == weights.shape == (96, k) and idx.dtype == jnp.int32
+    for token in range(0, 96, 7):
+        row = np.asarray(logits[token])
+        chosen = np.argsort(-row)[:k]
+        assert sorted(np.asarray(idx[token]).tolist()) == sorted(
+            chosen.tolist())
+        e = np.exp(row[chosen] - row[chosen].max())
+        want = dict(zip(chosen.tolist(), (e / e.sum()).tolist()))
+        for j, w in zip(np.asarray(idx[token]).tolist(),
+                        np.asarray(weights[token]).tolist()):
+            assert abs(w - want[j]) < 1e-6
+    np.testing.assert_allclose(np.asarray(weights.sum(axis=1)), 1.0,
+                               atol=1e-6)
+    # the weights take gradient, and a shift of a token's logits none
+    g = jax.grad(lambda l: jnp.sum(
+        moe.route_top_k_softmax(l, k)[1][:, 0]))(logits)
+    assert (k == 1) == (float(jnp.abs(g).max()) == 0.0)
+
+
+@pytest.mark.parametrize("activation", ["relu", "silu"])
+def test_the_gates_activation_against_a_loop(activation):
+    """``held_expert_ffn(activation=...)``: each held expert on the tokens
+    that chose it, ``down(act(gate u) * (up u))``, forward and gradients,
+    with experts 2..5 of 8 held."""
+    from distributed_parameter_server_for_ml_training_tpu.parallel import moe
+    x, logits, experts = _held_layer_inputs()
+    idx, weights = moe.route_top_k_softmax(logits, 2)
+    first, count = 2, 4
+    held = {name: w[first:first + count] for name, w in experts.items()}
+    act = {"relu": jax.nn.relu, "silu": jax.nn.silu}[activation]
+
+    def layer(x, held, weights):
+        return moe.held_expert_ffn(x, idx, weights, held, first, rows=32,
+                                   activation=activation)
+
+    def loop(x, held, weights):
+        out = jnp.zeros_like(x)
+        for c in range(count):
+            w = jnp.sum(jnp.where(idx == first + c, weights, 0.0), axis=1)
+            out = out + w[:, None] * (
+                (act(x @ held["gate"][c]) * (x @ held["up"][c]))
+                @ held["down"][c])
+        return out
+
+    y, processed = layer(x, held, weights)
+    loads = moe.expert_loads(idx, 8)
+    assert int(processed) == int(loads[first:first + count].sum())
+    np.testing.assert_allclose(np.asarray(y),
+                               np.asarray(loop(x, held, weights)), atol=1e-5)
+    got = jax.grad(lambda *a: jnp.sum(layer(*a)[0] ** 2),
+                   argnums=(0, 1, 2))(x, held, weights)
+    want = jax.grad(lambda *a: jnp.sum(loop(*a) ** 2),
+                    argnums=(0, 1, 2))(x, held, weights)
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5,
+                                   rtol=1e-4)
+    # the default is the gate it always had
+    default, _ = moe.held_expert_ffn(x, idx, weights, held, first, rows=32)
+    assert (activation == "silu") == bool(jnp.allclose(default, y))
+    with pytest.raises(KeyError):
+        moe.held_expert_ffn(x, idx, weights, held, first, rows=32,
+                            activation="gelu")
+
+
+def test_the_window_models_pass_plan():
+    """16,384 tokens, 6 experts a token, 16 of 64 held: an even load of
+    24,576 assignments in passes of 12,288 rows; the cell's stated capacity
+    of 4.0 always runs all eight (98,304 rows: every assignment the
+    sequence can give the held experts, and the deployment's even load)."""
+    from distributed_parameter_server_for_ml_training_tpu.parallel import moe
+    assert moe.pass_plan(16384, 6, 16, 64) == (12288, 1)
+    assert moe.pass_plan(16384, 6, 16, 64, 4.0) == (12288, 8)
+    assert moe.pass_plan(16384, 6, 16, 64, 9.0) == (12288, 8)
+
+
+@pytest.mark.parametrize("min_passes", [1, 5])
+def test_the_gather_combine_is_the_scatter_combine(min_passes):
+    """``combine="gather"`` (a pass writes its rows where the sort put them,
+    a token gathers its k rows at the end) against the default (a pass adds
+    its rows into the tokens' sums): output, the count and every gradient,
+    with fewer passes than the stated floor and with more."""
+    from distributed_parameter_server_for_ml_training_tpu.parallel import moe
+    x, logits, experts = _held_layer_inputs()
+    idx, weights = moe.route_top_k_softmax(logits, 3)
+    held = {name: w[1:6] for name, w in experts.items()}
+
+    def layer(combine):
+        def f(x, held, weights):
+            y, done = moe.held_expert_ffn(
+                x, idx, weights, held, 1, rows=32, min_passes=min_passes,
+                activation="relu", combine=combine)
+            return jnp.sum(y ** 2), (y, done)
+        return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)(
+            x, held, weights)
+
+    (_l, (y, done)), grads = layer("gather")
+    (_l2, (want, want_done)), want_grads = layer("scatter")
+    assert int(done) == int(want_done) == int(
+        moe.expert_loads(idx, 8)[1:6].sum())
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(grads),
+                    jax.tree_util.tree_leaves(want_grads)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5,
+                                   rtol=1e-4)
+    with pytest.raises(ValueError, match="combine"):
+        moe.held_expert_ffn(x, idx, weights, held, 1, rows=32,
+                            combine="sort")
