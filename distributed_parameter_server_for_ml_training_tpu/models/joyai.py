@@ -81,6 +81,13 @@ class JoyAIConfig:
     # the even load (parallel/moe.py:pass_plan); 0: what the routing needs
     expert_capacity_factor: float = 0.0
 
+    def pass_plan(self, n_tokens: int):
+        """``(rows, min_passes)`` of an expert layer over ``n_tokens``
+        tokens (parallel/moe.py:pass_plan)."""
+        return moe.pass_plan(
+            n_tokens, self.num_experts_per_tok, self.held_experts[1],
+            self.n_routed_experts, self.expert_capacity_factor)
+
     @property
     def expert_layers(self) -> int:
         """Expert layers that read a router bias: the main model's and the
@@ -345,8 +352,7 @@ class ExpertLayer(nn.Module):
                 scaling=cfg.routed_scaling_factor,
                 normalize=cfg.norm_topk_prob)
             loads = moe.expert_loads(idx, e)
-        rows, min_passes = moe.pass_plan(b * t, cfg.num_experts_per_tok,
-                                         held, e, cfg.expert_capacity_factor)
+        rows, min_passes = cfg.pass_plan(b * t)
         routed, processed = moe.held_expert_ffn(
             x, idx, weights, experts, first, rows=rows,
             min_passes=min_passes)

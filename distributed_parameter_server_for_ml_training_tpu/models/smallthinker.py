@@ -82,6 +82,13 @@ class SmallThinkerConfig:
     def n_routed_experts(self) -> int:
         return self.moe_num_primary_experts
 
+    def pass_plan(self, n_tokens: int):
+        """``(rows, min_passes)`` of an expert layer over ``n_tokens``
+        tokens (parallel/moe.py:pass_plan)."""
+        return moe.pass_plan(
+            n_tokens, self.moe_num_active_primary_experts, self.held_experts[1],
+            self.moe_num_primary_experts, self.expert_capacity_factor)
+
     @property
     def expert_layers(self) -> int:
         return self.num_hidden_layers
@@ -205,8 +212,7 @@ class ExpertLayer(nn.Module):
                              precision=jax.lax.Precision.HIGHEST)
             idx, weights = moe.route_top_k_softmax(logits, k)
             loads = moe.expert_loads(idx, e)
-        rows, min_passes = moe.pass_plan(b * t, k, held, e,
-                                         cfg.expert_capacity_factor)
+        rows, min_passes = cfg.pass_plan(b * t)
         routed, processed = moe.held_expert_ffn(
             u.reshape(b * t, d).astype(dt), idx, weights, experts, first,
             rows=rows, min_passes=min_passes, activation="relu",

@@ -260,6 +260,18 @@ def _pass_rows(p, order, starts, ends, total, rows: int):
 GATE_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
 
 
+def _gated(rows_x, wg, wu, group):
+    """``(gate u, up u)`` of the pass's rows, each by its own expert."""
+    return (jax.lax.ragged_dot(rows_x, wg, group),
+            jax.lax.ragged_dot(rows_x, wu, group))
+
+
+def _weighted(out, rows_w, valid):
+    # rows past the last group's end hold whatever the kernel left
+    return jnp.where(valid[:, None], out, 0) * rows_w[:, None].astype(
+        out.dtype)
+
+
 def _pass_out(rows_x, rows_w, experts, group, valid, activation: str):
     """The pass's rows through their experts' gated unit, weighted: three
     grouped matmuls (``jax.lax.ragged_dot``; on the TPU XLA's own grouped
@@ -267,13 +279,76 @@ def _pass_out(rows_x, rows_w, experts, group, valid, activation: str):
     with jax.named_scope("moe_experts"):
         wg, wu, wd = (experts[name].astype(rows_x.dtype)
                       for name in ("gate", "up", "down"))
-        gate = GATE_ACTIVATIONS[activation]
-        hidden = (gate(jax.lax.ragged_dot(rows_x, wg, group))
-                  * jax.lax.ragged_dot(rows_x, wu, group))
+        gate_u, up_u = _gated(rows_x, wg, wu, group)
+        hidden = GATE_ACTIVATIONS[activation](gate_u) * up_u
         out = jax.lax.ragged_dot(hidden, wd, group)
-    # rows past the last group's end hold whatever the kernel left
-    return jnp.where(valid[:, None], out, 0) * rows_w[:, None].astype(
-        rows_x.dtype)
+    return _weighted(out, rows_w, valid)
+
+
+def _grad_accumulate_impl(rows: int, d: int, f: int) -> str:
+    """Which way a layer's passes add their weight gradients to the carry,
+    chosen from the backend and the shapes alone and counted in
+    ``dps_moe_grad_accumulate_total{impl}`` at trace time: ``in_place``
+    (ops/pallas/grouped_grad.py: summed in float32 into the slices of the
+    experts a pass has rows of, the carry aliased in and out) on a TPU where
+    the kernel has tiles for the shapes, else ``xla`` (``ragged_dot_general``
+    into float32, then the whole carry read, added to and written)."""
+    from ..ops import attention
+    from ..ops.pallas import grouped_grad
+    from ..telemetry import get_registry
+    # whether there are tiles is the same for [d, f] and the down
+    # projection's [f, d]
+    impl = ("in_place" if attention._on_tpu()
+            and grouped_grad.pick_blocks(rows, d, f) else "xla")
+    get_registry().counter("dps_moe_grad_accumulate_total", impl=impl).inc()
+    return impl
+
+
+def _add_weight_grads(acc, lhs, rhs, group, impl: str):
+    """``acc [C, K, N]`` float32 with ``lhs[rows of c]^T @ rhs[rows of c]``
+    added to each expert ``c``'s slice: the products of the rows' dtype
+    summed in float32, nothing rounded before it is added."""
+    if impl == "in_place":
+        from ..ops.pallas.grouped_grad import grouped_grad_accumulate
+        return grouped_grad_accumulate(acc, lhs, rhs, group)
+    return acc + jax.lax.ragged_dot_general(
+        lhs, rhs, group, jax.lax.RaggedDotDimensionNumbers(
+            dot_dimension_numbers=(((0,), (0,)), ((), ())),
+            lhs_ragged_dimensions=[0], rhs_group_dimensions=[]),
+        preferred_element_type=jnp.float32)
+
+
+def _pass_grads(rows_x, rows_w, experts, group, valid, activation: str,
+                d_part, de, impl: str):
+    """The backward pass of :func:`_pass_out` for the cotangent ``d_part``
+    of its result: ``(d_rows_x, d_rows_w, de)``, ``de`` being the carried
+    float32 gradients of ``experts`` with this pass's added
+    (:func:`_add_weight_grads`). The forward is computed again stage by
+    stage and each stage's inputs differentiated by ``jax.vjp`` with the
+    weights held constant; the three weight gradients are the one thing not
+    left to it."""
+    with jax.named_scope("moe_experts"):
+        wg, wu, wd = (experts[name].astype(rows_x.dtype)
+                      for name in ("gate", "up", "down"))
+        (gate_u, up_u), vjp_in = jax.vjp(
+            lambda rx: _gated(rx, wg, wu, group), rows_x)
+        hidden, vjp_unit = jax.vjp(
+            lambda g, u: GATE_ACTIVATIONS[activation](g) * u, gate_u, up_u)
+        out, vjp_down = jax.vjp(
+            lambda h: jax.lax.ragged_dot(h, wd, group), hidden)
+    _part, vjp_weigh = jax.vjp(lambda o, rw: _weighted(o, rw, valid), out,
+                               rows_w)
+    d_out, d_rows_w = vjp_weigh(d_part)
+    with jax.named_scope("moe_experts"):
+        (d_hidden,) = vjp_down(d_out)
+        d_gate_u, d_up_u = vjp_unit(d_hidden)
+        (d_rows_x,) = vjp_in((d_gate_u, d_up_u))
+        de = {"gate": _add_weight_grads(de["gate"], rows_x, d_gate_u, group,
+                                        impl),
+              "up": _add_weight_grads(de["up"], rows_x, d_up_u, group, impl),
+              "down": _add_weight_grads(de["down"], hidden, d_out, group,
+                                        impl)}
+    return d_rows_x, d_rows_w, de
 
 
 def _passes(total, rows: int, min_passes: int):
@@ -335,6 +410,29 @@ def _most_passes(n: int, k: int, held: int, rows: int, min_passes: int):
     return max(min_passes, -(-n * min(k, held) // rows))
 
 
+def grad_visits(sizes: jax.Array, rows: int, min_passes: int):
+    """``(visits, passes)``, int32: the (pass, expert) pairs of a layer's
+    backward pass whose slice of the carried weight gradients is read and
+    written (:func:`_add_weight_grads` in place), out of ``passes x C``
+    that a whole-carry add touches, and the passes the loop runs. ``sizes``
+    ``[C]`` are the held experts' loads, as :func:`held_expert_ffn` sorts
+    them: expert ``c``'s rows lie in ``floor((end - 1) / rows) - floor(start
+    / rows) + 1`` passes, and the last held expert is also visited in every
+    pass that has slack rows. ``passes <= visits <= C + passes - 1``."""
+    sizes = sizes.astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    starts, total = ends - sizes, ends[-1]
+    passes = _passes(total, rows, min_passes)
+    own = jnp.sum(jnp.where(sizes > 0,
+                            (ends - 1) // rows - starts // rows + 1, 0))
+    # passes from the one the list ends in (or just before) hold slack; the
+    # last expert's own rows reach into the first of them unless the list
+    # ends on a pass's edge
+    slack = passes - total // rows
+    shared = (sizes[-1] > 0) & (total % rows != 0)
+    return own + slack - shared.astype(jnp.int32), passes
+
+
 @partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11))
 def _work_off(x, flat_weights, experts, order, starts, ends, total,
               k: int, rows: int, min_passes: int, activation: str,
@@ -343,7 +441,8 @@ def _work_off(x, flat_weights, experts, order, starts, ends, total,
     ``rows`` at a time, ``min_passes`` passes or as many as the list is long
     (a loop with a dynamic trip count, hence the hand-written backward pass
     below: the same loop, each pass's forward computed again and
-    differentiated by ``jax.vjp``), and the assignments the passes computed,
+    differentiated, its weight gradients added to their float32 sums in
+    place: ``_pass_grads``), and the assignments the passes computed,
     counted pass by pass: a trip count that stops short shows as fewer than
     ``total``. Under ``combine="scatter"`` only one pass's rows and the
     ``[N, D]`` sums are live, whatever the imbalance (``_TokenSums``), and a
@@ -384,6 +483,7 @@ def _work_off_bwd(k, rows, min_passes, activation, combine, residuals,
     dy, _ = cotangents          # the count takes none
     sums = _TokenSums(combine, x.shape[0], k, rows, _most_passes(
         x.shape[0], k, starts.shape[0], rows, min_passes), order)
+    impl = _grad_accumulate_impl(rows, x.shape[1], experts["gate"].shape[2])
 
     def one_pass(p, carry):
         dx, dw, de = carry
@@ -393,23 +493,23 @@ def _work_off_bwd(k, rows, min_passes, activation, combine, residuals,
             rows_x = jnp.where(valid[:, None], x[tokens], 0)
             rows_w = flat_weights[at]
             d_part = dy[tokens].astype(x.dtype)
-        _out, vjp = jax.vjp(
-            lambda rx, rw, ex: _pass_out(rx, rw, ex, group, valid,
-                                         activation),
-            rows_x, rows_w, experts)
-        d_rows_x, d_rows_w, d_experts = vjp(d_part)
+        d_rows_x, d_rows_w, de = _pass_grads(
+            rows_x, rows_w, experts, group, valid, activation, d_part, de,
+            impl)
         with jax.named_scope("moe_route"):
             dx = sums.add(dx, p, tokens,
                           jnp.where(valid[:, None], d_rows_x, 0))
             dw = dw.at[at].add(jnp.where(valid, d_rows_w, 0))
-        return dx, dw, jax.tree_util.tree_map(jnp.add, de, d_experts)
+        return dx, dw, de
 
     dx, dw, de = jax.lax.fori_loop(
         0, _passes(total, rows, min_passes), one_pass,
         (sums.zeros(x.shape[1], x.dtype), jnp.zeros_like(flat_weights),
-         jax.tree_util.tree_map(jnp.zeros_like, experts)))
+         {name: jnp.zeros(w.shape, jnp.float32)
+          for name, w in experts.items()}))
     with jax.named_scope("moe_route"):
         dx = sums.done(dx)
+    de = {name: de[name].astype(w.dtype) for name, w in experts.items()}
     return dx.astype(x.dtype), dw, de, None, None, None, None
 
 

@@ -114,9 +114,12 @@ class LMTask:
     batch_arity = 1
     #: per step, replicated: assignments given to held / absent experts and
     #: dropped (stays 0), the held experts' max-over-mean load (mean over
-    #: the expert layers), tokens trained on
+    #: the expert layers), tokens trained on, the share of (pass, expert)
+    #: slices of the carried weight gradients the backward pass touches
+    #: (parallel/moe.py:grad_visits; mean over the expert layers)
     extra_metrics = ("moe_held", "moe_absent", "moe_dropped",
-                     "moe_load_max_over_mean", "tokens")
+                     "moe_load_max_over_mean", "tokens",
+                     "moe_grad_visits_share")
 
     def __init__(self, model_config=None):
         self.model_config = model_config    # a config object, a preset name
@@ -205,6 +208,8 @@ class LMTask:
                 jnp.max(held_loads, axis=1)
                 / jnp.maximum(jnp.mean(held_loads, axis=1), 1e-9)),
             "tokens": jax.lax.psum(out["count"], axis).astype(jnp.float32),
+            "moe_grad_visits_share": jax.lax.pmean(
+                _grad_visits_share(mc, mine, tokens), axis),
         }
         return (loss, grads, {"router_bias": new_bias},
                 (out["correct"], out["count"]), extra)
@@ -231,7 +236,7 @@ class LMTask:
         rows = np.asarray(jnp.stack(
             [jnp.stack([m[k] for k in self.extra_metrics])
              for m in step_metrics]), np.float32)
-        held, absent, dropped, ratio, tokens = rows.T
+        held, absent, dropped, ratio, tokens, visits_share = rows.T
         registry.counter("dps_moe_tokens_routed_total",
                          where="held").inc(float(held.sum()))
         registry.counter("dps_moe_tokens_routed_total",
@@ -239,8 +244,22 @@ class LMTask:
         registry.counter("dps_moe_tokens_dropped_total").inc(
             float(dropped.sum()))
         registry.gauge("dps_moe_load_max_over_mean").set(float(ratio[-1]))
+        registry.gauge("dps_moe_grad_visits_share").set(
+            float(visits_share[-1]))
         registry.counter("dps_trainer_tokens_total", mode="sync").inc(
             float(tokens.sum()))
+
+
+def _grad_visits_share(mc, held_loads, tokens):
+    """Mean over the expert layers of ``visits / (passes x C)`` for this
+    worker's ``held_loads`` ``[layers, C]`` (parallel/moe.py:grad_visits):
+    what the backward pass reads and writes of the carried expert
+    gradients, as a share of a whole-carry add every pass."""
+    from ..parallel import moe
+    rows, min_passes = mc.pass_plan(tokens.shape[0] * (tokens.shape[1] - 2))
+    visits, passes = jax.vmap(
+        lambda sizes: moe.grad_visits(sizes, rows, min_passes))(held_loads)
+    return jnp.mean(visits / (passes * held_loads.shape[1]))
 
 
 def task_for(model_name: str, *, augment: bool = True, model_config=None):

@@ -325,3 +325,76 @@ def test_smallthinker_step_fits_one_chip_with_its_band_in_vmem(
     for scope in ("attn_window", "attn_full", "moe_route", "moe_experts",
                   "head_loss", "update"):
         assert f"/{scope}/" in text, scope
+
+
+# -- the expert layer's backward pass: weight gradients added in place (PR 35) --
+
+#: ``memory_analysis().temp_size_in_bytes`` of the parent's steps (PR 34's
+#: tree compiled for the same described chip), whose backward loop wrote a
+#: pass's three weight gradients in bf16 and added them to the whole carry
+PARENT_TEMP_BYTES = {"smallthinker": 5_715_688_448, "joyai": 6_531_915_264}
+
+
+def _while_bodies(text: str) -> list[str]:
+    """The text of every computation some ``while`` runs as its body."""
+    bodies = []
+    for name in set(re.findall(r"body=%([\w.\-]+)", text)):
+        at = text.index(f"\n%{name} (")
+        bodies.append(text[at:text.index("\n}\n", at)])
+    return bodies
+
+
+def _whole_carry_fusions(text: str, carries: tuple) -> list[str]:
+    """Fusions inside a ``while`` body that make an array of one of the
+    shapes ``carries`` from an operand of that same shape: a pass over a
+    whole carried expert gradient."""
+    found = []
+    for body in _while_bodies(text):
+        for line in body.splitlines():
+            made = re.match(
+                r"\s*(?:ROOT )?%[\w.\-]+ = (\S+?)\{\S* fusion\((.*?)\), kind=",
+                line)
+            if not made or made.group(1) not in carries:
+                continue
+            for operand in re.findall(r"%([\w.\-]+)", made.group(2)):
+                shape = re.search(
+                    rf"%{re.escape(operand)} = (\S+?)[{{ ]", body)
+                if shape and shape.group(1) == made.group(1):
+                    found.append(line.strip()[:200])
+    return found
+
+
+@pytest.mark.parametrize("cell", ["smallthinker", "joyai"])
+def test_the_backward_loop_adds_weight_gradients_in_place(
+        cell, request):
+    """Both LM cells' compiled steps: no fusion inside a ``while`` body
+    makes a float32 array of a carried expert gradient's shape from an
+    operand of that shape (the parent's three ``convert_add_fusion`` a
+    pass); ``grouped_grad_accumulate`` is there, three calls a loop, each
+    writing into the accumulator it was handed; XLA's ``ragged-dot`` still
+    computes the forward pass and ``dx``; the readers' scopes are where
+    they were."""
+    if cell == "smallthinker":
+        _state, step = request.getfixturevalue("smallthinker_step")
+        d, layers = 2560, 4
+    else:
+        _state, step, _evaluation = request.getfixturevalue("lm_programs")
+        d, layers = 2048, 5       # four expert blocks and the MTP module's
+    carries = (f"f32[16,{d},768]", f"f32[16,768,{d}]")
+    text = step.as_text()
+    assert not _whole_carry_fusions(text, carries)
+    calls = re.findall(
+        r"%grouped_grad_accumulate(?:\.\d+)? = (\S+?)\{[^\n]*"
+        r"custom_call_target=\"tpu_custom_call\"[^\n]*", text)
+    assert len(calls) == 3 * layers and set(calls) == set(carries)
+    for line in re.findall(r"%grouped_grad_accumulate(?:\.\d+)? = [^\n]*", text):
+        # operand 6 is the carry (after the plan's four arrays, lhs, rhs)
+        assert "output_to_operand_aliasing={{}: (6, {})}" in line, line[:300]
+        assert "/moe_experts/" in line
+    assert any("%grouped_grad_accumulate" in body
+               for body in _while_bodies(text))
+    assert re.search(r"%ragged-dot", text)
+    temp = step.memory_analysis().temp_size_in_bytes
+    assert temp <= PARENT_TEMP_BYTES[cell], temp
+    for scope in ("moe_route", "moe_experts", "head_loss", "update"):
+        assert f"/{scope}/" in text, scope

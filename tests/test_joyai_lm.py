@@ -647,6 +647,8 @@ def test_sync_trainer_trains_the_tiny_lm_and_the_loss_falls(devices, capsys):
                        where="absent").value == before["absent"]
     assert reg.counter("dps_moe_tokens_dropped_total").value == 0
     assert reg.gauge("dps_moe_load_max_over_mean").value >= 1.0
+    # one pass a layer here, so every held expert with a row is a visit
+    assert 1 / 16 <= reg.gauge("dps_moe_grad_visits_share").value <= 1.0
     # the bias moved, by steps of gamma
     bias = np.asarray(trainer.state.batch_stats["router_bias"])
     assert np.abs(bias).max() > 0 and np.abs(bias).max() <= 0.001 * 32 + 1e-9

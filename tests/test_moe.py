@@ -328,3 +328,182 @@ def test_the_gather_combine_is_the_scatter_combine(min_passes):
     with pytest.raises(ValueError, match="combine"):
         moe.held_expert_ffn(x, idx, weights, held, 1, rows=32,
                             combine="sort")
+
+
+# -- the backward pass's weight gradients, added in place (PR 35) --------------
+
+#: rows a held expert has in one pass of 96 rows, tiles of 32 rows
+GROUPED_GRAD_CASES = {
+    "boundaries_off_the_tile_edges": (10, 3, 37, 17, 29),
+    "experts_with_no_row": (0, 5, 0, 60, 0, 31),
+    "boundaries_on_the_tile_edges": (32, 32, 0, 32),
+    "only_the_first_expert": (96, 0, 0, 0, 0, 0),
+    "slack_rows_on_the_last_expert": (7, 0, 9, 0, 0, 80),
+    "only_slack_on_the_last_expert": (0, 0, 0, 0, 0, 96),
+    "one_row_an_expert": (1, 1, 1, 1, 1, 91),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GROUPED_GRAD_CASES))
+def test_the_grouped_grad_kernel_against_a_loop(case, monkeypatch):
+    """``grouped_grad_accumulate`` in interpret mode: ``acc[g] += lhs[rows of
+    g]^T @ rhs[rows of g]`` in float32 into a non-zero carry, against a loop
+    over the experts; an expert without a row comes back bit for bit."""
+    from distributed_parameter_server_for_ml_training_tpu.ops.pallas import (
+        grouped_grad as gg)
+    monkeypatch.setattr(gg, "INTERPRET", True)
+    monkeypatch.setattr(gg, "BLOCK_ROWS", 32)
+    group = GROUPED_GRAD_CASES[case]
+    m, k, n = sum(group), 128, 256
+    r = np.random.default_rng(len(case))
+    lhs = jnp.asarray(r.normal(size=(m, k)), jnp.bfloat16)
+    rhs = jnp.asarray(r.normal(size=(m, n)), jnp.bfloat16)
+    acc = jnp.asarray(r.normal(size=(len(group), k, n)), jnp.float32)
+    assert gg.pick_blocks(m, k, n) == (32, 128, 256)
+    got = np.asarray(jax.jit(gg.grouped_grad_accumulate)(
+        acc, lhs, rhs, jnp.asarray(group, jnp.int32)))
+    at = 0
+    for g, size in enumerate(group):
+        mine = slice(at, at + size)
+        want = np.asarray(acc[g]) + (
+            np.asarray(lhs[mine], np.float32).T
+            @ np.asarray(rhs[mine], np.float32))
+        if size:
+            np.testing.assert_allclose(got[g], want, atol=2e-5, rtol=1e-6)
+            assert not np.array_equal(got[g], np.asarray(acc[g]))
+        else:
+            assert np.array_equal(got[g], np.asarray(acc[g])), g
+        at += size
+    # the plan: every (group, tile) pair with a row once, in the rows' order
+    _offsets, group_of, tile_of, visits = (
+        np.asarray(a) for a in gg.visit_plan(
+            jnp.asarray(group, jnp.int32), m, 32))
+    ends = np.cumsum(group)
+    pairs = [(g, t) for g, (lo, hi) in enumerate(zip(ends - group, ends))
+             for t in range(m // 32) if max(lo, t * 32) < min(hi, t * 32 + 32)]
+    assert list(zip(group_of[:visits[0]], tile_of[:visits[0]])) == pairs
+    assert len(group_of) == m // 32 + len(group) - 1 >= visits[0]
+    assert all((g, t) == pairs[-1] for g, t in
+               zip(group_of[visits[0]:], tile_of[visits[0]:]))
+
+
+def test_the_grouped_grad_kernels_tiles_follow_the_shapes():
+    from distributed_parameter_server_for_ml_training_tpu.ops.pallas import (
+        grouped_grad as gg)
+    # the two cells' passes: SmallThinker 12,288 x 2,560 x 768, JoyAI 4,096
+    # x 2,048 x 768, and the down projection's transposes of both
+    assert gg.pick_blocks(12288, 2560, 768) == (512, 1280, 768)
+    assert gg.pick_blocks(12288, 768, 2560) == (512, 768, 1280)
+    assert gg.pick_blocks(4096, 2048, 768) == (512, 1024, 768)
+    assert gg.pick_blocks(4096, 768, 2048) == (512, 768, 1024)
+    # no tiles: a width off the 128 lanes, rows off bf16's 16 sublanes
+    assert gg.pick_blocks(32, 16, 8) is None
+    assert gg.pick_blocks(40, 128, 128) is None
+    with pytest.raises(ValueError, match="no tiles"):
+        gg.grouped_grad_accumulate(
+            jnp.zeros((2, 16, 8)), jnp.zeros((32, 16)), jnp.zeros((32, 8)),
+            jnp.asarray([16, 16], jnp.int32))
+    with pytest.raises(ValueError, match="groups"):
+        gg.grouped_grad_accumulate(
+            jnp.zeros((3, 128, 128)), jnp.zeros((32, 128)),
+            jnp.zeros((32, 128)), jnp.asarray([16, 16], jnp.int32))
+
+
+def _parent_pass_grads(moe):
+    """The parent's backward of a pass (PR 34): ``jax.vjp`` of the whole
+    pass, then the whole carry added to; the reference the change is held
+    to."""
+    def pass_grads(rows_x, rows_w, experts, group, valid, activation,
+                   d_part, de, impl):
+        _out, vjp = jax.vjp(
+            lambda rx, rw, ex: moe._pass_out(rx, rw, ex, group, valid,
+                                             activation),
+            rows_x, rows_w, experts)
+        d_rows_x, d_rows_w, d_experts = vjp(d_part)
+        return d_rows_x, d_rows_w, jax.tree_util.tree_map(
+            jnp.add, de, d_experts)
+    return pass_grads
+
+
+@pytest.mark.parametrize("impl", ["xla", "in_place"])
+@pytest.mark.parametrize("min_passes", [1, 3])
+@pytest.mark.parametrize("activation", ["silu", "relu"])
+@pytest.mark.parametrize("combine", ["scatter", "gather"])
+def test_the_backward_pass_against_the_parents(combine, activation,
+                                               min_passes, impl,
+                                               monkeypatch):
+    """``_work_off``'s gradients (``dx``, ``dw`` and the three expert
+    leaves) with a pass's weight gradients added by ``_add_weight_grads``
+    (XLA's route off the TPU; the kernel, here in interpret mode, on it)
+    against the parent's formulation, in float32, for a list of 163
+    assignments that ends in the middle of a pass of 64 rows."""
+    from distributed_parameter_server_for_ml_training_tpu.ops import (
+        attention)
+    from distributed_parameter_server_for_ml_training_tpu.ops.pallas import (
+        grouped_grad as gg)
+    from distributed_parameter_server_for_ml_training_tpu.parallel import moe
+    from distributed_parameter_server_for_ml_training_tpu.telemetry import (
+        get_registry)
+    monkeypatch.setattr(gg, "INTERPRET", True)
+    monkeypatch.setattr(gg, "BLOCK_ROWS", 32)
+    monkeypatch.setattr(attention, "_on_tpu", lambda: impl == "in_place")
+    x, logits, experts = _held_layer_inputs(n=80, d=128, f=128, e=8, seed=3)
+    idx, weights = moe.route_top_k_softmax(logits, 3)
+    held = {name: w[2:7] for name, w in experts.items()}
+    assert int(moe.expert_loads(idx, 8)[2:7].sum()) % 64
+
+    def grads():
+        def f(x, held, weights):
+            y, _done = moe.held_expert_ffn(
+                x, idx, weights, held, 2, rows=64, min_passes=min_passes,
+                activation=activation, combine=combine)
+            return jnp.sum(y ** 2)
+        return jax.grad(f, argnums=(0, 1, 2))(x, held, weights)
+
+    counted = get_registry().counter("dps_moe_grad_accumulate_total",
+                                     impl=impl)
+    before = counted.value
+    got = grads()
+    assert counted.value == before + 1
+    monkeypatch.setattr(moe, "_pass_grads", _parent_pass_grads(moe))
+    want = grads()
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        assert a.dtype == b.dtype and a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4,
+                                   rtol=1e-4)
+
+
+def _count_grad_visits(sizes, rows, min_passes):
+    """(pass, expert) pairs with a row, pass by pass, as ``_pass_rows`` cuts
+    them: the slack of a pass goes to the last expert."""
+    ends = np.cumsum(sizes)
+    starts, total = ends - sizes, int(ends[-1])
+    passes = max(-(-total // rows), min_passes)
+    visits = 0
+    for p in range(passes):
+        lo, hi = p * rows, (p + 1) * rows
+        group = np.clip(ends, lo, hi) - np.clip(starts, lo, hi)
+        group[-1] += rows - group.sum()
+        visits += int((group > 0).sum())
+    return visits, passes
+
+
+@pytest.mark.parametrize("min_passes", [1, 3, 8])
+@pytest.mark.parametrize("sizes", [
+    (10, 3, 37, 17, 29),        # ends inside a pass
+    (0, 5, 0, 60, 0, 31),
+    (32, 32, 0, 32),            # ends on a pass's edge
+    (7, 0, 9, 0, 0, 0),         # the last expert has slack alone
+    (0, 0, 0, 0),               # nothing held: slack passes only
+    (200, 1, 1, 1, 1, 1),       # more passes than the floor
+    (31, 1, 0, 0),              # the list ends on an edge, the last empty
+], ids=str)
+def test_grad_visits_against_a_count(sizes, min_passes):
+    from distributed_parameter_server_for_ml_training_tpu.parallel import moe
+    sizes = np.asarray(sizes)
+    visits, passes = jax.jit(moe.grad_visits, static_argnums=(1, 2))(
+        jnp.asarray(sizes), 32, min_passes)
+    assert (int(visits), int(passes)) == _count_grad_visits(
+        sizes, 32, min_passes)
+    assert int(passes) <= int(visits) <= len(sizes) + int(passes) - 1

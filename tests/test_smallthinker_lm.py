@@ -367,6 +367,12 @@ def test_sync_trainer_trains_the_tiny_model_and_the_loss_falls(devices,
     assert reg.counter("dps_moe_tokens_routed_total",
                        where="absent").value == before["absent"]
     assert reg.counter("dps_moe_tokens_dropped_total").value == 0
+    # the backward pass's (pass, expert) slices, of passes x 8 held experts
+    rows, passes = trainer.task.model_config.pass_plan(2 * T)
+    assert 1 / 8 <= reg.gauge("dps_moe_grad_visits_share").value <= (
+        (8 + passes - 1) / (passes * 8))
+    assert reg.counter("dps_moe_grad_accumulate_total",
+                       impl="xla").value >= 4
     # no balancing bias: the task's array stays what it was
     assert not np.asarray(trainer.state.batch_stats["router_bias"]).any()
     # the counter tells the window layers from the global one, three to one
