@@ -1,0 +1,23 @@
+"""The held experts' grouped matmuls, two an ungated expert (XLA's
+``ragged-dot`` kernels; their path is lost in XLA's expansion, so they go by
+instruction name) and the weight gradients' accumulate: device milliseconds
+a step, forward, recomputation and backward, of the instructions traced
+under the ``moe_experts`` scope
+(``harness/nemotron_scopes.py``: the compiled step's ``op_name``s joined
+to the traced slice's ``XLA Ops`` events, in this cell's own order of
+scopes). ``None`` without a trace, or from a program whose driver keeps no
+HLO text or that has no such scope."""
+
+from harness import nemotron_scopes
+
+LAYER = "expert layer"
+UNIT = "ms"
+BETTER = "lower"
+SOURCE = "device_trace"
+MOVES = "images_per_s_per_chip"
+DRIVERS = ("sync_mesh_lm",)
+CHIPS = None
+
+
+def read(run):
+    return nemotron_scopes.scope_ms(run, "moe_experts")

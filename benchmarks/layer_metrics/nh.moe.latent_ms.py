@@ -1,0 +1,22 @@
+"""The two latent projections of the five expert layers (4,096 -> 1,024 before
+the routed experts, 1,024 -> 4,096 after them): device milliseconds a step,
+forward, recomputation and backward, of the instructions traced under the
+``moe_latent`` scope
+(``harness/nemotron_scopes.py``: the compiled step's ``op_name``s joined
+to the traced slice's ``XLA Ops`` events, in this cell's own order of
+scopes). ``None`` without a trace, or from a program whose driver keeps no
+HLO text or that has no such scope."""
+
+from harness import nemotron_scopes
+
+LAYER = "expert layer"
+UNIT = "ms"
+BETTER = "lower"
+SOURCE = "device_trace"
+MOVES = "images_per_s_per_chip"
+DRIVERS = ("sync_mesh_lm",)
+CHIPS = None
+
+
+def read(run):
+    return nemotron_scopes.scope_ms(run, "moe_latent")

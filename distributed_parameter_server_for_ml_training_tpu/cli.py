@@ -119,20 +119,22 @@ def build_parser() -> argparse.ArgumentParser:
         q.add_argument("--model",
                        choices=["resnet18", "resnet50", "vit_b16",
                                 "vit_tiny", "joyai_llm_flash",
-                                "smallthinker"],
+                                "smallthinker", "nemotron_h"],
                        default="resnet18",
-                       help="joyai_llm_flash and smallthinker (train --mode "
-                            "sync only) are the decoder LMs: they train on "
-                            "seeded synthetic documents with AdamW; give "
-                            "them --lr 3e-3 (tiny) or 3e-6 (ep16, ep4), "
-                            "--batch-size in sequences")
+                       help="joyai_llm_flash, smallthinker and nemotron_h "
+                            "(train --mode sync only) are the decoder LMs: "
+                            "they train on seeded synthetic documents with "
+                            "AdamW; give them --lr 3e-3 (tiny) or 3e-6 "
+                            "(ep16, ep4, tp8_ep64), --batch-size in "
+                            "sequences")
         q.add_argument("--model-preset", default=None,
                        help="a decoder LM's: 'tiny' (default; CPU runs, "
                             "sequences of 64) or the published widths as "
                             "one chip's share, a TPU's memory: "
                             "joyai_llm_flash 'ep16' (one of 16 chips, "
                             "sequences of 4,096), smallthinker 'ep4' (one "
-                            "of 4, sequences of 16,384)")
+                            "of 4, sequences of 16,384), nemotron_h "
+                            "'tp8_ep64' (one of 64, sequences of 8,192)")
         q.add_argument("--dataset", choices=["cifar100", "imagenet-synth"],
                        default="cifar100",
                        help="imagenet-synth = ImageNet-shaped synthetic "
@@ -1214,10 +1216,12 @@ def _load_dataset(args):
         # the decoder LM's task: packed token rows (data/tokens.py)
         from .data.tokens import synthetic_documents
         config = lm_config(args.model, getattr(args, "model_preset", None))
-        # packed rows at the published widths: the model's own context
-        # where its configuration states one it trains at, else 4,096; 64
-        # for tiny
-        seq_len = (getattr(config, "max_position_embeddings", 4096)
+        # packed rows at the published widths: the length the model's
+        # configuration says it trains at (``train_seq_len``, or its own
+        # context where that is the training length), else 4,096; 64 for
+        # tiny
+        seq_len = (getattr(config, "train_seq_len", None)
+                   or getattr(config, "max_position_embeddings", 4096)
                    if config.hidden_size >= 1024 else 64)
         return synthetic_documents(
             vocab_size=config.vocab_size, seq_len=seq_len,

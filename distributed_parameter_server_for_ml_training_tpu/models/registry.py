@@ -12,6 +12,9 @@ Configs covered (BASELINE.json ``configs``):
   - smallthinker — the decoder LM of models/smallthinker.py: sliding-window
     and full attention mixed, grouped queries, a softmax router that reads
     the block's input, ReLU-gated experts; from a ``SmallThinkerConfig``
+  - nemotron_h — the hybrid decoder LM of models/nemotron_h.py: a layer is
+    one mixer (a Mamba-2 state-space scan, attention without positions, or
+    ungated experts in a latent); from a ``NemotronHConfig``
 
 A decoder LM's configuration object comes from :func:`lm_config`: itself, a
 preset's name, or (``lm_config_from_file``) a dict of the source's published
@@ -28,6 +31,8 @@ from __future__ import annotations
 import jax.numpy as jnp
 
 from .joyai import PRESETS as JOYAI_PRESETS, JoyAIConfig, JoyAILM
+from .nemotron_h import (PRESETS as NEMOTRON_H_PRESETS, NemotronHConfig,
+                         NemotronHLM)
 from .resnet import ResNet18, ResNet50
 from .smallthinker import (PRESETS as SMALLTHINKER_PRESETS,
                            SmallThinkerConfig, SmallThinkerLM)
@@ -54,6 +59,7 @@ _LM_REGISTRY = {
     "joyai_llm_flash": (JoyAILM, JoyAIConfig, JOYAI_PRESETS),
     "smallthinker": (SmallThinkerLM, SmallThinkerConfig,
                      SMALLTHINKER_PRESETS),
+    "nemotron_h": (NemotronHLM, NemotronHConfig, NEMOTRON_H_PRESETS),
 }
 
 MODEL_NAMES = tuple(_REGISTRY) + tuple(_LM_REGISTRY)

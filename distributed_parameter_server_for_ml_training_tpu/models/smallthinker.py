@@ -160,8 +160,13 @@ class GroupedAttention(nn.Module):
     plain matmul to ``[B, T, G*D]``, and ``o`` comes back ``[B, T, H*D]``
     into ``W_o``: the arrays the flash kernels read and write as they are.
     The parameters keep their published shapes (``[hidden, H*D]``, ``[hidden,
-    G*D]``, ``[H*D, hidden]``)."""
-    cfg: SmallThinkerConfig
+    G*D]``, ``[H*D, hidden]``).
+
+    ``cfg`` is any decoder's configuration that states ``hidden_size``,
+    ``num_attention_heads``, ``num_key_value_heads``, ``head_dim``,
+    ``rope_theta`` and ``init_std`` (models/nemotron_h.py's attention layer
+    is this module with ``rope=False`` and no window)."""
+    cfg: Any
     dtype: Dtype
     rope: bool
     window: int | None

@@ -256,14 +256,34 @@ def _pass_rows(p, order, starts, ends, total, rows: int):
     return at, valid, group.at[-1].add(rows - jnp.sum(group))
 
 
-#: the gate's activation, by the name :func:`held_expert_ffn` takes
-GATE_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+def relu2(u):
+    """``relu(u)^2``."""
+    return jnp.square(jax.nn.relu(u))
 
 
-def _gated(rows_x, wg, wu, group):
-    """``(gate u, up u)`` of the pass's rows, each by its own expert."""
-    return (jax.lax.ragged_dot(rows_x, wg, group),
-            jax.lax.ragged_dot(rows_x, wu, group))
+#: an expert's activation, by the name :func:`held_expert_ffn` takes: the
+#: gate's of a gated unit, the hidden units' own of an ungated one
+GATE_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu,
+                    "relu2": relu2}
+
+
+def _inputs(experts) -> tuple:
+    """The names of an expert's input matrices: ``gate`` and ``up`` of a
+    gated unit (``down(act(gate u) * (up u))``), ``up`` alone of an ungated
+    one (``down(act(up u))``). What ``experts`` holds says which."""
+    return ("gate", "up") if "gate" in experts else ("up",)
+
+
+def _pre(rows_x, weights_in, group):
+    """The pass's rows through each input matrix of their own expert:
+    ``(gate u, up u)`` or ``(up u,)``."""
+    return tuple(jax.lax.ragged_dot(rows_x, w, group) for w in weights_in)
+
+
+def _unit(pre, activation: str):
+    """An expert's hidden units from :func:`_pre`'s products."""
+    hidden = GATE_ACTIVATIONS[activation](pre[0])
+    return hidden * pre[1] if len(pre) == 2 else hidden
 
 
 def _weighted(out, rows_w, valid):
@@ -273,15 +293,16 @@ def _weighted(out, rows_w, valid):
 
 
 def _pass_out(rows_x, rows_w, experts, group, valid, activation: str):
-    """The pass's rows through their experts' gated unit, weighted: three
-    grouped matmuls (``jax.lax.ragged_dot``; on the TPU XLA's own grouped
-    kernel, which visits only the tiles a group fills)."""
+    """The pass's rows through their experts' unit, weighted: a grouped
+    matmul an expert matrix, three of a gated unit and two of an ungated one
+    (``jax.lax.ragged_dot``; on the TPU XLA's own grouped kernel, which
+    visits only the tiles a group fills)."""
     with jax.named_scope("moe_experts"):
-        wg, wu, wd = (experts[name].astype(rows_x.dtype)
-                      for name in ("gate", "up", "down"))
-        gate_u, up_u = _gated(rows_x, wg, wu, group)
-        hidden = GATE_ACTIVATIONS[activation](gate_u) * up_u
-        out = jax.lax.ragged_dot(hidden, wd, group)
+        weights_in = [experts[name].astype(rows_x.dtype)
+                      for name in _inputs(experts)]
+        hidden = _unit(_pre(rows_x, weights_in, group), activation)
+        out = jax.lax.ragged_dot(
+            hidden, experts["down"].astype(rows_x.dtype), group)
     return _weighted(out, rows_w, valid)
 
 
@@ -325,15 +346,14 @@ def _pass_grads(rows_x, rows_w, experts, group, valid, activation: str,
     float32 gradients of ``experts`` with this pass's added
     (:func:`_add_weight_grads`). The forward is computed again stage by
     stage and each stage's inputs differentiated by ``jax.vjp`` with the
-    weights held constant; the three weight gradients are the one thing not
-    left to it."""
+    weights held constant; the weight gradients, one an expert matrix, are
+    the one thing not left to it."""
+    names = _inputs(experts)
     with jax.named_scope("moe_experts"):
-        wg, wu, wd = (experts[name].astype(rows_x.dtype)
-                      for name in ("gate", "up", "down"))
-        (gate_u, up_u), vjp_in = jax.vjp(
-            lambda rx: _gated(rx, wg, wu, group), rows_x)
-        hidden, vjp_unit = jax.vjp(
-            lambda g, u: GATE_ACTIVATIONS[activation](g) * u, gate_u, up_u)
+        weights_in = [experts[name].astype(rows_x.dtype) for name in names]
+        wd = experts["down"].astype(rows_x.dtype)
+        pre, vjp_in = jax.vjp(lambda rx: _pre(rx, weights_in, group), rows_x)
+        hidden, vjp_unit = jax.vjp(lambda *pre: _unit(pre, activation), *pre)
         out, vjp_down = jax.vjp(
             lambda h: jax.lax.ragged_dot(h, wd, group), hidden)
     _part, vjp_weigh = jax.vjp(lambda o, rw: _weighted(o, rw, valid), out,
@@ -341,13 +361,12 @@ def _pass_grads(rows_x, rows_w, experts, group, valid, activation: str,
     d_out, d_rows_w = vjp_weigh(d_part)
     with jax.named_scope("moe_experts"):
         (d_hidden,) = vjp_down(d_out)
-        d_gate_u, d_up_u = vjp_unit(d_hidden)
-        (d_rows_x,) = vjp_in((d_gate_u, d_up_u))
-        de = {"gate": _add_weight_grads(de["gate"], rows_x, d_gate_u, group,
-                                        impl),
-              "up": _add_weight_grads(de["up"], rows_x, d_up_u, group, impl),
-              "down": _add_weight_grads(de["down"], hidden, d_out, group,
-                                        impl)}
+        d_pre = vjp_unit(d_hidden)
+        (d_rows_x,) = vjp_in(d_pre)
+        de = dict(
+            {name: _add_weight_grads(de[name], rows_x, d, group, impl)
+             for name, d in zip(names, d_pre)},
+            down=_add_weight_grads(de["down"], hidden, d_out, group, impl))
     return d_rows_x, d_rows_w, de
 
 
@@ -483,7 +502,7 @@ def _work_off_bwd(k, rows, min_passes, activation, combine, residuals,
     dy, _ = cotangents          # the count takes none
     sums = _TokenSums(combine, x.shape[0], k, rows, _most_passes(
         x.shape[0], k, starts.shape[0], rows, min_passes), order)
-    impl = _grad_accumulate_impl(rows, x.shape[1], experts["gate"].shape[2])
+    impl = _grad_accumulate_impl(rows, x.shape[1], experts["up"].shape[2])
 
     def one_pass(p, carry):
         dx, dw, de = carry
@@ -524,21 +543,22 @@ def held_expert_ffn(x: jax.Array, idx: jax.Array, weights: jax.Array,
 
     ``x`` ``[N, D]`` tokens, ``idx`` / ``weights`` ``[N, k]`` from
     :func:`route_top_k` (or :func:`route_top_k_softmax`), ``experts`` the
-    stacked weights of the gated experts held here (``gate`` / ``up`` ``[C,
-    D, F]``, ``down`` ``[C, F, D]``; an expert is ``down(activation(gate u)
-    * (up u))``, ``activation`` a key of ``GATE_ACTIVATIONS``: SwiGLU by
-    default), which are the experts ``first .. first + C - 1`` of the
-    router's numbering. Returns ``(y [N, D], processed)``: for each token the
-    weighted sum over the held experts among its ``k``, zero for a token that
-    chose none of them, and the number of assignments the passes computed,
-    counted as they ran: it equals the number given to held experts, because
-    nothing is dropped.
+    stacked weights of the experts held here (``gate`` / ``up`` ``[C, D,
+    F]``, ``down`` ``[C, F, D]``; an expert is ``down(activation(gate u) *
+    (up u))``, ``activation`` a key of ``GATE_ACTIVATIONS``: SwiGLU by
+    default; without a ``gate`` it is the ungated ``down(activation(up
+    u))``, two matrices), which are the experts ``first .. first + C - 1``
+    of the router's numbering. Returns ``(y [N, D], processed)``: for each
+    token the weighted sum over the held experts among its ``k``, zero for a
+    token that chose none of them, and the number of assignments the passes
+    computed, counted as they ran: it equals the number given to held
+    experts, because nothing is dropped.
 
     How: the assignments are sorted by held expert (absent experts' last),
     and the sorted list, at most ``N * min(k, C)`` long, is worked off in
     passes of ``rows`` rows (:func:`_work_off`): gather the rows' tokens,
-    three grouped matmuls over the held experts, weigh, add into the
-    tokens' sums. As many passes run as the list needs, found at run time,
+    a grouped matmul an expert matrix over the held experts, weigh, add into
+    the tokens' sums. As many passes run as the list needs, found at run time,
     so only the worst case pays for the worst case; ``min_passes`` is the
     floor a stated capacity sets (:func:`pass_plan`), 1 without one.
     ``combine`` (``COMBINES``) says how the rows' results reach the tokens'
@@ -546,7 +566,7 @@ def held_expert_ffn(x: jax.Array, idx: jax.Array, weights: jax.Array,
     memory) or written where the sort put them and gathered at the end
     (``gather``: a time that does not follow the routing; ``_TokenSums``).
     """
-    k, c = idx.shape[1], experts["gate"].shape[0]
+    k, c = idx.shape[1], experts["down"].shape[0]
     with jax.named_scope("moe_route"):
         local = idx - first
         held = (local >= 0) & (local < c)

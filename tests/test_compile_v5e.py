@@ -327,6 +327,85 @@ def test_smallthinker_step_fits_one_chip_with_its_band_in_vmem(
         assert f"/{scope}/" in text, scope
 
 
+# -- the hybrid model's step: a chunked scan, latent experts, 2 x 8,192 tokens ----
+
+@pytest.fixture(scope="module")
+def nemotron_step(topo):
+    """The Nemotron cell's step program (``worker_step`` with the LM task,
+    the ``tp8_ep64`` preset, 2 sequences of 8,192 tokens, bf16, AdamW)
+    compiled for one described v5e; about a minute."""
+    from distributed_parameter_server_for_ml_training_tpu.parallel.sync_dp \
+        import make_sync_dp_step
+    from distributed_parameter_server_for_ml_training_tpu.train.distributed \
+        import DistributedConfig
+    from distributed_parameter_server_for_ml_training_tpu.train.tasks import (
+        LMTask)
+
+    class Data:
+        vocab_size, seq_len = 16384, 8192
+
+    was_tpu, at._on_tpu = at._on_tpu, lambda: True
+    was_cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        mesh = Mesh(np.array(topo.devices[:1]), ("data",))
+        replicated, split = (NamedSharding(mesh, P()),
+                             NamedSharding(mesh, P("data")))
+        task = LMTask("tp8_ep64")
+        cfg = DistributedConfig(model="nemotron_h", learning_rate=3e-6)
+        model = task.make_model(cfg, Data, jnp.bfloat16, "data")
+        state = jax.eval_shape(lambda: task.init_state(
+            model, jax.random.PRNGKey(0), task.make_optimizer(cfg), Data))
+        state = jax.tree_util.tree_map(
+            lambda x: _shaped(x.shape, x.dtype, replicated), state)
+        step = make_sync_dp_step(mesh, compression="none", task=task).lower(
+            state, _shaped((2, 8194), jnp.int32, split),
+            _shaped((2,), jnp.uint32, replicated)).compile()
+    finally:
+        at._on_tpu = was_tpu
+        jax.config.update("jax_enable_compilation_cache", was_cache)
+    return state, step
+
+
+def test_nemotron_step_fits_one_chip_and_keeps_no_state_a_token(
+        nemotron_step):
+    """The step fits the chip's memory; the scan's largest arrays are a
+    chunk's decays (``[2, 64, 16, 128, 128]``) and one state a chunk, never
+    a state a token; the attention layer runs the flash kernels (4 query
+    heads on one key/value head); the ungated experts' two weight gradients
+    a layer go through the accumulate kernel at ``[3,072, 1,024] x [3,072,
+    2,688]``, tiles from the shapes; the readers' scopes are in the text."""
+    state, step = nemotron_step
+    n = sum(int(np.prod(x.shape))
+            for x in jax.tree_util.tree_leaves(state.params))
+    assert n == 700_862_960
+    memory = step.memory_analysis()
+    assert memory.argument_size_in_bytes >= 12 * n
+    assert memory.alias_size_in_bytes >= 12 * n
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < LM_MEMORY_LIMIT), memory
+    text = step.as_text()
+    # a state a token would be [2, 8192, 16, 64, 128]; a state a chunk is
+    # [.., 64 chunks, 2, 1, 16, 64, 128] in some order of the leading axes
+    assert not re.search(r"\[[\d,]*8192,(?:1,)?16,64,128\]", text)
+    assert re.search(r"f32\[(?:64,2|2,64),1,16,64,128\]", text)
+    assert not re.search(r"\[[\d,]*8192,8192\]", text)       # no scores
+    for kernel, calls in (("flash_attention_fwd", 2),
+                          ("flash_attention_bwd_dq", 1),
+                          ("flash_attention_bwd_dkv", 1),
+                          ("flash_attention_bwd_delta", 1)):
+        assert len(re.findall(rf"%{kernel}(?:\.\d+)? = ", text)) == calls
+    calls = re.findall(
+        r"%grouped_grad_accumulate(?:\.\d+)? = (\S+?)\{[^\n]*"
+        r"custom_call_target=\"tpu_custom_call\"[^\n]*", text)
+    assert len(calls) == 2 * 5
+    assert set(calls) == {"f32[8,1024,2688]", "f32[8,2688,1024]"}
+    assert re.search(r"%ragged-dot", text)
+    for scope in ("ssm", "ssm_scan", "attn_full", "moe_route", "moe_experts",
+                  "moe_latent", "moe_shared", "head_loss", "update"):
+        assert f"/{scope}/" in text, scope
+
+
 # -- the expert layer's backward pass: weight gradients added in place (PR 35) --
 
 #: ``memory_analysis().temp_size_in_bytes`` of the parent's steps (PR 34's

@@ -239,17 +239,29 @@ def test_softmax_of_the_top_k_against_a_loop(k):
     assert (k == 1) == (float(jnp.abs(g).max()) == 0.0)
 
 
-@pytest.mark.parametrize("activation", ["relu", "silu"])
+def _unit_of(activation):
+    """``(the activation's name, whether the experts have a gate)`` of a
+    test's ``activation`` parameter: ``ungated:<name>`` is the two-matrix
+    expert ``down(act(up u))``."""
+    return activation.rpartition(":")[2], not activation.startswith("ungated")
+
+
+@pytest.mark.parametrize("activation", ["relu", "silu", "ungated:relu2",
+                                        "ungated:silu"])
 def test_the_gates_activation_against_a_loop(activation):
     """``held_expert_ffn(activation=...)``: each held expert on the tokens
-    that chose it, ``down(act(gate u) * (up u))``, forward and gradients,
-    with experts 2..5 of 8 held."""
+    that chose it, ``down(act(gate u) * (up u))`` or, of experts without a
+    ``gate``, ``down(act(up u))``, forward and gradients, with experts 2..5
+    of 8 held."""
     from distributed_parameter_server_for_ml_training_tpu.parallel import moe
     x, logits, experts = _held_layer_inputs()
     idx, weights = moe.route_top_k_softmax(logits, 2)
     first, count = 2, 4
-    held = {name: w[first:first + count] for name, w in experts.items()}
-    act = {"relu": jax.nn.relu, "silu": jax.nn.silu}[activation]
+    activation, gated = _unit_of(activation)
+    held = {name: w[first:first + count] for name, w in experts.items()
+            if gated or name != "gate"}
+    act = {"relu": jax.nn.relu, "silu": jax.nn.silu,
+           "relu2": lambda u: jnp.maximum(u, 0) ** 2}[activation]
 
     def layer(x, held, weights):
         return moe.held_expert_ffn(x, idx, weights, held, first, rows=32,
@@ -259,9 +271,9 @@ def test_the_gates_activation_against_a_loop(activation):
         out = jnp.zeros_like(x)
         for c in range(count):
             w = jnp.sum(jnp.where(idx == first + c, weights, 0.0), axis=1)
-            out = out + w[:, None] * (
-                (act(x @ held["gate"][c]) * (x @ held["up"][c]))
-                @ held["down"][c])
+            hidden = act(x @ held["gate"][c]) * (x @ held["up"][c]) \
+                if gated else act(x @ held["up"][c])
+            out = out + w[:, None] * (hidden @ held["down"][c])
         return out
 
     y, processed = layer(x, held, weights)
@@ -273,6 +285,7 @@ def test_the_gates_activation_against_a_loop(activation):
                    argnums=(0, 1, 2))(x, held, weights)
     want = jax.grad(lambda *a: jnp.sum(loop(*a) ** 2),
                     argnums=(0, 1, 2))(x, held, weights)
+    assert set(got[1]) == set(held)
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5,
@@ -294,6 +307,17 @@ def test_the_window_models_pass_plan():
     assert moe.pass_plan(16384, 6, 16, 64) == (12288, 1)
     assert moe.pass_plan(16384, 6, 16, 64, 4.0) == (12288, 8)
     assert moe.pass_plan(16384, 6, 16, 64, 9.0) == (12288, 8)
+
+
+def test_the_latent_models_pass_plan():
+    """16,384 tokens, 22 experts a token, 8 of 512 held: an even load of
+    5,632 assignments in passes of 3,072 rows; the cell's stated capacity of
+    1.5 always runs three (9,216 rows); a token can give the held experts 8
+    assignments at most, 43 passes."""
+    from distributed_parameter_server_for_ml_training_tpu.parallel import moe
+    assert moe.pass_plan(16384, 22, 8, 512) == (3072, 1)
+    assert moe.pass_plan(16384, 22, 8, 512, 1.5) == (3072, 3)
+    assert moe.pass_plan(16384, 22, 8, 512, 99.0) == (3072, 43)
 
 
 @pytest.mark.parametrize("min_passes", [1, 5])
@@ -396,6 +420,9 @@ def test_the_grouped_grad_kernels_tiles_follow_the_shapes():
     assert gg.pick_blocks(12288, 768, 2560) == (512, 768, 1280)
     assert gg.pick_blocks(4096, 2048, 768) == (512, 1024, 768)
     assert gg.pick_blocks(4096, 768, 2048) == (512, 768, 1024)
+    # the latent experts' two matrices: 3,072 x 1,024 x 2,688 (21 x 128)
+    assert gg.pick_blocks(3072, 1024, 2688) == (512, 1024, 896)
+    assert gg.pick_blocks(3072, 2688, 1024) == (512, 896, 1024)
     # no tiles: a width off the 128 lanes, rows off bf16's 16 sublanes
     assert gg.pick_blocks(32, 16, 8) is None
     assert gg.pick_blocks(40, 128, 128) is None
@@ -427,13 +454,14 @@ def _parent_pass_grads(moe):
 
 @pytest.mark.parametrize("impl", ["xla", "in_place"])
 @pytest.mark.parametrize("min_passes", [1, 3])
-@pytest.mark.parametrize("activation", ["silu", "relu"])
+@pytest.mark.parametrize("activation", ["silu", "relu", "ungated:relu2"])
 @pytest.mark.parametrize("combine", ["scatter", "gather"])
 def test_the_backward_pass_against_the_parents(combine, activation,
                                                min_passes, impl,
                                                monkeypatch):
-    """``_work_off``'s gradients (``dx``, ``dw`` and the three expert
-    leaves) with a pass's weight gradients added by ``_add_weight_grads``
+    """``_work_off``'s gradients (``dx``, ``dw`` and the expert leaves,
+    three of a gated unit and two of an ungated one)
+    with a pass's weight gradients added by ``_add_weight_grads``
     (XLA's route off the TPU; the kernel, here in interpret mode, on it)
     against the parent's formulation, in float32, for a list of 163
     assignments that ends in the middle of a pass of 64 rows."""
@@ -449,7 +477,9 @@ def test_the_backward_pass_against_the_parents(combine, activation,
     monkeypatch.setattr(attention, "_on_tpu", lambda: impl == "in_place")
     x, logits, experts = _held_layer_inputs(n=80, d=128, f=128, e=8, seed=3)
     idx, weights = moe.route_top_k_softmax(logits, 3)
-    held = {name: w[2:7] for name, w in experts.items()}
+    activation, gated = _unit_of(activation)
+    held = {name: w[2:7] for name, w in experts.items()
+            if gated or name != "gate"}
     assert int(moe.expert_loads(idx, 8)[2:7].sum()) % 64
 
     def grads():
