@@ -432,12 +432,15 @@ class JoyAILM(nn.Module):
     head are the main model's."""
     cfg: JoyAIConfig
     dtype: Dtype = jnp.float32
-    remat: bool = True          # recompute a block at a time in backward
+    # recompute a block at a time in backward, but for its routing
+    # decisions (parallel/moe.py:KEEP_ROUTING)
+    remat: bool = True
     loss_chunk: int = 4096      # rows of logits live at once
 
     def setup(self):
         cfg, dt = self.cfg, self.dtype
-        block = nn.remat(Block) if self.remat else Block
+        block = (nn.remat(Block, policy=moe.KEEP_ROUTING) if self.remat
+                 else Block)
         self.embed = self.param("embed", _normal(cfg),
                                 (cfg.vocab_size, cfg.hidden_size),
                                 jnp.float32)

@@ -379,12 +379,15 @@ class NemotronHLM(nn.Module):
     last token is not read."""
     cfg: NemotronHConfig
     dtype: Dtype = jnp.float32
-    remat: bool = True          # recompute a layer at a time in backward
+    # recompute a layer at a time in backward, but for its routing
+    # decisions (parallel/moe.py:KEEP_ROUTING)
+    remat: bool = True
     loss_chunk: int = 4096      # rows of logits live at once
 
     def setup(self):
         cfg, dt = self.cfg, self.dtype
-        layer = nn.remat(Layer) if self.remat else Layer
+        layer = (nn.remat(Layer, policy=moe.KEEP_ROUTING) if self.remat
+                 else Layer)
         self.embed = self.param("embed", _normal(cfg),
                                 (cfg.vocab_size, cfg.hidden_size),
                                 jnp.float32)

@@ -33,6 +33,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -182,6 +183,26 @@ def dense_reference(params, tokens, capacity: int | None = None):
 
 # -- the held-experts layer ---------------------------------------------------
 
+#: the name of a layer's integer routing decisions: the chosen experts
+#: ``idx [N, k]``, the assignments' sorted ``order`` and the held experts'
+#: bounds in it (``starts``, ``ends``, ``total``). None takes a gradient
+#: and a recomputation would rebuild each identically, so a block that is
+#: recomputed in the backward pass keeps them: a layer routes once a step.
+ROUTING = "moe_routing"
+
+#: the ``policy`` of a decoder's ``nn.remat``: everything in a block is
+#: recomputed but what is named ``ROUTING`` (integers, ``N k`` x 8 bytes and
+#: change a layer, counted in ``dps_moe_routing_kept_bytes_total``)
+KEEP_ROUTING = jax.checkpoint_policies.save_only_these_names(ROUTING)
+
+
+def _keep(a: jax.Array) -> jax.Array:
+    """``a`` named ``ROUTING``: inert but under ``KEEP_ROUTING``. Named
+    flat: a TPU pads the last axis of what it keeps to 128 lanes, so ``idx
+    [N, k]`` kept as it stands would be ``128 / k`` times its bytes."""
+    return checkpoint_name(a.reshape(-1), ROUTING).reshape(a.shape)
+
+
 def route_top_k(scores: jax.Array, bias: jax.Array, k: int, *,
                 scaling: float = 1.0, normalize: bool = True):
     """Choose ``k`` experts a token and weigh them.
@@ -196,10 +217,11 @@ def route_top_k(scores: jax.Array, bias: jax.Array, k: int, *,
     times ``scaling``.
     """
     _, idx = jax.lax.top_k(scores + jax.lax.stop_gradient(bias)[None], k)
+    idx = _keep(idx.astype(jnp.int32))
     weights = jnp.take_along_axis(scores, idx, axis=1)
     if normalize:
         weights = weights / jnp.sum(weights, axis=1, keepdims=True)
-    return idx.astype(jnp.int32), weights * scaling
+    return idx, weights * scaling
 
 
 def route_top_k_softmax(logits: jax.Array, k: int):
@@ -208,8 +230,17 @@ def route_top_k_softmax(logits: jax.Array, k: int):
     softmax over those ``k`` logits alone: ``(idx [N, k] int32, weights [N,
     k] float32)``, the weights of a token summing to 1. No bias steers the
     choice."""
-    top, idx = jax.lax.top_k(logits, k)
-    return idx.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
+    _, idx = jax.lax.top_k(logits, k)
+    idx = _keep(idx.astype(jnp.int32))
+    # the k logits read through the kept choice (``top_k``'s values), so
+    # that a recomputation selects nothing; by comparison, not by a gather:
+    # on a TPU ``take_along_axis`` fetches the N k floats one by one, 1 ms
+    # at 16,384 x 6 where the values used to come with the selection
+    # (PERF.md, PR 37), and a compare, select and sum over [N, k, E] is
+    # fused elementwise work, forward and backward
+    chosen = idx[:, :, None] == jnp.arange(logits.shape[1], dtype=jnp.int32)
+    top = jnp.sum(jnp.where(chosen, logits[:, None, :], 0), axis=-1)
+    return idx, jax.nn.softmax(top, axis=-1)
 
 
 def expert_loads(idx: jax.Array, n_experts: int) -> jax.Array:
@@ -535,6 +566,17 @@ def _work_off_bwd(k, rows, min_passes, activation, combine, residuals,
 _work_off.defvjp(_work_off_fwd, _work_off_bwd)
 
 
+def _count_kept(*routing):
+    """At trace time, once an expert layer: the bytes of what the layer
+    names ``ROUTING`` into ``dps_moe_routing_kept_bytes_total``, the layer
+    into ``dps_moe_routing_kept_layers_total``."""
+    from ..telemetry import get_registry
+    registry = get_registry()
+    registry.counter("dps_moe_routing_kept_bytes_total").inc(
+        sum(a.size * a.dtype.itemsize for a in routing))
+    registry.counter("dps_moe_routing_kept_layers_total").inc()
+
+
 def held_expert_ffn(x: jax.Array, idx: jax.Array, weights: jax.Array,
                     experts: dict, first: int, *, rows: int,
                     min_passes: int = 1, activation: str = "silu",
@@ -576,7 +618,9 @@ def held_expert_ffn(x: jax.Array, idx: jax.Array, weights: jax.Array,
         ends = jnp.cumsum(sizes)
         starts, total = ends - sizes, ends[-1]
         # the last pass, or a capacity's floor, may reach past the end
-        order = jnp.pad(order, (0, rows * min_passes))
+        order, starts, ends, total = map(_keep, (
+            jnp.pad(order, (0, rows * min_passes)), starts, ends, total))
+    _count_kept(idx, order, starts, ends, total)
     y, processed = _work_off(x, weights.reshape(-1), experts, order, starts,
                              ends, total, k, rows, min_passes, activation,
                              combine)

@@ -24,6 +24,8 @@ token accuracy.
 
 from __future__ import annotations
 
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -123,6 +125,7 @@ class LMTask:
 
     def __init__(self, model_config=None):
         self.model_config = model_config    # a config object, a preset name
+        self._said_routing_kept = False
 
     def make_model(self, cfg, dataset, dtype, axis_name):
         from ..models import get_model
@@ -248,6 +251,17 @@ class LMTask:
             float(visits_share[-1]))
         registry.counter("dps_trainer_tokens_total", mode="sync").inc(
             float(tokens.sum()))
+        if not self._said_routing_kept:
+            # once, at the first epoch's sync: the step has been traced
+            self._said_routing_kept = True
+            layers = registry.counter("dps_moe_routing_kept_layers_total")
+            kept = registry.counter("dps_moe_routing_kept_bytes_total")
+            sys.stdout.write(
+                f"[lm] routing kept for the backward pass: {layers.value:.0f} "
+                f"expert layers traced in all programs so far, "
+                f"{kept.value:.0f} bytes of integers named "
+                f"(parallel/moe.py:KEEP_ROUTING)\n")
+            sys.stdout.flush()
 
 
 def _grad_visits_share(mc, held_loads, tokens):

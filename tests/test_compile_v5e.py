@@ -410,8 +410,13 @@ def test_nemotron_step_fits_one_chip_and_keeps_no_state_a_token(
 
 #: ``memory_analysis().temp_size_in_bytes`` of the parent's steps (PR 34's
 #: tree compiled for the same described chip), whose backward loop wrote a
-#: pass's three weight gradients in bf16 and added them to the whole carry
-PARENT_TEMP_BYTES = {"smallthinker": 5_715_688_448, "joyai": 6_531_915_264}
+#: pass's three weight gradients in bf16 and added them to the whole carry.
+#: The JoyAI step's bound has 13 MB of room since PR 37, which keeps 5.6 MB
+#: of routing integers from the forward pass (parallel/moe.py:KEEP_ROUTING):
+#: the compiler then places 6,541,663,744 bytes of temporaries, 9.7 MB over
+#: PR 34's 6,531,915,264, beside 14.2 MB less generated code; on the chip
+#: the cell's peak fell by 13.4 MB (PERF.md, PR 37)
+PARENT_TEMP_BYTES = {"smallthinker": 5_715_688_448, "joyai": 6_545_000_000}
 
 
 def _while_bodies(text: str) -> list[str]:
@@ -477,3 +482,35 @@ def test_the_backward_loop_adds_weight_gradients_in_place(
     assert temp <= PARENT_TEMP_BYTES[cell], temp
     for scope in ("moe_route", "moe_experts", "head_loss", "update"):
         assert f"/{scope}/" in text, scope
+
+
+# -- an expert layer routes once a step (PR 37) ---------------------------------
+
+@pytest.mark.parametrize("cell", ["joyai", "smallthinker", "nemotron"])
+def test_the_step_selects_and_sorts_once_an_expert_layer(cell, request):
+    """The three decoder cells' compiled steps: a block is recomputed in
+    the backward pass but for its routing decisions
+    (``parallel/moe.py:KEEP_ROUTING``), so the step holds one selection (on
+    the TPU ``top_k`` is a sort of every token's ``E`` scores) and one sort
+    of the ``N k`` assignments an expert layer, where the parent held two
+    of each. The passes loop is computed again only where a weight gradient
+    needs its sum: three ``while`` bodies with grouped matmuls a layer in
+    the Nemotron cell (``latent_up``), two in the others."""
+    if cell == "joyai":
+        _state, step, _evaluation = request.getfixturevalue("lm_programs")
+        k, e, layers, loops = 8, 256, 5, 2       # four blocks and the MTP's
+    elif cell == "smallthinker":
+        _state, step = request.getfixturevalue("smallthinker_step")
+        k, e, layers, loops = 6, 64, 4, 2
+    else:
+        _state, step = request.getfixturevalue("nemotron_step")
+        k, e, layers, loops = 22, 512, 5, 3
+    text = step.as_text()
+    n = 16384
+    selections = re.findall(
+        rf"= \(f32\[{n},{e}\]\S*, s32\[{n},{e}\]\S*\) sort\(", text)
+    sorts = re.findall(
+        rf"= \(s32\[{n * k}\]\S*, s32\[{n * k}\]\S*\) sort\(", text)
+    assert len(selections) == layers and len(sorts) == layers
+    grouped = [body for body in _while_bodies(text) if "%ragged-dot" in body]
+    assert len(grouped) == loops * layers
