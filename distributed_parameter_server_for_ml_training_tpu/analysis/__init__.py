@@ -38,6 +38,8 @@ from .traces import (
     critical_path_report,
     find_trace_dumps,
     load_trace_dumps,
+    ps_phase_report,
+    render_ps_phase_table,
     save_chrome_trace,
     to_chrome_trace,
 )
@@ -57,7 +59,9 @@ __all__ = ["OP_CLASSES", "PHASES", "PHASE_ORDER",
            "resolve_exemplars",
            "parse_cluster_series",
            "parse_experiment", "parse_snapshot_series",
+           "ps_phase_report",
            "render_profile_diff", "render_profile_table",
+           "render_ps_phase_table",
            "save_chrome_trace", "staleness_series", "to_chrome_trace",
            "worker_throughput_series",
            "ExperimentVisualizer", "run_cell", "run_matrix"]

@@ -2,7 +2,7 @@
 
 Consumes the span records the flight recorder produces
 (``telemetry/trace.py``): crash/atexit dump files, ``/debug/trace``
-bodies, or raw span lists. Three capabilities:
+bodies, or raw span lists. Four capabilities:
 
 - :func:`assemble_traces` — join spans from MANY processes by
   ``trace_id`` and parent links into per-step trace trees (the server's
@@ -10,6 +10,11 @@ bodies, or raw span lists. Three capabilities:
   step via the wire-propagated context);
 - :func:`to_chrome_trace` — Chrome trace-event JSON (the ``traceEvents``
   array format), loadable directly in Perfetto / ``chrome://tracing``;
+- :func:`ps_phase_report` — where the parameter-server exchange's time
+  went, from the spans it records in EVERY run (no ``--trace``): each
+  worker's seconds by phase, the store's waits for the device, the
+  staleness of what it applied, and updates a second between two
+  ``store.sync`` stamps (``cli perf phases``);
 - :func:`critical_path_report` — classify each ``worker.step``'s wall
   time into **compute / fetch-wait / push-wait / server-apply / codec**
   and rank steps by wall time with their dominant phase: the per-step
@@ -44,16 +49,27 @@ _WAIT_NAMES = ("worker.fetch_wait", "worker.push_wait")
 PHASES = ("compute", "fetch_wait", "push_wait", "server_apply", "codec")
 
 
+def _read_payload(source: str):
+    if source.startswith(("http://", "https://")):
+        from urllib.request import urlopen
+        with urlopen(source, timeout=10.0) as r:
+            return json.loads(r.read().decode())
+    with open(source) as f:
+        return json.load(f)
+
+
 def load_trace_dumps(paths: Iterable[str]) -> list[dict]:
     """Merge span records from flight-recorder dump files (or any JSON
     file holding either a ``{"spans": [...]}`` payload or a bare span
-    list). Deduplicates by ``span_id`` — a SIGTERM dump followed by an
-    atexit dump of the same process overlaps almost entirely."""
+    list) and live ``http://host:port/debug/trace`` bodies. Deduplicates
+    by ``span_id`` — a SIGTERM dump followed by an atexit dump of the
+    same process overlaps almost entirely, and so do two scrapes of one
+    ring taken less than a ring's length apart (how a window longer than
+    the ring is read: scrape as it goes, merge here)."""
     spans: list[dict] = []
     seen: set[str] = set()
     for path in paths:
-        with open(path) as f:
-            payload = json.load(f)
+        payload = _read_payload(path)
         records = payload.get("spans", []) if isinstance(payload, dict) \
             else payload
         for s in records:
@@ -275,3 +291,158 @@ def critical_path_report(spans: list[dict], top: int = 10) -> dict:
         "stragglers": entries[:top],
         "by_dominant_phase": by_dom,
     }
+
+
+# -- the parameter-server exchange's always-on record -------------------------
+
+#: Per-worker phases of :func:`ps_phase_report`, by span name. The first
+#: three are children of ``worker.step``; ``codec`` lies inside the waits
+#: (or on the comms thread under ``--overlap``) and is reported beside
+#: them, not subtracted; the last two are a worker-epoch's roots.
+PS_WORKER_PHASES = {
+    "worker.fetch_wait": "fetch_wait",
+    "worker.compute": "compute",
+    "worker.push_wait": "push_wait",
+    "worker.codec": "codec",
+    "worker.epoch_sync": "epoch_sync",
+    "worker.eval": "eval",
+}
+
+
+def ps_phase_report(spans: list[dict]) -> dict:
+    """Where the parameter-server path's time went, from the spans
+    ``ps/worker.py`` and the in-process stores record in every run
+    (``trace_span(..., always=True)``: a ``/debug/trace`` body or a
+    ``--trace-dump-dir`` dump of a run started WITHOUT ``--trace`` holds
+    them; ``worker.compute`` is then dispatch-to-return and the device
+    was never made to wait for the record).
+
+    Returns::
+
+        {"workers": {worker: {"steps", "epochs", "observed_s",
+                              "step_s", "phases_s": {fetch_wait, compute,
+                              push_wait, codec, epoch_sync, eval},
+                              "unnamed_s"}},
+         "store": {"pushes", "rejected", "reject_share",
+                   "applies", "staleness_mean", "staleness_max",
+                   "apply_s", "fetch_s",
+                   "syncs", "sync_wait_s",
+                   "updates", "updates_per_s", "rate_uncertainty"}}
+
+    ``observed_s`` is a worker's first span start to its last span end;
+    ``unnamed_s`` what its roots (``worker.step``, ``worker.epoch_sync``,
+    ``worker.eval``) leave of it: the shard's shuffle, the first batches'
+    gather and put. ``sync_wait_s`` (``store.sync``, the device store's
+    wait on every ``wait_every``-th update) is time inside some worker's
+    ``push_wait``: a large push wait that is mostly sync wait is the
+    device's backlog, not the store's lock.
+
+    ``updates_per_s`` is (last ``updates`` - first) over the time between
+    those two ``store.sync`` returns (``ready_mono``). A stamp's
+    ``updates`` is a FLOOR on finished work: gradient steps of later
+    updates that other workers dispatched before that apply, up to
+    workers-1 of them, are finished by then too, and the host cannot tell
+    how many. The rate is therefore off by up to ``rate_uncertainty`` =
+    (workers - 1) / (updates between the stamps) of itself: one reading
+    over a few hundred updates is good to a percent, not to a tenth.
+    """
+    workers: dict = {}
+    pushes = rejected = 0
+    staleness: list = []
+    apply_s = fetch_s = sync_wait_s = 0.0
+    syncs = []
+    for s in spans:
+        name, attrs = s.get("name", ""), s.get("attrs", {})
+        dur = float(s.get("dur", 0.0))
+        if name.startswith("worker.") and "worker" in attrs:
+            w = workers.setdefault(attrs["worker"], {
+                "steps": 0, "epochs": 0, "step_s": 0.0, "roots_s": 0.0,
+                "t0": float("inf"), "t1": 0.0,
+                "phases_s": dict.fromkeys(PS_WORKER_PHASES.values(), 0.0)})
+            start = float(s.get("ts", 0.0))
+            w["t0"], w["t1"] = min(w["t0"], start), max(w["t1"], start + dur)
+            if name == "worker.step":
+                w["step_s"] += dur
+                w["roots_s"] += dur
+                w["steps"] += not attrs.get("epoch_open", False)
+            elif name in PS_WORKER_PHASES:
+                w["phases_s"][PS_WORKER_PHASES[name]] += dur
+                if name in ("worker.epoch_sync", "worker.eval"):
+                    w["roots_s"] += dur
+                    w["epochs"] += name == "worker.epoch_sync"
+        elif name == "store.push":
+            pushes += 1
+            rejected += attrs.get("accepted") is False
+        elif name == "store.apply":
+            apply_s += dur
+            if attrs.get("staleness") is not None:
+                staleness.append(attrs["staleness"])
+        elif name == "store.fetch":
+            fetch_s += dur
+        elif name == "store.sync" and "ready_mono" in attrs:
+            sync_wait_s += dur
+            syncs.append((attrs["ready_mono"], attrs.get("updates", 0),
+                          attrs.get("rejected", 0)))
+    out_workers = {}
+    for wid, w in sorted(workers.items(), key=lambda kv: str(kv[0])):
+        observed = max(0.0, w["t1"] - w["t0"])
+        out_workers[wid] = {
+            "steps": w["steps"], "epochs": w["epochs"],
+            "observed_s": round(observed, 6),
+            "step_s": round(w["step_s"], 6),
+            "phases_s": {k: round(v, 6) for k, v in w["phases_s"].items()},
+            "unnamed_s": round(max(0.0, observed - w["roots_s"]), 6),
+        }
+    store = {
+        "pushes": pushes, "rejected": rejected,
+        "reject_share": round(rejected / pushes, 6) if pushes else None,
+        "applies": len(staleness),
+        "staleness_mean": round(sum(staleness) / len(staleness), 4)
+        if staleness else None,
+        "staleness_max": max(staleness) if staleness else None,
+        "apply_s": round(apply_s, 6), "fetch_s": round(fetch_s, 6),
+        "syncs": len(syncs), "sync_wait_s": round(sync_wait_s, 6),
+        "updates": None, "updates_per_s": None, "rate_uncertainty": None,
+    }
+    if len(syncs) >= 2:
+        syncs.sort()
+        (t_a, u_a, _), (t_b, u_b, _) = syncs[0], syncs[-1]
+        store["updates"] = [u_a, u_b]
+        if t_b > t_a and u_b > u_a:
+            store["updates_per_s"] = round((u_b - u_a) / (t_b - t_a), 4)
+            store["rate_uncertainty"] = round(
+                max(0, len(workers) - 1) / (u_b - u_a), 6)
+    return {"workers": out_workers, "store": store}
+
+
+def render_ps_phase_table(report: dict) -> str:
+    """``cli perf phases``'s text view of :func:`ps_phase_report`."""
+    phases = list(PS_WORKER_PHASES.values())
+    lines = ["worker  steps  epochs  observed_s  " + "  ".join(
+        f"{p:>10}" for p in phases) + "     unnamed"]
+    for wid, w in report["workers"].items():
+        lines.append(
+            f"{wid!s:>6}  {w['steps']:>5}  {w['epochs']:>6}  "
+            f"{w['observed_s']:>10.3f}  " + "  ".join(
+                f"{w['phases_s'][p]:>10.3f}" for p in phases)
+            + f"  {w['unnamed_s']:>10.3f}")
+    if not report["workers"]:
+        lines.append("  (no worker.* span: not a parameter-server run, or "
+                     "the ring has lost them)")
+    st = report["store"]
+    lines.append(
+        f"store: {st['pushes']} pushes, {st['rejected']} refused"
+        + (f" ({st['reject_share']:.4f})" if st["pushes"] else "")
+        + f"; {st['applies']} async applies"
+        + (f", staleness mean {st['staleness_mean']} max "
+           f"{st['staleness_max']}" if st["applies"] else "")
+        + f"; apply {st['apply_s']:.3f} s, fetch {st['fetch_s']:.3f} s")
+    lines.append(
+        f"device waits (store.sync): {st['syncs']}, {st['sync_wait_s']:.3f} s"
+        " inside push_wait"
+        + (f"; updates {st['updates'][0]} -> {st['updates'][1]}"
+           if st["updates"] else "")
+        + (f", {st['updates_per_s']} updates/s (a floor's rate: good to "
+           f"+-{100 * st['rate_uncertainty']:.2f}%)"
+           if st["updates_per_s"] else ""))
+    return "\n".join(lines)

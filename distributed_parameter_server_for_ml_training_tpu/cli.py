@@ -1063,6 +1063,20 @@ def build_parser() -> argparse.ArgumentParser:
                           "prune them — the artifact is the durable "
                           "record; traces are kept automatically when "
                           "attribution fails so they stay debuggable)")
+    pfh = pfsub.add_parser(
+        "phases",
+        help="where a parameter-server run's time went, from the phase "
+             "spans its workers and store record in every run (no "
+             "--trace needed): seconds by phase a worker, the store's "
+             "waits for the device, staleness applied, updates/s "
+             "(docs/OBSERVABILITY.md, 'Span names')")
+    pfh.add_argument("sources", nargs="+",
+                     help="flight-recorder dumps: files, --trace-dump-dir "
+                          "directories, or live http://host:port/"
+                          "debug/trace URLs (merged, deduplicated by "
+                          "span id)")
+    pfh.add_argument("--json", action="store_true",
+                     help="print the report as JSON instead of the table")
     pfd = pfsub.add_parser(
         "diff",
         help="regression attribution: diff two attribution artifacts "
@@ -3199,6 +3213,8 @@ def cmd_perf(args) -> int:
         return _cmd_perf_check(args)
     if args.perf_command == "diff":
         return _cmd_perf_diff(args)
+    if args.perf_command == "phases":
+        return _cmd_perf_phases(args)
     return _cmd_perf_profile(args)
 
 
@@ -3284,6 +3300,26 @@ def _cmd_perf_profile(args) -> int:
                   f"file(s) from {args.profile_dir} (--keep-traces to "
                   f"keep)", file=sys.stderr)
     return 0
+
+
+def _cmd_perf_phases(args) -> int:
+    """``cli perf phases SOURCE...``: the parameter-server exchange's
+    always-on record as a table (analysis/traces.py:ps_phase_report)."""
+    import json as _json
+
+    from .analysis.traces import (find_trace_dumps, load_trace_dumps,
+                                  ps_phase_report, render_ps_phase_table)
+    sources = []
+    for src in args.sources:
+        sources += find_trace_dumps(src) if os.path.isdir(src) else [src]
+    try:
+        report = ps_phase_report(load_trace_dumps(sources))
+    except (OSError, ValueError) as e:
+        print(f"perf phases: cannot read {sources}: {e}", file=sys.stderr)
+        return 1
+    print(_json.dumps(report, indent=2) if args.json
+          else render_ps_phase_table(report))
+    return 0 if report["workers"] else 1
 
 
 def _cmd_perf_diff(args) -> int:

@@ -155,7 +155,8 @@ class DeviceParameterStore(AggregationBase):
         (immutability makes the reference's copy-under-lock, server.py:222,
         free here)."""
         t0 = _tnow()
-        with trace_span("store.fetch", backend=self.store_backend):
+        with trace_span("store.fetch", always=True,
+                        backend=self.store_backend):
             with self._param_lock:
                 payload = dict(self.parameters)
                 step = self.global_step
@@ -196,15 +197,15 @@ class DeviceParameterStore(AggregationBase):
                       f"shape {g.shape} != server {p_shape}")
                 return False
         try:
-            with trace_span("store.push",
-                            backend=self.store_backend) as sp:
-                if self.config.mode == "sync":
-                    accepted = self._push_sync(worker_id, gradients,
-                                               fetched_step)
-                    sp.attrs["accepted"] = accepted
-                    return accepted
-                accepted = self._push_async(worker_id, gradients,
-                                            fetched_step)
+            # Recorded in every run, on the pushing worker's thread: who
+            # pushed and whether the store took it (an accepted push's
+            # staleness is on the ``store.apply`` span it encloses).
+            with trace_span("store.push", always=True,
+                            backend=self.store_backend,
+                            worker=worker_id) as sp:
+                push = (self._push_sync if self.config.mode == "sync"
+                        else self._push_async)
+                accepted = push(worker_id, gradients, fetched_step)
                 sp.attrs["accepted"] = accepted
                 return accepted
         finally:
@@ -266,8 +267,21 @@ class DeviceParameterStore(AggregationBase):
             if self._updates_since_wait < self.wait_every:
                 return False  # declined: caller must not record a timing
             self._updates_since_wait = 0
-        # Deliberately outside _param_lock: one consistent reference is
-        # enough (jax arrays are immutable), and blocking the device under
-        # the lock would convoy every concurrent push behind the wait.
-        jax.block_until_ready(self.parameters)  # dpslint: ignore[lock-guard]
+        # One consistent (parameters, step) pair under the lock; the wait
+        # itself deliberately outside it (jax arrays are immutable, and
+        # blocking the device under the lock would convoy every concurrent
+        # push behind the wait).
+        with self._param_lock:
+            reference, updates = self.parameters, self.global_step
+        # A device-complete stamp in every run: when the wait returns
+        # (``ready_mono``), ``updates`` updates, and every gradient step
+        # that fed one, are finished on the device. A floor, not a count:
+        # up to workers-1 gradient steps of later updates, dispatched
+        # before this apply, are finished by then too, and the host cannot
+        # tell how many (two threads' dispatches overlap).
+        with trace_span("store.sync", always=True,
+                        backend=self.store_backend, updates=updates,
+                        rejected=self.stats.gradients_rejected) as sp:
+            jax.block_until_ready(reference)
+            sp.attrs["ready_mono"] = time.monotonic()
         return True

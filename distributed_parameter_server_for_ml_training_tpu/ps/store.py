@@ -532,8 +532,8 @@ class AggregationBase(TelemetryMixin, MembershipMixin):
             # push that COMPLETED the round — the causally responsible
             # step (trace context is thread-local; the last pusher's
             # thread runs the aggregation).
-            with trace_span("store.apply", backend=self.store_backend,
-                            mode="sync",
+            with trace_span("store.apply", always=True,
+                            backend=self.store_backend, mode="sync",
                             n_grads=self._gradients_received):
                 self._round_update(list(self._pending.values()),
                                    self.config.learning_rate)
@@ -676,7 +676,8 @@ class AggregationBase(TelemetryMixin, MembershipMixin):
             accepted = staleness <= self.config.staleness_bound
             if accepted:
                 weight = staleness_weight(staleness)
-                with trace_span("store.apply", backend=self.store_backend,
+                with trace_span("store.apply", always=True,
+                                backend=self.store_backend,
                                 mode="async", staleness=staleness,
                                 weight=round(weight, 4)):
                     self._apply(grads, self.config.learning_rate, weight)

@@ -19,9 +19,9 @@ from __future__ import annotations
 import math
 import threading
 import time
-from contextlib import nullcontext
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
-from functools import partial
+from functools import cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -40,6 +40,7 @@ from ..telemetry import (
     GoodputAccount,
     current_wire_trace,
     now as _tnow,
+    trace_enabled,
     trace_span,
     use_wire_context,
 )
@@ -367,8 +368,12 @@ class _CommsPipeline:
             self._go.clear()
             if self._stop:
                 return
-            grads, fetched_step, prefetch_current, wctx = self._item
+            grads, fetched_step, prefetch_current, wctx, at = self._item
             self._item = None
+            # The submitting step's span attributes, for this thread's
+            # always-on ``worker.codec`` spans (the training thread has
+            # moved on to the next step by now).
+            self._worker._span_attrs = at
             t0 = _tnow()
             try:
                 # Adopt the submitting step's trace context so this item's
@@ -427,7 +432,7 @@ class _CommsPipeline:
         # Trace context captured on the TRAINING thread (the submitting
         # step's push_wait span) — the comms thread re-enters it.
         self._item = (grads, fetched_step, prefetch_current,
-                      current_wire_trace())
+                      current_wire_trace(), self._worker._span_attrs)
         self._pending_prefetch = prefetch_current is not None
         self._done.clear()
         self._tm_depth.set(1)
@@ -474,6 +479,16 @@ class _CommsPipeline:
         self._thread.join(timeout=10.0)
 
 
+@cache
+def _eval_tx():
+    """The evaluation state's optimizer, one a process: ``tx`` is a static
+    field of the jitted ``eval_step``'s argument and compares by identity,
+    so a fresh ``optax.identity()`` at every ``evaluate`` compiled the
+    evaluation again at every epoch end."""
+    import optax
+    return optax.identity()
+
+
 class PSWorker(threading.Thread):
     """One logical worker. Runs as a thread; compute runs on the accelerator
     via a shared jit-compiled grad step (one compile for all workers)."""
@@ -499,6 +514,11 @@ class PSWorker(threading.Thread):
         # Overlapped comms pipeline (set in _run when overlap=True); an
         # attribute so the session-resume path can drain and rebuild it.
         self._pipe: _CommsPipeline | None = None
+        # What the loop's phase spans carry (worker, epoch, step), a
+        # thread each: set by the training thread at the top of each
+        # iteration and by the comms thread from the item it was handed,
+        # read by the span sites in the methods they call.
+        self._span = threading.local()
         self._tm_reconnect = None  # created at _init_telemetry
         self._tm_hb_err = None
         # Worker health report (docs/OBSERVABILITY.md): built at push
@@ -988,10 +1008,12 @@ class PSWorker(threading.Thread):
                 # slow wire — shows up in the straggler report as a
                 # fetch-wait-dominant step rather than vanishing into
                 # epoch bookkeeping.
-                with trace_span("worker.step", root=True, worker=worker_id,
-                                step=self.result.local_steps_completed,
-                                epoch=epoch, epoch_open=True):
-                    with trace_span("worker.fetch_wait"):
+                at = self._span_attrs = {
+                    "worker": worker_id, "epoch": epoch,
+                    "step": self.result.local_steps_completed}
+                with trace_span("worker.step", root=True, always=True,
+                                epoch_open=True, **at):
+                    with trace_span("worker.fetch_wait", always=True, **at):
                         params, fetched_step = self._boundary_fetch(
                             worker_id, fetched_step, params)
                 # A session resume inside the fetch may have re-registered
@@ -1025,13 +1047,18 @@ class PSWorker(threading.Thread):
                     # handler/store/apply spans join the same trace —
                     # the per-step causal tree the critical-path
                     # attribution consumes (analysis/traces.py).
-                    step_span = trace_span(
-                        "worker.step", root=True, worker=worker_id,
-                        step=self.result.local_steps_completed,
-                        epoch=epoch)
-                    with step_span:
+                    # Recorded in every run (``always``), --trace or not,
+                    # and never touching the device: ``cli perf phases``
+                    # reads the loop's phases from /debug/trace or a dump
+                    # of a run nobody thought to trace.
+                    at = self._span_attrs = {
+                        "worker": worker_id, "epoch": epoch,
+                        "step": self.result.local_steps_completed}
+                    with trace_span("worker.step", root=True, always=True,
+                                    **at):
                         if boundary and batch_idx > 0:
-                            with trace_span("worker.fetch_wait"):
+                            with trace_span("worker.fetch_wait",
+                                            always=True, **at):
                                 params, fetched_step = \
                                     self._boundary_fetch(
                                         worker_id, fetched_step, params)
@@ -1049,7 +1076,8 @@ class PSWorker(threading.Thread):
                                 accum = jax.tree_util.tree_map(
                                     jnp.zeros_like, local_params)
                                 accum_n = 0
-                            with trace_span("worker.compute") as _csp, \
+                            with trace_span("worker.compute", always=True,
+                                            **at), \
                                     self._gp(self._compute_category()):
                                 (local_params, accum, batch_stats, loss,
                                  acc) = self._fused_step(
@@ -1057,18 +1085,22 @@ class PSWorker(threading.Thread):
                                     xb, yb, rng,
                                     self.result.local_steps_completed,
                                     local_lr)
-                                if _csp.ctx is not None:
+                                if trace_enabled():
                                     jax.block_until_ready(accum)
                             grads = None
                         else:
-                            with trace_span("worker.compute") as _csp, \
+                            with trace_span("worker.compute", always=True,
+                                            **at), \
                                     self._gp(self._compute_category()):
                                 grads, batch_stats, loss, acc = \
                                     self._grad_step(
                                         params, batch_stats, xb, yb, rng,
                                         self.result.local_steps_completed)
-                                if _csp.ctx is not None:
-                                    # Tracing: pin jax's async dispatch so
+                                if trace_enabled():
+                                    # --trace only (the span itself is
+                                    # recorded in every run and must not
+                                    # synchronize anything):
+                                    # pin jax's async dispatch so
                                     # device time lands on THIS span
                                     # instead of on whichever later span
                                     # first materializes the grads (the
@@ -1184,13 +1216,22 @@ class PSWorker(threading.Thread):
                 self.result.epoch_times.append(time.time() - t_epoch)
                 self._tm_epochs.inc()
                 if loss is not None:
-                    lval = float(loss)
+                    # The worker's device-complete edge: the last step's
+                    # loss is on the host, so every step of this worker's
+                    # epoch is finished on the device (the store's own
+                    # edge is ``store.sync``).
+                    with trace_span(
+                            "worker.epoch_sync", root=True, always=True,
+                            worker=worker_id, epoch=epoch,
+                            steps=self.result.local_steps_completed) as sp:
+                        lval = float(loss)
+                        sp.attrs["ready_mono"] = time.monotonic()
                     self.result.final_train_loss = \
                         round(lval, 6) if math.isfinite(lval) else None
                     self.result.device_id = min(
                         d.id for d in loss.devices())
                 if cfg.eval_each_epoch:
-                    with trace_span("worker.eval", root=True,
+                    with trace_span("worker.eval", root=True, always=True,
                                     worker=worker_id, epoch=epoch), \
                             self._gp("compute"):
                         self.result.test_accuracies.append(
@@ -1409,7 +1450,8 @@ class PSWorker(threading.Thread):
         overlap win, visible per step in the trace)."""
         if self._skip_quarantined_push():
             return params, fetched_step
-        with trace_span("worker.push_wait"), self._gp("push_wait"):
+        with trace_span("worker.push_wait", always=True,
+                        **self._span_attrs), self._gp("push_wait"):
             item = grads_tree
             try:
                 if self._pipe is None:
@@ -1434,7 +1476,8 @@ class PSWorker(threading.Thread):
                             fetched_step: int, params):
         if self._skip_quarantined_push():
             return params, fetched_step
-        with trace_span("worker.push_wait"), self._gp("push_wait"):
+        with trace_span("worker.push_wait", always=True,
+                        **self._span_attrs), self._gp("push_wait"):
             item = None
             try:
                 if self._pipe is None:
@@ -1504,7 +1547,7 @@ class PSWorker(threading.Thread):
                 return current, fetched_step
         else:
             flat, fetched_step = self.store.fetch(worker_id)
-        with trace_span("worker.codec", stage="decode"), self._gp("codec"):
+        with self._codec("decode"):
             if (getattr(self.store, "fetch_codec", "none")
                     in ("fp16", "bf16")
                     and not getattr(self.store, "decompresses_fetches",
@@ -1572,8 +1615,28 @@ class PSWorker(threading.Thread):
         return self._device_codec.encode(
             flat, plan=plan, scales=self._gradient_scales())
 
+    @property
+    def _span_attrs(self) -> dict:
+        return getattr(self._span, "attrs", {})
+
+    @_span_attrs.setter
+    def _span_attrs(self, attrs: dict) -> None:
+        self._span.attrs = attrs
+
+    @contextmanager
+    def _codec(self, stage: str):
+        """The ``worker.codec`` span and the goodput bracket around codec
+        work. The device store runs no codec (it is handed device arrays
+        and hands them back) and gets neither."""
+        if getattr(self.store, "keeps_device_arrays", False):
+            yield
+            return
+        with trace_span("worker.codec", always=True, stage=stage,
+                        **self._span_attrs), self._gp("codec"):
+            yield
+
     def _push(self, worker_id, grads_tree, fetched_step) -> None:
-        with trace_span("worker.codec", stage="encode"), self._gp("codec"):
+        with self._codec("encode"):
             if getattr(self.store, "keeps_device_arrays", False):
                 # Device-resident store: hand over the device arrays
                 # untouched — no host round-trip, no wire, no codec.
@@ -1643,10 +1706,9 @@ class PSWorker(threading.Thread):
     def evaluate(self, params, batch_stats) -> float:
         """Full test-set top-1 (worker.py:313-331)."""
         from ..train.train_state import TrainState  # light TrainState shim
-        import optax
         state = TrainState.create(
             apply_fn=self.model.apply, params=params,
-            batch_stats=batch_stats, tx=optax.identity())
+            batch_stats=batch_stats, tx=_eval_tx())
         # Device-resident test set, shared by every worker in the process:
         # uploaded once instead of ~30 MB per eval. Benign create race:
         # last wins.

@@ -28,8 +28,15 @@ overhead budget (docs/OBSERVABILITY.md, the <2% tier-1 guard) is
 untouched. Enable with ``--trace`` (CLI) or :func:`enable_tracing`. A
 call site whose spans are few enough to record in every run says so with
 ``trace_span(..., always=True)``: the sync trainer's phase spans (three a
-step, five an epoch) go into the same ring whether tracing is on or not,
-so a benchmark that builds the trainer itself finds them there.
+step, five an epoch) and the parameter-server path's (a worker's step and
+its fetch, compute and push phases, the device store's push, apply, fetch
+and sampled sync: about seven a worker step) go into the same ring whether
+tracing is on or not, so a benchmark that builds the trainer itself finds
+them there, and so does an operator who never asked for ``--trace``
+(``/debug/trace``, a ``--trace-dump-dir`` dump, an incident bundle; read
+with ``cli perf phases``). An ``always`` span never touches the device:
+what synchronizes under ``--trace`` (``worker.compute`` blocks on its
+gradients) asks :func:`trace_enabled`, not whether its span is live.
 
 Every span carries two starts: ``ts`` is ``time.time()`` (wall clock —
 comparable across the processes of one host, which is what the
@@ -76,20 +83,29 @@ __all__ = [
 #: docs/OBSERVABILITY.md documents exactly these names (both pinned by
 #: ``tests/test_docs_drift.py``).
 SPAN_CATALOG = {
-    "worker.step": "one PS-worker loop iteration (root; attrs: worker, "
-                   "step, epoch; epoch_open=True for the epoch's opening "
+    "worker.step": "one PS-worker loop iteration (root, in every run; "
+                   "attrs: worker, step, epoch, which its phase children "
+                   "carry too; epoch_open=True for the epoch's opening "
                    "fetch-only entry)",
     "worker.fetch_wait": "training thread blocked on a params fetch "
                          "(serial fetch or pipeline await)",
     "worker.push_wait": "training thread blocked on a gradient push "
                         "(serial push or pipeline submit backpressure)",
-    "worker.compute": "compiled grad-step call (synchronized on the "
-                      "result while tracing, so device time is "
+    "worker.compute": "compiled grad-step call, dispatch to return "
+                      "(synchronized on the result only under "
+                      "enable_tracing(), so that device time is "
                       "attributed here, not to the first consumer)",
     "worker.codec": "worker-side codec work (attr stage=encode|decode: "
                     "flatten+compress before push / decompress+unflatten "
-                    "after fetch)",
-    "worker.eval": "per-epoch full test-set eval (root)",
+                    "after fetch; none against the device store, which "
+                    "runs no codec)",
+    "worker.epoch_sync": "a worker's wait on its epoch's last loss, after "
+                         "which every step of the epoch is finished on "
+                         "the device (root; attrs worker, epoch, steps: "
+                         "local steps completed, ready_mono: the "
+                         "monotonic time of the wait's return)",
+    "worker.eval": "per-epoch full test-set eval (root; attrs worker, "
+                   "epoch)",
     "worker.reconnect": "session-resume state machine after a lost "
                         "server connection (root; attrs attempts, "
                         "new_worker_id, inflight=repushed|discarded|none, "
@@ -104,12 +120,19 @@ SPAN_CATALOG = {
                          "cached bytes (local root; attr shard) — the "
                          "serve-tier exemplar source",
     "store.push": "store push incl. codec decode (attrs backend, "
-                  "accepted)",
+                  "accepted; the device store adds worker)",
     "store.fetch": "store fetch incl. codec encode (attrs backend, "
                    "not_modified when delta-gated)",
     "store.apply": "parameter update apply (sync round aggregate+apply "
                    "or async staleness-weighted apply; attrs backend, "
                    "staleness/weight in async mode)",
+    "store.sync": "the device store's sampled wait for the device, every "
+                  "wait_every-th update (attrs updates: global_step of "
+                  "the parameters it blocked on, every one of which is "
+                  "then complete on the device; a floor on finished work: "
+                  "up to workers-1 gradient steps of later updates are "
+                  "finished too, uncounted; rejected: pushes refused "
+                  "so far, ready_mono: the monotonic time of its return)",
     "trainer.epoch": "one pass of the sync trainer's epoch loop (root; "
                      "attrs epoch, first_step); its self time is what "
                      "the phase spans below leave unnamed",

@@ -742,7 +742,15 @@ class TestMonitorOverheadGuard:
         """ISSUE 5 satellite, same methodology as the PR 1 telemetry
         guard: measure the EXACT per-RPC monitor cost (one ingest — the
         only health work on a handler thread) directly, then compare
-        against a realistic push/fetch pair."""
+        against a realistic push/fetch pair.
+
+        What it asserts (ISSUE 38): the BEST of several repetitions of
+        each side, the two sides taken in turn so that both see the same
+        stretches of a loaded machine. A neighbour's load can only add to
+        a timing, so the least of ten is what the code costs, and the
+        ratio of two such minima is the same on an idle machine and under
+        six test workers (0.55% here, against a bound of 2%); one ratio
+        of two wall clocks read 2.58% under load (ROADMAP D12)."""
         store = ParameterStore({"w": np.zeros((1024, 1024), np.float32)},
                                StoreConfig(mode="async", total_workers=1,
                                            push_codec="none"))
@@ -753,20 +761,19 @@ class TestMonitorOverheadGuard:
                          pipeline_depth=0, reconnects=0,
                          heartbeat_errors=0)
 
-        n = 5_000
-        t0 = time.perf_counter()
-        for i in range(n):
-            mon.ingest(wid, report)
-        ingest_per_op = (time.perf_counter() - t0) / n
-
-        durations = []
-        _, step = store.fetch(wid)
-        for _ in range(30):
+        store.fetch(wid)
+        ingests, pairs = [], []
+        for _ in range(10):
             t0 = time.perf_counter()
-            store.push(wid, grads, store.global_step)
-            store.fetch(wid)
-            durations.append(time.perf_counter() - t0)
-        op = float(np.median(durations))
+            for _i in range(500):
+                mon.ingest(wid, report)
+            ingests.append((time.perf_counter() - t0) / 500)
+            for _i in range(5):
+                t0 = time.perf_counter()
+                store.push(wid, grads, store.global_step)
+                store.fetch(wid)
+                pairs.append(time.perf_counter() - t0)
+        ingest_per_op, op = min(ingests), min(pairs)
         overhead = 2 * ingest_per_op / op  # one ingest per RPC, 2 RPCs
         assert overhead < 0.02, (
             f"monitor ingest adds {overhead:.2%} to a push/fetch pair "
