@@ -20,10 +20,8 @@ reference the tests and the benchmark hold this file to is
 
 Training path only, as the other decoders: every position of a packed
 sequence counted, no cross-document mask (neither the convolution nor the
-attention resets at a document boundary), no auxiliary loss. Parameters
-are float32, compute is ``dtype``; the norms, the gates and the
-convolution, RoPE, the router and the loss compute in float32 whatever
-``dtype`` is.
+attention resets at a document boundary), no auxiliary loss. The gates and
+the convolution compute in float32 whatever ``dtype`` is.
 
 **What is held here is what the configuration says**: every head (a conv
 mixer is 16.8M parameters and an attention layer 10.5M; the chips that
@@ -32,38 +30,30 @@ router's ``num_experts``; an expert layer's output is the held experts'
 partial sum, which a deployment would add up across the chips that share
 the layer (tests/test_lfm2_lm.py adds them up).
 
-Shared with the other decoders: ``RMSNorm``, ``Linear``, ``SwiGLU`` and the
-chunked cross-entropy (models/joyai.py), ``GroupedAttention``
-(models/smallthinker.py, with its q/k norm on), ``route_top_k`` and
-``held_expert_ffn`` (parallel/moe.py), ``causal_conv1d`` (ops/ssm.py, with
-the Mamba-2 layers of models/nemotron_h.py), the balancing bias as an
-argument that the task updates.
+The blocks (``GroupedAttention`` with its q/k norm on), the expert layer
+(without a shared expert), the skeleton and the loss are
+models/decoder.py's; the gates and the convolution are ops/ssm.py's.
 """
 
 from __future__ import annotations
 
-import math
-from dataclasses import dataclass, replace
-from typing import Any
+from dataclasses import dataclass
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from ..ops.ssm import short_conv
-from ..parallel import moe
-from .joyai import (Linear, RMSNorm, SwiGLU, _chunked_cross_entropy, _normal,
-                    rms_norm)
-from .smallthinker import GroupedAttention
-
-Dtype = Any
+from .decoder import (DecoderConfig, DecoderLM, Dtype, ExpertLayer,
+                      GroupedAttention, Linear, RMSNorm, SwiGLU,
+                      fan_in_uniform, remat_layer)
 
 #: ``layer_types``' values
 CONV, ATTENTION = "conv", "full_attention"
 
 
 @dataclass(frozen=True)
-class Lfm2MoeConfig:
+class Lfm2MoeConfig(DecoderConfig):
     """The published ``config.json`` keys this file reads, under their own
     names, plus what the config lacks (``assumed`` in the benchmark's
     configuration file) and the share held here."""
@@ -95,9 +85,6 @@ class Lfm2MoeConfig:
     # -- sequences ``cli train`` packs at the published widths
     # (``max_position_embeddings`` is the context, not a training length)
     train_seq_len: int = 8192
-    # -- the deployment's: rows an expert layer always computes, in units of
-    # the even load (parallel/moe.py:pass_plan); 0: what the routing needs
-    expert_capacity_factor: float = 0.0
 
     @property
     def head_dim(self) -> int:
@@ -110,7 +97,7 @@ class Lfm2MoeConfig:
         """The mixers of the layers run here, one entry each."""
         return self.layer_types[:self.num_hidden_layers]
 
-    # -- what train/tasks.py:LMTask asks of every decoder's configuration
+    # -- models/decoder.py:DecoderConfig's names
     @property
     def n_routed_experts(self) -> int:
         return self.num_experts
@@ -119,28 +106,12 @@ class Lfm2MoeConfig:
     def expert_layers(self) -> int:
         return self.num_hidden_layers - self.num_dense_layers
 
-    def pass_plan(self, n_tokens: int):
-        """``(rows, min_passes)`` of an expert layer over ``n_tokens``
-        tokens (parallel/moe.py:pass_plan)."""
-        return moe.pass_plan(
-            n_tokens, self.num_experts_per_tok, self.held_experts[1],
-            self.num_experts, self.expert_capacity_factor)
-
     @classmethod
     def from_hf(cls, config: dict, **overrides) -> "Lfm2MoeConfig":
-        """From a dict with the published keys (``config.json`` or the
-        benchmark's configuration file); refuses what this file does not
-        compute."""
-        unsupported = {"conv_bias": False, "use_expert_bias": True,
-                       "tie_word_embeddings": False}
-        for key, want in unsupported.items():
-            if key in config and config[key] != want:
-                raise ValueError(f"{key}={config[key]!r}: this model "
-                                 f"computes {want!r} only")
-        fields = {f: tuple(config[f]) if isinstance(config[f], list)
-                  else config[f]
-                  for f in cls.__dataclass_fields__ if f in config}
-        cfg = replace(cls(**fields), **overrides)
+        """``from_published``, refusing what this file does not compute."""
+        cfg = cls.from_published(
+            config, {"conv_bias": False, "use_expert_bias": True,
+                     "tie_word_embeddings": False}, **overrides)
         if len(cfg.layers) < cfg.num_hidden_layers or \
                 set(cfg.layers) - {CONV, ATTENTION}:
             raise ValueError(f"layer_types {cfg.layers!r} for "
@@ -173,15 +144,6 @@ PRESETS = {
 }
 
 
-def _conv_init(cfg: Lfm2MoeConfig):
-    """Uniform on ``+- 1 / sqrt(K)``: the library default of a depthwise
-    convolution of width ``K`` (its fan-in)."""
-    def init(key, shape, dtype=jnp.float32):
-        bound = 1.0 / math.sqrt(cfg.conv_L_cache)
-        return jax.random.uniform(key, shape, dtype, -bound, bound)
-    return init
-
-
 class ShortConv(nn.Module):
     """``[B | C | x] = u W_in`` (three runs of ``D``), ``y_t = C_t * sum_k
     w[k] (B * x)_{t - (K-1) + k}`` (depthwise, causal, ``K`` =
@@ -195,50 +157,11 @@ class ShortConv(nn.Module):
         cfg, dt = self.cfg, self.dtype
         d = cfg.hidden_size
         bcx = Linear(3 * d, cfg, dt, name="in_proj")(u)
-        kernel = self.param("conv", _conv_init(cfg), (cfg.conv_L_cache, d),
-                            jnp.float32)
+        kernel = self.param("conv", fan_in_uniform(cfg.conv_L_cache),
+                            (cfg.conv_L_cache, d), jnp.float32)
         with jax.named_scope("short_conv"):
             y = short_conv(bcx, kernel)
         return Linear(d, cfg, dt, name="out_proj")(y)
-
-
-class ExpertLayer(nn.Module):
-    """``s = sigmoid(u W_r)`` over all E (float32, true-float32 matmul);
-    the ``k`` largest of ``s + b`` are selected; their weights are the
-    selected ``s`` over their sum (``norm_topk_prob``), times
-    ``routed_scaling_factor``; ``out = sum over the selected e held here of
-    w_e W_down,e(silu(W_gate,e u) * (W_up,e u))``. Returns ``(out, loads
-    [E] int32, processed)``."""
-    cfg: Lfm2MoeConfig
-    dtype: Dtype
-
-    @nn.compact
-    def __call__(self, u, bias):
-        cfg, dt = self.cfg, self.dtype
-        b, t, d = u.shape
-        first, held = cfg.held_experts
-        f, e = cfg.moe_intermediate_size, cfg.num_experts
-        router = self.param("router", _normal(cfg), (d, e), jnp.float32)
-        experts = {
-            name: self.param(f"experts_{name}", _normal(cfg), shape,
-                             jnp.float32)
-            for name, shape in (("gate", (held, d, f)), ("up", (held, d, f)),
-                                ("down", (held, f, d)))}
-        x = u.reshape(b * t, d).astype(dt)
-        with jax.named_scope("moe_route"):
-            scores = jax.nn.sigmoid(jnp.dot(
-                x.astype(jnp.float32), router,
-                precision=jax.lax.Precision.HIGHEST))
-            idx, weights = moe.route_top_k(
-                scores, bias, cfg.num_experts_per_tok,
-                scaling=cfg.routed_scaling_factor,
-                normalize=cfg.norm_topk_prob)
-            loads = moe.expert_loads(idx, e)
-        rows, min_passes = cfg.pass_plan(b * t)
-        routed, processed = moe.held_expert_ffn(
-            x, idx, weights, experts, first, rows=rows,
-            min_passes=min_passes)
-        return routed.reshape(b, t, d), loads, processed
 
 
 class Layer(nn.Module):
@@ -247,7 +170,8 @@ class Layer(nn.Module):
         h   = x + Mixer(RMSNorm(x))     # layer_types[l]: ShortConv or
                                         # GroupedAttention (q/k norm, RoPE)
         out = h + FFN(RMSNorm(h))       # SwiGLU if l < num_dense_layers,
-                                        # else ExpertLayer
+                                        # else models/decoder.py's
+                                        # ExpertLayer
 
     Returns ``(out, loads, processed)``; the last two are None of a dense
     layer. Scopes: the conv mixer whole (norm, projections, residual) under
@@ -286,74 +210,19 @@ class Layer(nn.Module):
             return h + y, loads, processed
 
 
-class Lfm2MoeLM(nn.Module):
-    """The decoder. ``__call__(tokens [B, T+2], router_bias [expert layers,
-    E])`` returns the training quantities of a packed batch: position ``i <
-    T`` of a row reads tokens ``0..i`` and predicts token ``i+1``. Rows are
-    ``T+2`` long because that is what train/tasks.py:LMTask hands every
-    decoder (the first has a second head that predicts token ``i+2``); the
-    last token is not read. The final norm, then the untied head."""
+class Lfm2MoeLM(DecoderLM):
+    """The decoder: models/decoder.py's skeleton over the layers of
+    ``layer_types``, those past ``num_dense_layers`` the expert layers; the
+    final norm, then the untied head."""
     cfg: Lfm2MoeConfig
-    dtype: Dtype = jnp.float32
-    # recompute a layer at a time in backward, but for its routing
-    # decisions (parallel/moe.py:KEEP_ROUTING)
-    remat: bool = True
-    loss_chunk: int = 4096      # rows of logits live at once
 
     def setup(self):
-        cfg, dt = self.cfg, self.dtype
-        layer = (nn.remat(Layer, policy=moe.KEEP_ROUTING) if self.remat
-                 else Layer)
-        self.embed = self.param("embed", _normal(cfg),
-                                (cfg.vocab_size, cfg.hidden_size),
-                                jnp.float32)
-        self.layers = [layer(cfg, dt, kind=kind,
+        super().setup()
+        cfg = self.cfg
+        layer = remat_layer(Layer, self.remat)
+        self.layers = [layer(cfg, self.dtype, kind=kind,
                              dense=i < cfg.num_dense_layers,
                              name=f"layer_{i}")
                        for i, kind in enumerate(cfg.layers)]
-        self.final_norm = self.param("final_norm", nn.initializers.ones,
-                                     (cfg.hidden_size,), jnp.float32)
-        self.head = self.param("head", _normal(cfg),
-                               (cfg.hidden_size, cfg.vocab_size),
-                               jnp.float32)
-
-    def hidden(self, tokens, router_bias):
-        """``tokens`` ``[B, T]`` -> the last layer's output ``[B, T, D]``,
-        the loads ``[expert layers, E]`` and the assignments the held
-        experts computed."""
-        with jax.named_scope("embed"):
-            x = self.embed.astype(self.dtype)[tokens]
-        loads, processed = [], jnp.int32(0)
-        for i, layer in enumerate(self.layers):
-            dense = i < self.cfg.num_dense_layers
-            x, load, done = layer(x, None if dense else
-                                  router_bias[len(loads)])
-            if not dense:
-                loads.append(load)
-                processed += done
-        return x, jnp.stack(loads), processed
-
-    def __call__(self, tokens, router_bias):
-        cfg = self.cfg
-        b, t = tokens.shape[0], tokens.shape[1] - 2
-        x, loads, processed = self.hidden(tokens[:, :-2], router_bias)
-        # the most rows, up to ``loss_chunk``, that divide the batch
-        chunk = max(c for c in range(1, min(self.loss_chunk, b * t) + 1)
-                    if (b * t) % c == 0)
-        with jax.named_scope("head_loss"):
-            next_sum, hits = _chunked_cross_entropy(
-                x.reshape(b * t, -1), self.final_norm, self.head,
-                tokens[:, 1:-1].reshape(-1), cfg.norm_eps, self.dtype, chunk)
-        loss = next_sum / (b * t)
-        return {"loss": loss, "next_loss": loss, "correct": hits,
-                "count": jnp.int32(b * t), "loads": loads,
-                "processed": processed}
-
-    def logits_at(self, tokens, router_bias, positions):
-        """Float32 logits at ``positions`` ``[P]`` of every row, as the
-        one-element tuple ``(main [B, P, V],)``."""
-        x, _loads, _n = self.hidden(tokens[:, :-2], router_bias)
-        w = self.head.astype(self.dtype)
-        return ((rms_norm(x[:, positions], self.final_norm,
-                          self.cfg.norm_eps).astype(self.dtype)
-                 @ w).astype(jnp.float32),)
+        self.experts = [i >= cfg.num_dense_layers
+                        for i in range(len(cfg.layers))]

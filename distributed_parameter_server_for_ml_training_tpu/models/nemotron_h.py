@@ -20,9 +20,7 @@ Training path only, as the other decoders: every position of a packed
 sequence counted, no cross-document mask (the scan's state and the
 attention both run across a boundary), no auxiliary loss, and the
 multi-token-prediction module (``num_nextn_predict_layers``) is not built.
-Parameters are float32, compute is ``dtype``; the norms, the router, the
-scan's decays and carried state and the loss compute in float32 whatever
-``dtype`` is.
+The scan's decays and carried state are float32 whatever ``dtype`` is.
 
 **What is held here is what the configuration says.** ``mamba_num_heads``,
 ``n_groups``, ``num_attention_heads`` and ``num_key_value_heads`` count the
@@ -34,17 +32,14 @@ chips that share the layer (tests/test_nemotron_h_lm.py adds them up).
 The Mamba width is ``mamba_num_heads x mamba_head_dim``, never ``expand x
 hidden_size``.
 
-Shared with the other decoders: ``RMSNorm``, ``Linear``, ``Kernel`` and the
-chunked cross-entropy (models/joyai.py), ``GroupedAttention``
-(models/smallthinker.py), ``held_expert_ffn`` and ``route_top_k``
-(parallel/moe.py), the balancing bias as an argument that the task updates.
+The blocks, the sigmoid router, the skeleton and the loss are
+models/decoder.py's.
 """
 
 from __future__ import annotations
 
 import math
-from dataclasses import dataclass, replace
-from typing import Any
+from dataclasses import dataclass
 
 import flax.linen as nn
 import jax
@@ -52,18 +47,16 @@ import jax.numpy as jnp
 
 from ..ops.ssm import causal_conv1d, ssm_scan
 from ..parallel import moe
-from .joyai import (Linear, RMSNorm, _chunked_cross_entropy, _normal,
-                    rms_norm)
-from .smallthinker import GroupedAttention
-
-Dtype = Any
+from .decoder import (DecoderConfig, DecoderLM, Dtype, GroupedAttention,
+                      Linear, RMSNorm, expert_params, fan_in_uniform,
+                      normal_init, remat_layer, sigmoid_route)
 
 #: ``hybrid_override_pattern``'s characters
 MAMBA, ATTENTION, EXPERTS = "M", "*", "E"
 
 
 @dataclass(frozen=True)
-class NemotronHConfig:
+class NemotronHConfig(DecoderConfig):
     """The published ``config.json`` keys this file reads, under their own
     names, plus what the config lacks (``assumed`` in the benchmark's
     configuration file) and the share held here."""
@@ -109,9 +102,6 @@ class NemotronHConfig:
     # family's pre-training length (``max_position_embeddings`` is the
     # 262,144 of its long-context stage)
     train_seq_len: int = 8192
-    # -- the deployment's: rows an expert layer always computes, in units of
-    # the even load (parallel/moe.py:pass_plan); 0: what the routing needs
-    expert_capacity_factor: float = 0.0
 
     @property
     def pattern(self) -> str:
@@ -122,37 +112,25 @@ class NemotronHConfig:
     def mamba_inner(self) -> int:
         return self.mamba_num_heads * self.mamba_head_dim
 
-    # -- what train/tasks.py:LMTask asks of every decoder's configuration
+    # -- models/decoder.py:DecoderConfig's names
+    @property
+    def norm_eps(self) -> float:
+        return self.layer_norm_epsilon
+
     @property
     def expert_layers(self) -> int:
         return self.pattern.count(EXPERTS)
 
-    def pass_plan(self, n_tokens: int):
-        """``(rows, min_passes)`` of an expert layer over ``n_tokens``
-        tokens (parallel/moe.py:pass_plan)."""
-        return moe.pass_plan(
-            n_tokens, self.num_experts_per_tok, self.held_experts[1],
-            self.n_routed_experts, self.expert_capacity_factor)
-
     @classmethod
     def from_hf(cls, config: dict, **overrides) -> "NemotronHConfig":
-        """From a dict with the published keys (``config.json`` or the
-        benchmark's configuration file); refuses what this file does not
-        compute."""
-        unsupported = {
+        """``from_published``, refusing what this file does not compute."""
+        cfg = cls.from_published(config, {
             "mlp_hidden_act": "relu2", "mamba_hidden_act": "silu",
             "n_group": 1, "topk_group": 1, "n_shared_experts": 1,
             "attention_bias": False, "mamba_proj_bias": False,
             "mlp_bias": False, "use_bias": False, "use_conv_bias": True,
             "tie_word_embeddings": False, "sliding_window": None,
-            "num_nextn_predict_layers": 0}
-        for key, want in unsupported.items():
-            if key in config and config[key] != want:
-                raise ValueError(f"{key}={config[key]!r}: this model "
-                                 f"computes {want!r} only")
-        fields = {f: config[f] for f in cls.__dataclass_fields__
-                  if f in config}
-        cfg = replace(cls(**fields), **overrides)
+            "num_nextn_predict_layers": 0}, **overrides)
         if set(cfg.pattern) - {MAMBA, ATTENTION, EXPERTS} or \
                 len(cfg.pattern) < cfg.num_hidden_layers:
             raise ValueError(f"hybrid_override_pattern {cfg.pattern!r} for "
@@ -208,15 +186,6 @@ def _a_log_init(cfg: NemotronHConfig):
     return init
 
 
-def _conv_init(cfg: NemotronHConfig):
-    """Uniform on ``+- 1 / sqrt(K)``: the library default of a depthwise
-    convolution of width ``K`` (its fan-in)."""
-    def init(key, shape, dtype=jnp.float32):
-        bound = 1.0 / math.sqrt(cfg.conv_kernel)
-        return jax.random.uniform(key, shape, dtype, -bound, bound)
-    return init
-
-
 def group_rms_norm(y, gain, groups: int, eps: float):
     """RMSNorm over each of ``groups`` equal runs of the last axis, one
     learned gain a channel; float32 inside."""
@@ -246,7 +215,7 @@ class Mamba2Mixer(nn.Module):
         inner, conv_dim = h * p, h * p + 2 * g * n
         zxbcdt = Linear(inner + conv_dim + h, cfg, dt_, name="in_proj")(u)
         z, xbc, dt = jnp.split(zxbcdt, [inner, inner + conv_dim], axis=-1)
-        kernel = self.param("conv_kernel", _conv_init(cfg),
+        kernel = self.param("conv_kernel", fan_in_uniform(cfg.conv_kernel),
                             (cfg.conv_kernel, conv_dim), jnp.float32)
         conv_bias = self.param("conv_bias", nn.initializers.zeros,
                                (conv_dim,), jnp.float32)
@@ -283,13 +252,11 @@ class Relu2MLP(nn.Module):
 
 
 class LatentExpertLayer(nn.Module):
-    """``s = sigmoid(x W_r)`` over all E (float32, true-float32 matmul) of
-    the full-width ``x``; the ``k`` largest of ``s + b`` are selected, their
-    weights the selected ``s`` over their sum, times
-    ``routed_scaling_factor``. ``u = x W_down`` into the latent; ``r = sum
-    over the selected e held here of w_e W2_e relu(W1_e u)^2``; ``out = r
-    W_up + shared(x)``, the shared expert on the full width. Returns ``(out,
-    loads [E] int32, processed)``.
+    """models/decoder.py:sigmoid_route on the full-width ``x``; ``u = x
+    W_down`` into the latent; ``r = sum over the selected e held here of
+    w_e W2_e relu(W1_e u)^2``; ``out = r W_up + shared(x)``, the shared
+    expert on the full width. Returns ``(out, loads [E] int32,
+    processed)``.
 
     ``W_up`` is linear, so applying it to the held experts' partial sum
     gives this chip's share of the layer; the shared expert is whole here,
@@ -301,30 +268,18 @@ class LatentExpertLayer(nn.Module):
     def __call__(self, x, bias):
         cfg, dt = self.cfg, self.dtype
         b, t, d = x.shape
-        first, held = cfg.held_experts
-        f, e, latent = (cfg.moe_intermediate_size, cfg.n_routed_experts,
-                        cfg.moe_latent_size)
-        router = self.param("router", _normal(cfg), (d, e), jnp.float32)
-        experts = {
-            name: self.param(f"experts_{name}", _normal(cfg), shape,
-                             jnp.float32)
-            for name, shape in (("up", (held, latent, f)),
-                                ("down", (held, f, latent)))}
+        latent = cfg.moe_latent_size
+        router = self.param("router", normal_init(cfg),
+                            (d, cfg.n_routed_experts), jnp.float32)
+        experts = expert_params(self, cfg, latent, cfg.moe_intermediate_size,
+                                ("up", "down"))
         x = x.reshape(b * t, d).astype(dt)
-        with jax.named_scope("moe_route"):
-            scores = jax.nn.sigmoid(jnp.dot(
-                x.astype(jnp.float32), router,
-                precision=jax.lax.Precision.HIGHEST))
-            idx, weights = moe.route_top_k(
-                scores, bias, cfg.num_experts_per_tok,
-                scaling=cfg.routed_scaling_factor,
-                normalize=cfg.norm_topk_prob)
-            loads = moe.expert_loads(idx, e)
+        idx, weights, loads = sigmoid_route(x, router, bias, cfg)
         with jax.named_scope("moe_latent"):
             u = Linear(latent, cfg, dt, name="latent_down")(x)
         rows, min_passes = cfg.pass_plan(b * t)
         routed, processed = moe.held_expert_ffn(
-            u, idx, weights, experts, first, rows=rows,
+            u, idx, weights, experts, cfg.held_experts[0], rows=rows,
             min_passes=min_passes, activation="relu2")
         with jax.named_scope("moe_latent"):
             routed = Linear(d, cfg, dt, name="latent_up")(routed)
@@ -370,72 +325,15 @@ class Layer(nn.Module):
             return x + mixer(norm(x)), None, None
 
 
-class NemotronHLM(nn.Module):
-    """The decoder. ``__call__(tokens [B, T+2], router_bias [expert layers,
-    E])`` returns the training quantities of a packed batch: position ``i <
-    T`` of a row reads tokens ``0..i`` and predicts token ``i+1``. Rows are
-    ``T+2`` long because that is what train/tasks.py:LMTask hands every
-    decoder (the first has a second head that predicts token ``i+2``); the
-    last token is not read."""
+class NemotronHLM(DecoderLM):
+    """The decoder: models/decoder.py's skeleton over the layers of
+    ``hybrid_override_pattern``, its ``E`` layers the expert layers."""
     cfg: NemotronHConfig
-    dtype: Dtype = jnp.float32
-    # recompute a layer at a time in backward, but for its routing
-    # decisions (parallel/moe.py:KEEP_ROUTING)
-    remat: bool = True
-    loss_chunk: int = 4096      # rows of logits live at once
 
     def setup(self):
-        cfg, dt = self.cfg, self.dtype
-        layer = (nn.remat(Layer, policy=moe.KEEP_ROUTING) if self.remat
-                 else Layer)
-        self.embed = self.param("embed", _normal(cfg),
-                                (cfg.vocab_size, cfg.hidden_size),
-                                jnp.float32)
-        self.layers = [layer(cfg, dt, kind=kind, name=f"layer_{i}")
-                       for i, kind in enumerate(cfg.pattern)]
-        self.final_norm = self.param("final_norm", nn.initializers.ones,
-                                     (cfg.hidden_size,), jnp.float32)
-        self.head = self.param("head", _normal(cfg),
-                               (cfg.hidden_size, cfg.vocab_size),
-                               jnp.float32)
-
-    def hidden(self, tokens, router_bias):
-        """``tokens`` ``[B, T]`` -> the last layer's output ``[B, T, D]``,
-        the loads ``[expert layers, E]`` and the assignments the held
-        experts computed."""
-        with jax.named_scope("embed"):
-            x = self.embed.astype(self.dtype)[tokens]
-        loads, processed = [], jnp.int32(0)
-        for layer, kind in zip(self.layers, self.cfg.pattern):
-            bias = router_bias[len(loads)] if kind == EXPERTS else None
-            x, load, done = layer(x, bias)
-            if kind == EXPERTS:
-                loads.append(load)
-                processed += done
-        return x, jnp.stack(loads), processed
-
-    def __call__(self, tokens, router_bias):
-        cfg = self.cfg
-        b, t = tokens.shape[0], tokens.shape[1] - 2
-        x, loads, processed = self.hidden(tokens[:, :-2], router_bias)
-        # the most rows, up to ``loss_chunk``, that divide the batch
-        chunk = max(c for c in range(1, min(self.loss_chunk, b * t) + 1)
-                    if (b * t) % c == 0)
-        with jax.named_scope("head_loss"):
-            next_sum, hits = _chunked_cross_entropy(
-                x.reshape(b * t, -1), self.final_norm, self.head,
-                tokens[:, 1:-1].reshape(-1), cfg.layer_norm_epsilon,
-                self.dtype, chunk)
-        loss = next_sum / (b * t)
-        return {"loss": loss, "next_loss": loss, "correct": hits,
-                "count": jnp.int32(b * t), "loads": loads,
-                "processed": processed}
-
-    def logits_at(self, tokens, router_bias, positions):
-        """Float32 logits at ``positions`` ``[P]`` of every row, as the
-        one-element tuple ``(main [B, P, V],)``."""
-        x, _loads, _n = self.hidden(tokens[:, :-2], router_bias)
-        w = self.head.astype(self.dtype)
-        return ((rms_norm(x[:, positions], self.final_norm,
-                          self.cfg.layer_norm_epsilon).astype(self.dtype)
-                 @ w).astype(jnp.float32),)
+        super().setup()
+        layer = remat_layer(Layer, self.remat)
+        self.layers = [layer(self.cfg, self.dtype, kind=kind,
+                             name=f"layer_{i}")
+                       for i, kind in enumerate(self.cfg.pattern)]
+        self.experts = [kind == EXPERTS for kind in self.cfg.pattern]

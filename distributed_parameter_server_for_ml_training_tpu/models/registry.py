@@ -20,6 +20,10 @@ Configs covered (BASELINE.json ``configs``):
     mixer, a dense SwiGLU or sigmoid-routed SwiGLU experts as its
     feed-forward half; from an ``Lfm2MoeConfig``
 
+The four decoders share models/decoder.py (blocks, the LM skeleton, the
+routed-expert layer, the configuration's protocol ``DecoderConfig``); this
+module is the one that imports the model files.
+
 A decoder LM's configuration object comes from :func:`lm_config`: itself, a
 preset's name, or (``lm_config_from_file``) a dict of the source's published
 keys with what the caller overrides, which is how a benchmark driver builds

@@ -14,15 +14,9 @@ the tests and the benchmark hold this file to is
 ``benchmarks/reference/smallthinker_reference.py``.
 
 Training path only: every position of a packed sequence counted, no
-cross-document mask, no auxiliary balance loss. Parameters are float32,
-compute is ``dtype`` (bf16 in the benchmark's cell); RMSNorm, RoPE, the
-router and the loss compute in float32 whatever ``dtype`` is. The pieces
-that the two decoders share are models/joyai.py's (``RMSNorm``, ``Linear``,
-``Kernel``, ``rope_half_split``, the chunked cross-entropy), the expert
-layer is parallel/moe.py's ``held_expert_ffn``, **told which experts it
-holds** (``held_experts``), and the attention core is
-``ops.attention.heads_attention_core``, told the window and handed 4
-key/value heads beside 28 query heads.
+cross-document mask, no auxiliary balance loss. The blocks, the skeleton
+and the loss are models/decoder.py's (its ``GroupedAttention`` told the
+window); the experts are parallel/moe.py's ``held_expert_ffn``.
 
 The model has no balancing bias. ``train/tasks.py:LMTask`` hands every
 decoder a ``router_bias`` and updates it by ``bias_update_gamma``; this one
@@ -32,23 +26,19 @@ it.
 
 from __future__ import annotations
 
-from dataclasses import dataclass, replace
-from typing import Any
+from dataclasses import dataclass
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ..ops.attention import heads_attention_core
 from ..parallel import moe
-from .joyai import (Kernel, Linear, RMSNorm, _chunked_cross_entropy,
-                    _normal, rms_norm, rope_half_split)
-
-Dtype = Any
+from .decoder import (DecoderConfig, DecoderLM, Dtype, GroupedAttention,
+                      RMSNorm, expert_params, normal_init, remat_layer)
 
 
 @dataclass(frozen=True)
-class SmallThinkerConfig:
+class SmallThinkerConfig(DecoderConfig):
     """The published ``config.json`` keys this file reads, under their own
     names, plus what the config lacks (``assumed`` in the benchmark's
     configuration file) and the share held here."""
@@ -73,21 +63,19 @@ class SmallThinkerConfig:
     held_experts: tuple = (0, 64)
     # -- assumed
     init_std: float = 0.02
-    # -- the deployment's: rows an expert layer always computes, in units of
-    # the even load (parallel/moe.py:pass_plan); 0: what the routing needs
-    expert_capacity_factor: float = 0.0
 
-    # -- what train/tasks.py:LMTask asks of every decoder's configuration
+    # -- models/decoder.py:DecoderConfig's names
     @property
     def n_routed_experts(self) -> int:
         return self.moe_num_primary_experts
 
-    def pass_plan(self, n_tokens: int):
-        """``(rows, min_passes)`` of an expert layer over ``n_tokens``
-        tokens (parallel/moe.py:pass_plan)."""
-        return moe.pass_plan(
-            n_tokens, self.moe_num_active_primary_experts, self.held_experts[1],
-            self.moe_num_primary_experts, self.expert_capacity_factor)
+    @property
+    def num_experts_per_tok(self) -> int:
+        return self.moe_num_active_primary_experts
+
+    @property
+    def norm_eps(self) -> float:
+        return self.rms_norm_eps
 
     @property
     def expert_layers(self) -> int:
@@ -105,20 +93,11 @@ class SmallThinkerConfig:
 
     @classmethod
     def from_hf(cls, config: dict, **overrides) -> "SmallThinkerConfig":
-        """From a dict with the published keys (``config.json`` or the
-        benchmark's configuration file); refuses what this file does not
-        compute."""
-        unsupported = {"moe_primary_router_apply_softmax": True,
-                       "norm_topk_prob": True, "rope_scaling": None,
-                       "tie_word_embeddings": False}
-        for key, want in unsupported.items():
-            if key in config and config[key] != want:
-                raise ValueError(f"{key}={config[key]!r}: this model "
-                                 f"computes {want!r} only")
-        fields = {f: tuple(config[f]) if isinstance(config[f], list)
-                  else config[f]
-                  for f in cls.__dataclass_fields__ if f in config}
-        cfg = replace(cls(**fields), **overrides)
+        """``from_published``, refusing what this file does not compute."""
+        cfg = cls.from_published(
+            config, {"moe_primary_router_apply_softmax": True,
+                     "norm_topk_prob": True, "rope_scaling": None,
+                     "tie_word_embeddings": False}, **overrides)
         for layout in (cfg.rope_layout, cfg.sliding_window_layout):
             if len(layout) < cfg.num_hidden_layers:
                 raise ValueError(f"a layout of {len(layout)} entries for "
@@ -148,55 +127,6 @@ PRESETS = {
 }
 
 
-class GroupedAttention(nn.Module):
-    """``q, k, v = a W_q [T, H, D], a W_k [T, G, D], a W_v [T, G, D]`` (no
-    bias), RoPE on q and k where ``rope`` says (half-split pairs ``(x[i],
-    x[i + D/2])``), ``o = softmax(q k_g^T / sqrt(D) + mask) v_g`` with query
-    head ``h`` on key/value head ``g = h // (H/G)`` and ``mask`` causal
-    and, with a ``window``, ``i - j < window``; ``W_o`` on the heads.
-
-    **Layouts**, as models/joyai.py:MLA's: q and k are projected per head
-    straight to heads-major ``[B, H, T, D]`` and ``[B, G, T, D]``, v by a
-    plain matmul to ``[B, T, G*D]``, and ``o`` comes back ``[B, T, H*D]``
-    into ``W_o``: the arrays the flash kernels read and write as they are.
-    The parameters keep their published shapes (``[hidden, H*D]``, ``[hidden,
-    G*D]``, ``[H*D, hidden]``).
-
-    ``cfg`` is any decoder's configuration that states ``hidden_size``,
-    ``num_attention_heads``, ``num_key_value_heads``, ``head_dim``,
-    ``rope_theta`` and ``init_std`` (models/nemotron_h.py's attention layer
-    is this module with ``rope=False`` and no window). ``qk_norm``, where
-    given, is the eps of an RMSNorm over each q and each k head (a gain of
-    ``D`` a side, ``q_norm`` and ``k_norm``), applied before RoPE
-    (models/lfm2.py's attention layers)."""
-    cfg: Any
-    dtype: Dtype
-    rope: bool
-    window: int | None
-    qk_norm: float | None = None
-
-    @nn.compact
-    def __call__(self, a):
-        cfg, dt = self.cfg, self.dtype
-        d = a.shape[-1]
-        h, g, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                    cfg.head_dim)
-        w_q = Kernel((d, h * hd), cfg, dt, name="q")().reshape(d, h, hd)
-        w_k = Kernel((d, g * hd), cfg, dt, name="k")().reshape(d, g, hd)
-        a = a.astype(dt)
-        q = jnp.einsum("btc,chd->bhtd", a, w_q)
-        k = jnp.einsum("btc,chd->bhtd", a, w_k)
-        v = Linear(g * hd, cfg, dt, name="v")(a)              # [B, T, G*D]
-        if self.qk_norm is not None:
-            q = RMSNorm(self.qk_norm, name="q_norm")(q)
-            k = RMSNorm(self.qk_norm, name="k_norm")(k)
-        if self.rope:
-            q = rope_half_split(q, cfg.rope_theta)
-            k = rope_half_split(k, cfg.rope_theta)
-        o = heads_attention_core(q, k, v, causal=True, window=self.window)
-        return Linear(cfg.hidden_size, cfg, dt, name="o")(o)
-
-
 class ExpertLayer(nn.Module):
     """``sum over the selected e held here of w_e * W_down,e(relu(W_gate,e
     u) * (W_up,e u))``: ``r = x W_r`` over all E (float32, true-float32
@@ -210,24 +140,20 @@ class ExpertLayer(nn.Module):
     def __call__(self, x, u):
         cfg, dt = self.cfg, self.dtype
         b, t, d = u.shape
-        first, held = cfg.held_experts
-        f, e = cfg.moe_ffn_hidden_size, cfg.moe_num_primary_experts
-        k = cfg.moe_num_active_primary_experts
-        router = self.param("router", _normal(cfg), (d, e), jnp.float32)
-        experts = {
-            name: self.param(f"experts_{name}", _normal(cfg), shape,
-                             jnp.float32)
-            for name, shape in (("gate", (held, d, f)), ("up", (held, d, f)),
-                                ("down", (held, f, d)))}
+        router = self.param("router", normal_init(cfg),
+                            (d, cfg.n_routed_experts), jnp.float32)
+        experts = expert_params(self, cfg, d, cfg.moe_ffn_hidden_size)
         with jax.named_scope("moe_route"):
             logits = jnp.dot(x.reshape(b * t, d).astype(jnp.float32), router,
                              precision=jax.lax.Precision.HIGHEST)
-            idx, weights = moe.route_top_k_softmax(logits, k)
-            loads = moe.expert_loads(idx, e)
+            idx, weights = moe.route_top_k_softmax(
+                logits, cfg.num_experts_per_tok)
+            loads = moe.expert_loads(idx, cfg.n_routed_experts)
         rows, min_passes = cfg.pass_plan(b * t)
         routed, processed = moe.held_expert_ffn(
-            u.reshape(b * t, d).astype(dt), idx, weights, experts, first,
-            rows=rows, min_passes=min_passes, activation="relu",
+            u.reshape(b * t, d).astype(dt), idx, weights, experts,
+            cfg.held_experts[0], rows=rows, min_passes=min_passes,
+            activation="relu",
             # 6 x 16,384 rows a layer: the scatter-add's read-modify-write
             # is the layer's largest cost there and follows the routing
             combine="gather")
@@ -243,13 +169,15 @@ class Block(nn.Module):
 
     with RoPE where ``rope_layout[l]`` is 1 and the window where
     ``sliding_window_layout[l]`` is 1. The attention's instructions are
-    traced under the scope ``attn_window`` or ``attn_full``."""
+    traced under the scope ``attn_window`` or ``attn_full``. ``bias`` is
+    the row of ``router_bias`` every expert layer is handed, and not
+    read."""
     cfg: SmallThinkerConfig
     dtype: Dtype
     layer: int
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, bias=None):
         cfg, dt = self.cfg, self.dtype
         window = cfg.window(self.layer)
         with jax.named_scope("attn_full" if window is None
@@ -263,72 +191,15 @@ class Block(nn.Module):
         return y + m, loads, processed
 
 
-class SmallThinkerLM(nn.Module):
-    """The decoder. ``__call__(tokens [B, T+2], router_bias)`` returns the
-    training quantities of a packed batch: position ``i < T`` of a row
-    reads tokens ``0..i`` and predicts token ``i+1``. Rows are ``T+2`` long
-    and ``router_bias`` is an argument because that is what
-    train/tasks.py:LMTask hands every decoder (the other has a second head
-    that predicts token ``i+2``, and a bias); the last token and the bias
-    are not read."""
+class SmallThinkerLM(DecoderLM):
+    """The decoder: models/decoder.py's skeleton over the blocks, every one
+    an expert layer."""
     cfg: SmallThinkerConfig
-    dtype: Dtype = jnp.float32
-    # recompute a block at a time in backward, but for its routing
-    # decisions (parallel/moe.py:KEEP_ROUTING)
-    remat: bool = True
-    loss_chunk: int = 4096      # rows of logits live at once
 
     def setup(self):
-        cfg, dt = self.cfg, self.dtype
-        block = (nn.remat(Block, policy=moe.KEEP_ROUTING) if self.remat
-                 else Block)
-        self.embed = self.param("embed", _normal(cfg),
-                                (cfg.vocab_size, cfg.hidden_size),
-                                jnp.float32)
-        self.layers = [block(cfg, dt, layer=i, name=f"layer_{i}")
-                       for i in range(cfg.num_hidden_layers)]
-        self.final_norm = self.param("final_norm", nn.initializers.ones,
-                                     (cfg.hidden_size,), jnp.float32)
-        self.head = self.param("head", _normal(cfg),
-                               (cfg.hidden_size, cfg.vocab_size),
-                               jnp.float32)
-
-    def hidden(self, tokens):
-        """``tokens`` ``[B, T]`` -> the last block's output ``[B, T, D]``,
-        the loads ``[layers, E]`` and the assignments the held experts
-        computed."""
-        with jax.named_scope("embed"):
-            x = self.embed.astype(self.dtype)[tokens]
-        loads, processed = [], jnp.int32(0)
-        for block in self.layers:
-            x, load, done = block(x)
-            loads.append(load)
-            processed += done
-        return x, jnp.stack(loads), processed
-
-    def __call__(self, tokens, router_bias=None):
+        super().setup()
         cfg = self.cfg
-        b, t = tokens.shape[0], tokens.shape[1] - 2
-        x, loads, processed = self.hidden(tokens[:, :-2])
-        # the most rows, up to ``loss_chunk``, that divide the batch
-        chunk = max(c for c in range(1, min(self.loss_chunk, b * t) + 1)
-                    if (b * t) % c == 0)
-        with jax.named_scope("head_loss"):
-            next_sum, hits = _chunked_cross_entropy(
-                x.reshape(b * t, -1), self.final_norm, self.head,
-                tokens[:, 1:-1].reshape(-1), cfg.rms_norm_eps, self.dtype,
-                chunk)
-        loss = next_sum / (b * t)
-        return {"loss": loss, "next_loss": loss, "correct": hits,
-                "count": jnp.int32(b * t), "loads": loads,
-                "processed": processed}
-
-    def logits_at(self, tokens, router_bias, positions):
-        """Float32 logits at ``positions`` ``[P]`` of every row, as the
-        one-element tuple ``(main [B, P, V],)`` (the other decoder's holds
-        its second head's too)."""
-        x, _loads, _n = self.hidden(tokens[:, :-2])
-        w = self.head.astype(self.dtype)
-        return ((rms_norm(x[:, positions], self.final_norm,
-                          self.cfg.rms_norm_eps).astype(self.dtype)
-                 @ w).astype(jnp.float32),)
+        block = remat_layer(Block, self.remat)
+        self.layers = [block(cfg, self.dtype, layer=i, name=f"layer_{i}")
+                       for i in range(cfg.num_hidden_layers)]
+        self.experts = [True] * cfg.num_hidden_layers

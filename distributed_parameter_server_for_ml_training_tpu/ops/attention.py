@@ -174,7 +174,7 @@ def heads_attention_core(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          causal: bool = False,
                          window: int | None = None) -> jax.Array:
     """For a caller that projects per head (models/joyai.py:MLA,
-    models/smallthinker.py:GroupedAttention): q and k heads-major ``[B, H,
+    models/decoder.py:GroupedAttention): q and k heads-major ``[B, H,
     T, Dqk]`` and ``[B, G, T, Dqk]`` (G = H, or fewer key/value heads), ``v``
     ``[B, T, G*Dv]`` as its Dense writes it -> ``o`` ``[B, T, H*Dv]`` as the
     output Dense reads it; by the same rule and counted in the same counter

@@ -111,7 +111,8 @@ class LMTask:
     """Packed token rows ``[B, T+2]`` (data/tokens.py); the decoder's
     next-token loss in float32 plus ``mtp_lambda`` x its MTP loss, AdamW,
     the router-bias update from the counted loads, held-out token
-    accuracy."""
+    accuracy. What it reads of the decoder's configuration is
+    models/decoder.py:DecoderConfig."""
 
     batch_arity = 1
     #: per step, replicated: assignments given to held / absent experts and

@@ -652,7 +652,7 @@ def test_tracing_window_attention_counts_its_core_and_its_tiles(monkeypatch):
     masked), counted under ``impl=flash`` with the layer's ``window`` and
     ``group``."""
     from distributed_parameter_server_for_ml_training_tpu.models import (
-        smallthinker)
+        decoder, smallthinker)
     from distributed_parameter_server_for_ml_training_tpu.ops import (
         attention as at)
     from distributed_parameter_server_for_ml_training_tpu.telemetry import (
@@ -670,7 +670,7 @@ def test_tracing_window_attention_counts_its_core_and_its_tiles(monkeypatch):
              for layer, extra in ((0, {}), (1, {"window": "300"}))}
     u = jax.ShapeDtypeStruct((1, 1024, cfg.hidden_size), jnp.bfloat16)
     for layer in (0, 1):
-        attn = smallthinker.GroupedAttention(
+        attn = decoder.GroupedAttention(
             cfg, jnp.bfloat16, rope=bool(cfg.rope_layout[layer]),
             window=cfg.window(layer))
         params = jax.eval_shape(attn.init, jax.random.PRNGKey(0), u)

@@ -1,5 +1,5 @@
 """The decoder LM (models/joyai.py) against its plain reference
-(tests/reference/joyai_reference.py) at a small size on the CPU, and the
+(benchmarks/reference/joyai_reference.py) at a small size on the CPU, and the
 pieces it brought: the held-experts layer, AdamW, token data, the LM task
 through ``SyncTrainer``.
 
@@ -19,7 +19,7 @@ import pytest
 
 from distributed_parameter_server_for_ml_training_tpu.data import tokens as tk
 from distributed_parameter_server_for_ml_training_tpu.models import (
-    get_model, joyai)
+    decoder, get_model, joyai)
 from distributed_parameter_server_for_ml_training_tpu.parallel import moe
 from distributed_parameter_server_for_ml_training_tpu.train.optimizers import (
     adamw)
@@ -31,8 +31,8 @@ B, T = 2, 64
 
 def _load_reference():
     spec = importlib.util.spec_from_file_location(
-        "joyai_reference", os.path.join(HERE, "reference",
-                                        "joyai_reference.py"))
+        "joyai_reference", os.path.join(HERE, "..", "benchmarks",
+                                        "reference", "joyai_reference.py"))
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module
@@ -172,7 +172,7 @@ def test_rope_is_interleaved_not_half_split():
     (x[i], x[i + R/2]), gives other numbers."""
     x = jnp.asarray(np.random.default_rng(0).normal(size=(1, 5, 2, 8)),
                     jnp.float32)
-    got = joyai.rope_interleaved(x, 32e6)
+    got = decoder.rope_interleaved(x, 32e6)
     np.testing.assert_allclose(
         np.asarray(got[0]), np.asarray(ref.rope(x[0], 32e6)), atol=1e-6)
     theta = 32e6 ** (-np.arange(0, 8, 2) / 8)
@@ -193,11 +193,11 @@ def test_half_split_rotation_is_the_interleaved_one_on_permuted_lanes(shape):
     The model holds the array with T next to the lanes (heads-major q, the
     shared k_rope without a head axis)."""
     x = jnp.asarray(np.random.default_rng(1).normal(size=shape), jnp.float32)
-    lanes = joyai.half_split_lanes(shape[-1])
+    lanes = decoder.half_split_lanes(shape[-1])
     assert sorted(lanes) == list(range(shape[-1]))
     np.testing.assert_array_equal(lanes[:4], [0, 2, 4, 6])
-    want = joyai.rope_interleaved(x, 32e6)[..., lanes]       # [B, T, H, R]
-    got = joyai.rope_half_split(
+    want = decoder.rope_interleaved(x, 32e6)[..., lanes]       # [B, T, H, R]
+    got = decoder.rope_half_split(
         jnp.swapaxes(x[..., lanes], 1, 2), 32e6)             # [B, H, T, R]
     np.testing.assert_allclose(np.asarray(jnp.swapaxes(got, 1, 2)),
                                np.asarray(want), atol=1e-6)
@@ -502,8 +502,9 @@ def test_four_shares_of_four_experts_add_up_to_the_whole_layer(f32_case):
         share = replace(cfg, held_experts=(first, 4))
         mine = dict(p, **{f"experts_{n}": p[f"experts_{n}"][first:first + 4]
                           for n in ("gate", "up", "down")})
-        y, share_loads, processed = joyai.ExpertLayer(
-            share, jnp.float32).apply({"params": mine}, u, bias[0])
+        y, share_loads, processed = decoder.ExpertLayer(
+            share, jnp.float32, shared_experts=1).apply(
+                {"params": mine}, u, bias[0])
         np.testing.assert_array_equal(np.asarray(share_loads),
                                       np.asarray(loads))
         assert int(processed) == int(loads[first:first + 4].sum())

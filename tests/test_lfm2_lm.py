@@ -22,7 +22,7 @@ import numpy as np
 import pytest
 
 from distributed_parameter_server_for_ml_training_tpu.models import (
-    get_model, lfm2)
+    decoder, get_model, lfm2)
 from distributed_parameter_server_for_ml_training_tpu.models.registry import (
     MODEL_NAMES, family_of, lm_config, lm_config_from_file)
 from distributed_parameter_server_for_ml_training_tpu.ops import ssm
@@ -230,8 +230,6 @@ def test_a_mixer_against_the_reference(f32_case, layer):
     each parameter."""
     cfg, _model, params, _bias, _tokens, _want = f32_case
     p, u, w = params[layer], _activations(1), _activations(2)
-    from distributed_parameter_server_for_ml_training_tpu.models import (
-        smallthinker)
     if layer == "layer_0":
         p = p["conv"]
         module = lfm2.ShortConv(cfg, jnp.float32)
@@ -240,7 +238,7 @@ def test_a_mixer_against_the_reference(f32_case, layer):
             return jnp.sum(ref.short_conv(p, u[0], cfg) * w[0])
     else:
         p = p["self_attn"]
-        module = smallthinker.GroupedAttention(
+        module = decoder.GroupedAttention(
             cfg, jnp.float32, rope=True, window=None, qk_norm=cfg.norm_eps)
 
         def reference(p, u):
@@ -299,7 +297,7 @@ def test_four_shares_of_eight_experts_add_up_to_the_whole_layer():
     u = _activations(5, 2.0)
     bias = jnp.asarray(np.random.default_rng(6).normal(size=32) * 0.05,
                        jnp.float32)
-    p = lfm2.ExpertLayer(cfg, jnp.float32).init(
+    p = decoder.ExpertLayer(cfg, jnp.float32).init(
         jax.random.PRNGKey(3), u, bias)["params"]
     with jax.default_matmul_precision("highest"):
         whole, loads = ref.expert_layer(p, u[0], bias, cfg)
@@ -309,7 +307,7 @@ def test_four_shares_of_eight_experts_add_up_to_the_whole_layer():
             mine = dict(p, **{f"experts_{n}": p[f"experts_{n}"][first:
                                                                  first + 8]
                               for n in ("gate", "up", "down")})
-            y, share_loads, processed = lfm2.ExpertLayer(
+            y, share_loads, processed = decoder.ExpertLayer(
                 share, jnp.float32).apply({"params": mine}, u, bias)
             np.testing.assert_array_equal(np.asarray(share_loads),
                                           np.asarray(loads))

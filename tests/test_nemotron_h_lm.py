@@ -347,12 +347,11 @@ def test_the_attention_layer_has_no_positions(f32_case):
     a = _activations(2)
     shuffled = a.at[:, :T - 1].set(a[:, np.random.default_rng(3).permutation(
         T - 1)])
-    from distributed_parameter_server_for_ml_training_tpu.models import (
-        smallthinker)
+    from distributed_parameter_server_for_ml_training_tpu.models import decoder
 
     def last(x):
-        attn = smallthinker.GroupedAttention(cfg, jnp.float32, rope=False,
-                                             window=None)
+        attn = decoder.GroupedAttention(cfg, jnp.float32, rope=False,
+                                        window=None)
         return attn.apply({"params": params["layer_2"]["mixer"]}, x)[0, -1]
 
     np.testing.assert_allclose(np.asarray(last(a)), np.asarray(last(shuffled)),
@@ -424,8 +423,7 @@ def test_four_head_shares_of_the_attention_layer_add_up(f32_case):
     each with the key/value head it reads (two shares hold the same one, as
     two chips of the deployment both hold a key/value head)."""
     cfg, _model, params, _bias, _tokens, _want = f32_case
-    from distributed_parameter_server_for_ml_training_tpu.models import (
-        smallthinker)
+    from distributed_parameter_server_for_ml_training_tpu.models import decoder
     p, u, hd = params["layer_2"]["mixer"], _activations(7), cfg.head_dim
     want = ref.attention(p, u[0], cfg, None)
     share_cfg = replace(cfg, num_attention_heads=1, num_key_value_heads=1)
@@ -437,7 +435,7 @@ def test_four_head_shares_of_the_attention_layer_add_up(f32_case):
                 "k": {"kernel": p["k"]["kernel"][:, kv]},
                 "v": {"kernel": p["v"]["kernel"][:, kv]},
                 "o": {"kernel": p["o"]["kernel"][q]}}
-        total = total + smallthinker.GroupedAttention(
+        total = total + decoder.GroupedAttention(
             share_cfg, jnp.float32, rope=False, window=None).apply(
                 {"params": mine}, u)[0]
     np.testing.assert_allclose(np.asarray(total), np.asarray(want),

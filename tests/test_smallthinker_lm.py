@@ -21,7 +21,7 @@ import pytest
 
 from distributed_parameter_server_for_ml_training_tpu.data import tokens as tk
 from distributed_parameter_server_for_ml_training_tpu.models import (
-    get_model, smallthinker)
+    decoder, get_model, smallthinker)
 from distributed_parameter_server_for_ml_training_tpu.models.registry import (
     family_of, lm_config, lm_config_from_file)
 
@@ -189,7 +189,7 @@ def test_the_global_layer_has_no_positions(f32_case):
         T - 1)])
 
     def last(layer, x):
-        attn = smallthinker.GroupedAttention(
+        attn = decoder.GroupedAttention(
             cfg, jnp.float32, rope=bool(cfg.rope_layout[layer]),
             window=cfg.window(layer))
         return attn.apply({"params": params[f"layer_{layer}"]["attn"]},
@@ -216,8 +216,7 @@ def test_the_router_reads_the_blocks_input(f32_case):
     np.testing.assert_array_equal(np.asarray(loads), np.asarray(want_loads))
 
     # the plant: the same layer with the router fed the normed x
-    normed = smallthinker.rms_norm(x, p["attn_norm"]["scale"],
-                                   cfg.rms_norm_eps)
+    normed = decoder.rms_norm(x, p["attn_norm"]["scale"], cfg.rms_norm_eps)
     u = jnp.asarray(np.random.default_rng(5).normal(size=(1, T, 64)),
                     jnp.float32)
     layer = smallthinker.ExpertLayer(cfg, jnp.float32)
